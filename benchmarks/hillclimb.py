@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.configs import shapes as shapes_lib
 from repro.distributed import sharding as shard_lib
-from repro.hw import roofline_terms
+from repro.hw import TPU_V5E, roofline_terms
 from repro.launch import hlo as hlo_lib
 from repro.launch import specs as specs_lib
 from repro.launch.dryrun import HBM_BYTES, _cost_dict, _lower_compile, _mem_dict
@@ -140,7 +140,7 @@ def run_variant(arch: str, shape_name: str, mesh_kind: str, opts: dict) -> dict:
         out["collective_bytes"] = coll
         out["collective_by_op"] = coll_by
         out["terms_ms"] = {
-            k: v * 1e3 for k, v in roofline_terms(flops, bytes_, coll, 1).items()
+            k: v * 1e3 for k, v in roofline_terms(flops, bytes_, coll, 1, TPU_V5E).items()
         }
     return out
 

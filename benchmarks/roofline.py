@@ -31,7 +31,7 @@ import os
 
 from repro import configs
 from repro.configs import shapes as shapes_lib
-from repro.hw import roofline_terms
+from repro.hw import TPU_V5E, roofline_terms
 from repro.models.common import ModelConfig
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
@@ -109,8 +109,8 @@ def analyze_cell(record: dict) -> dict | None:
     adj_flops = max(adj_flops, 0.4 * flops)
     adj_bytes = max(adj_bytes, 0.4 * bytes_)
 
-    raw = roofline_terms(flops, bytes_, coll, n_chips=1)
-    adj = roofline_terms(adj_flops, adj_bytes, coll, n_chips=1)
+    raw = roofline_terms(flops, bytes_, coll, n_chips=1, chip=TPU_V5E)
+    adj = roofline_terms(adj_flops, adj_bytes, coll, n_chips=1, chip=TPU_V5E)
     dominant = max(adj, key=adj.get)
 
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
@@ -194,7 +194,9 @@ def main() -> None:
         print("\n-- the paper's technique: PIPER preprocessing engine --")
         for r in piper_rows:
             pc = r["cost_per_chunk"]
-            t = roofline_terms(pc["flops"], pc["bytes"], pc["collective_bytes"], 1)
+            t = roofline_terms(
+                pc["flops"], pc["bytes"], pc["collective_bytes"], 1, TPU_V5E
+            )
             fin = r["cost_stages"]["finalize"]["collectives"]["total_bytes"]
             dom = max(t, key=t.get)
             print(

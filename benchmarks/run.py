@@ -37,6 +37,7 @@ from benchmarks import (
     table3_throughput,
     table4_operators,
 )
+from repro.launch import compile_cache
 
 SECTIONS = {
     "fig8": fig8_cpu_scaling.main,
@@ -72,6 +73,7 @@ OPT_IN = {"fig8_sharded", "e2e"}
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated section names")
     ap.add_argument(

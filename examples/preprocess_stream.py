@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import pipeline as P, schema as schema_lib
 from repro.data import synth
+from repro.launch import compile_cache
 
 
 def packet_stream(total_rows: int, rows_per_packet: int, chunk_bytes: int, seed=0):
@@ -31,6 +32,7 @@ def packet_stream(total_rows: int, rows_per_packet: int, chunk_bytes: int, seed=
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=30_000)
     ap.add_argument("--chunk-kb", type=int, default=256)

@@ -11,6 +11,9 @@ import numpy as np
 
 from repro.core import baseline, pipeline as P
 from repro.data import synth
+from repro.launch import compile_cache
+
+compile_cache.enable()
 
 # 1. synthesize a Criteo-format dataset (1 label + 13 dense + 26 sparse)
 cfg = synth.SynthConfig(rows=2_000, seed=0)

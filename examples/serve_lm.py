@@ -10,11 +10,13 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models import lm as lm_lib
 from repro.serve import engine as engine_lib
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="hymba-1.5b", choices=configs.ARCH_IDS)
     ap.add_argument("--requests", type=int, default=12)

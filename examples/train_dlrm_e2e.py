@@ -31,6 +31,7 @@ from repro.core import pipeline as P
 from repro.core import schema as schema_lib
 from repro.data import chunk_cache as chunk_cache_lib
 from repro.data import synth
+from repro.launch import compile_cache
 from repro.models import dlrm
 from repro.stream import StreamingPreprocessService
 from repro.train import checkpoint as ckpt_lib
@@ -40,6 +41,7 @@ from repro.train import steps as steps_lib
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--rows", type=int, default=8_192)

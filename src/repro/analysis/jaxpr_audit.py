@@ -37,8 +37,9 @@ from repro.core import schema as schema_lib
 from repro.core import vocab as vocab_lib
 
 # call-like wrappers that are pure structure (inlined by XLA), not work:
-# descend into their bodies instead of counting them
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "custom_jvp_call")
+# descend into their bodies instead of counting them ("jit" is the nested
+# jit's primitive name since JAX 0.7, "pjit" before)
+_CALL_PRIMS = ("jit", "pjit", "closed_call", "core_call", "custom_jvp_call")
 
 
 def count_dispatches(fn, *args) -> int:

@@ -70,7 +70,9 @@ class PipelineConfig:
     max_rows_per_chunk: int = 1 << 14
     # Input already decoded ("binary", the paper's Config III) or raw UTF-8.
     input_format: str = "utf8"
-    # Route hot ops through the Pallas kernels (interpret=True on CPU).
+    # Route hot ops through the per-op Pallas kernels (interpreted on
+    # the CPU). Opt-in: on a TPU Mosaic refuses the vocab and decode
+    # kernels' (1, block) tiles (ROADMAP speed item 2).
     use_kernels: bool = False
     # COMPILER HINT — canonical loop-② groups (Modulus → ApplyVocab ∥
     # Neg2Zero → Logarithm) as one fused Pallas dispatch instead of
@@ -81,7 +83,9 @@ class PipelineConfig:
     # XLA-fused unfused chain), so auto resolves off there and the fused
     # path is opt-in via True — the same reason `use_kernels` defaults
     # False. Outputs are bit-identical on sparse ids and allclose (same
-    # f32 formula) on dense vs. the unfused chain either way.
+    # f32 formula) on dense vs. the unfused chain either way. On a TPU
+    # the VMEM tier's kernel does not lower, so the plan compiler keeps
+    # that tier unfused there (plan_compiler.CompiledPlan).
     use_fused_kernel: bool | None = None
     # COMPILER HINT — loop ①'s canonical vocab group (uint32 Modulus →
     # GenVocab scatter-min over every vocab column, crosses included) as
@@ -107,16 +111,12 @@ class PipelineConfig:
     # `CompiledPlan.decode_*_dispatch`); per-chunk the wrappers still
     # tier-route against the shared 8 MiB VMEM residency budget and
     # fall back to decode + the decoded-input chains beyond it. Unlike
-    # the other fused hints, None currently resolves to **off on every
-    # backend**: CI is CPU-only, so the compiled Mosaic lowering of the
-    # bytes-in kernels (SMEM limits operand, per-byte dynamic RMW /
-    # stores) has never run on real TPU hardware — auto-enabling there
-    # would make an unexercised code path the default. Opt in with True
-    # (what the differential tests and CPU interpret-mode runs do); once
-    # tests/test_decode_fuzz.py is green on a TPU, flip the resolver to
-    # `kernels.resolve_fused()` to match the other hints. Outputs are
-    # bit-identical on sparse ids/labels/state and identical-formula on
-    # dense either way.
+    # the other fused hints, None resolves to **off on every backend**:
+    # Mosaic refuses both bytes-in kernels as written (their (1, block)
+    # byte tiles break the TPU tiling; ROADMAP speed item 2). Opt in with
+    # True (what the differential tests and CPU interpret-mode runs do).
+    # Outputs are bit-identical on sparse ids/labels/state and
+    # identical-formula on dense either way.
     use_fused_decode: bool | None = None
     # Carry the occurrence-count plane beside first_pos in the loop-①
     # state (VocabState.counts) — required by the frequency-capped
@@ -166,11 +166,9 @@ class PipelineConfig:
 
     @property
     def fused_decode_enabled(self) -> bool:
-        """The resolved ``use_fused_decode`` hint. None → **off**: the
-        bytes-in kernels' compiled Mosaic lowering is not yet validated
-        on real TPU hardware (CI runs interpret-mode only), so the
-        fused-decode path stays opt-in until it is — see the field
-        comment. Only consulted for utf8 feeds."""
+        """The resolved ``use_fused_decode`` hint. None → **off**: Mosaic
+        refuses the bytes-in kernels, so the path stays opt-in — see the
+        field comment. Only consulted for utf8 feeds."""
         if self.use_fused_decode is None:
             return False
         return self.use_fused_decode

@@ -50,6 +50,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels as kernels_lib
 from repro.core import ops
 from repro.core import plan as plan_lib
 from repro.core import schema as schema_lib
@@ -298,8 +299,16 @@ class CompiledPlan:
         has_canonical_dense = any(
             _is_dense_canonical(sig) for sig, _, _ in dense_groups
         )
+        # On a TPU the VMEM tier of the fused loop-② kernel stays off:
+        # Mosaic refuses its in-kernel take_along_axis table gather (an
+        # AssertionError inside the lowering, JAX 0.9;
+        # tests/test_tpu_compile.py), so that tier runs the unfused XLA
+        # chain there and its route label says so (ROADMAP speed item 2).
         self._fused_dispatch = (
-            fused and self._n_apply_columns > 0 and has_canonical_dense
+            fused
+            and self._n_apply_columns > 0
+            and has_canonical_dense
+            and not (self.tier == "vmem" and kernels_lib.on_tpu())
         )
         # Loop ①'s single canonical group is "every GenVocab column"
         # (crosses materialize at gather time and join the same rows), so
@@ -800,7 +809,7 @@ def compile_plan(
     ``PipelineConfig.use_fused_decode`` hint for the bytes-in whole-
     pipeline dispatches (utf8 feeds only — the engines consult the
     routing, the compiler just records admissibility; ``None`` resolves
-    to **off** until the compiled lowering is TPU-validated, mirroring
+    to **off** — Mosaic refuses the bytes-in kernels — mirroring
     ``PipelineConfig.fused_decode_enabled``); ``use_kernels`` routes
     the unfused per-op stages through their Pallas kernels.
     ``track_counts`` builds the state with the occurrence-count plane
@@ -809,8 +818,6 @@ def compile_plan(
     with that per-column slab width.
     """
     if fused is None or fused_vocab is None:
-        from repro import kernels as kernels_lib
-
         resolved = kernels_lib.resolve_fused()
         fused = resolved if fused is None else fused
         fused_vocab = resolved if fused_vocab is None else fused_vocab

@@ -27,7 +27,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ops
@@ -140,7 +140,7 @@ class ShardedPiper:
                 P(self.row_axes),
             ),
             out_specs=P(self.row_axes, "model", None),
-            check_rep=False,
+            check_vma=False,
         )(state, chunks, offsets)
 
     def finalize(self, state: jnp.ndarray) -> vocab_lib.Vocabulary:
@@ -188,7 +188,7 @@ class ShardedPiper:
                 P(self.row_axes, None, "model"),
                 P(self.row_axes, None),
             ),
-            check_rep=False,
+            check_vma=False,
         )(vocabulary.table, chunks)
         # Columns stay padded to a multiple of the model axis (padding columns
         # hold ordinal 0 everywhere); downstream embedding tables are padded

@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
@@ -185,7 +185,7 @@ class ShardedPiperPipeline:
                     P(self.row_axes, None, None) if track_counts else None
                 ),
             ),
-            check_rep=False,
+            check_vma=False,
         )(chunks, offsets)
 
     def build_state_scan(self, chunks, offsets) -> vocab_lib.VocabState:
@@ -257,7 +257,7 @@ class ShardedPiperPipeline:
             out_specs=schema_lib.ProcessedBatch(
                 label=row3, dense=row4, sparse=row4, valid=row3
             ),
-            check_rep=False,
+            check_vma=False,
         )(vocabulary, chunks)
 
     def transform_scan(

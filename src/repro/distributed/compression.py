@@ -19,7 +19,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = Any
@@ -83,7 +83,7 @@ def compressed_psum_mean(
             mesh=mesh,
             in_specs=(spec_in, spec_in),
             out_specs=(P(), spec_in),
-            check_rep=False,
+            check_vma=False,
         )(g, e)
 
     flat_g, treedef = jax.tree.flatten(grads)

@@ -1,7 +1,9 @@
-"""Target-hardware constants for roofline analysis and kernel sizing.
+"""Accelerator peak rates for roofline analysis and kernel sizing.
 
-The runtime in this container is CPU; TPU v5e is the *target* platform.
-All roofline terms in benchmarks/ and launch/dryrun.py are derived from
+The program runs on TPU v5e; tests run on the CPU. Peaks are keyed by
+the ``device_kind`` JAX reports (:data:`CHIPS`), and a kind the table
+does not list is an error (:func:`chip_spec`), never a default. All
+roofline terms in benchmarks/ and launch/dryrun.py are derived from
 these numbers, so they live in one place.
 """
 
@@ -23,9 +25,9 @@ class ChipSpec:
     vmem_bytes: int             # on-chip vector memory
 
 
-# TPU v5e numbers given by the brief: 197 TFLOP/s bf16, 819 GB/s HBM,
-# ~50 GB/s/link ICI. VMEM ~128 MiB on v5e-class chips (we size kernel
-# tiles well under this); HBM capacity 16 GiB.
+# Published peaks of one TPU v5e chip (Google Cloud documentation,
+# "TPU v5e"): 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s
+# of chip-to-chip interconnect (4 links of 50 GB/s). VMEM 128 MiB.
 TPU_V5E = ChipSpec(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
@@ -35,6 +37,22 @@ TPU_V5E = ChipSpec(
     hbm_bytes=16 * 1024**3,
     vmem_bytes=128 * 1024**2,
 )
+
+# Keyed by ``jax.devices()[i].device_kind``.
+CHIPS: dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Peak rates of ``device_kind``; raises ``ValueError`` on a kind
+    :data:`CHIPS` does not list."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}; "
+            f"known kinds: {sorted(CHIPS)}"
+        ) from None
+
 
 # MXU native tile — matmul dims should be multiples of this.
 MXU_DIM = 128
@@ -48,7 +66,7 @@ def roofline_terms(
     hlo_bytes: float,
     collective_bytes: float,
     n_chips: int,
-    chip: ChipSpec = TPU_V5E,
+    chip: ChipSpec,
 ) -> dict[str, float]:
     """The three roofline terms (seconds) per the methodology in DESIGN.md §6.
 

@@ -24,21 +24,32 @@ def pallas_available() -> bool:
     return True
 
 
-def resolve_fused(backend: str | None = None) -> bool:
-    """The single source of truth for the fused-kernel auto knob.
-
-    True iff the Pallas toolchain imports *and* ``backend`` (default: the
-    process's default jax backend) compiles it through Mosaic — i.e. TPU.
-    Everywhere else Pallas only interprets, which is slower than the
-    XLA-fused unfused chain, so auto resolves off and callers opt in
-    explicitly. Consumers: ``PipelineConfig.fused_enabled``, the plan
-    compiler's ``fused=None`` hint, and the fused-kernel wrapper's
-    per-backend interpret switch (``kernels/fused_xform/ops.py``).
-    """
-    if not pallas_available():
-        return False
+def on_tpu(backend: str | None = None) -> bool:
+    """Whether ``backend`` (default: the process's default jax backend)
+    is a TPU, where Mosaic compiles Pallas kernels."""
     if backend is None:
         import jax
 
         backend = jax.default_backend()
     return backend == "tpu"
+
+
+def interpret(backend: str | None = None) -> bool:
+    """The ``interpret`` flag every kernel wrapper passes to its Pallas
+    call: compiled through Mosaic on a TPU, interpreted everywhere else
+    (CPU CI). The one per-backend decision, so no kernel interprets on a
+    TPU."""
+    return not on_tpu(backend)
+
+
+def resolve_fused(backend: str | None = None) -> bool:
+    """The single source of truth for the fused-kernel auto knob.
+
+    True iff the Pallas toolchain imports *and* ``backend`` compiles it
+    through Mosaic — i.e. TPU. Everywhere else Pallas only interprets,
+    which is slower than the XLA-fused unfused chain, so auto resolves
+    off and callers opt in explicitly. Consumers:
+    ``PipelineConfig.fused_enabled`` / ``fused_vocab_enabled`` and the
+    plan compiler's ``fused=None`` hints.
+    """
+    return pallas_available() and on_tpu(backend)

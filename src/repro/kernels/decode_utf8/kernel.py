@@ -203,8 +203,8 @@ def decode_scan(
     """Run the decode kernel over a padded byte buffer.
 
     Returns per-byte (value, ordinal, is_delim) — int32 [B] each.
-    ``interpret=True`` executes on CPU (this container); on real TPU pass
-    False for the Mosaic path.
+    ``interpret=True`` runs the Pallas interpreter (the CPU); on a TPU
+    pass False for the Mosaic path (``repro.kernels.interpret()``).
     """
     n = byte_buf.shape[0]
     if n % block:

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels as kernels_lib
 from repro.core import schema as schema_lib
 from repro.kernels.decode_utf8 import kernel
 
@@ -88,7 +89,6 @@ def decode(
     max_rows: int,
     n_dense: int,
     n_sparse: int,
-    interpret: bool = True,
 ):
     """Kernel decode with the layout contract made explicit.
 
@@ -104,5 +104,5 @@ def decode(
         max_rows=max_rows,
         n_dense=n_dense,
         n_sparse=n_sparse,
-        interpret=interpret,
+        interpret=kernels_lib.interpret(),
     )

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.kernels.embedding_bag import kernel, ref
 
 # One column's table must fit VMEM alongside the batch block.
@@ -21,6 +22,8 @@ def embedding_gather(
         bb = min(512, batch)
         pad = (-batch) % bb
         ids_t = jnp.pad(ids, ((0, pad), (0, 0))).T
-        out = kernel.embedding_gather(tables, ids_t, batch_block=bb)
+        out = kernel.embedding_gather(
+            tables, ids_t, batch_block=bb, interpret=kernels_lib.interpret()
+        )
         return out.transpose(1, 0, 2)[:batch]
     return ref.embedding_gather(tables, ids)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.kernels.flash_attention import kernel, ref
 
 
@@ -20,8 +21,9 @@ def attention(
     *,
     causal: bool = True,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     if use_kernel:
-        return kernel.flash_attention(q, k, v, causal=causal, interpret=interpret)
+        return kernel.flash_attention(
+            q, k, v, causal=causal, interpret=kernels_lib.interpret()
+        )
     return ref.mha(q, k, v, causal=causal)

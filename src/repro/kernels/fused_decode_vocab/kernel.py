@@ -36,13 +36,11 @@ routed ``fused_vocab`` chain, which itself degrades to the XLA oracle.
 
 Like every kernel package here, ``interpret=True`` on CPU (tier-1 CI
 exercises the logic without accelerator hardware) and compiled Mosaic on
-a TPU backend (ops.py switches per backend). The CI container is
-CPU-only, so the compiled lowering — in particular the SMEM limits
-operand and the per-byte dynamic RMW — is **not** exercised by CI; for
-that reason ``PipelineConfig.use_fused_decode=None`` resolves to *off*
-on every backend and this path is opt-in via ``True``. On first TPU
-bring-up run ``tests/test_decode_fuzz.py`` there, then flip the
-resolver to auto (see the ``PipelineConfig`` field comment).
+a TPU backend (ops.py switches per backend). Mosaic refuses this kernel
+as written: its ``(1, block)`` byte-tile block breaks the (8, 128)
+tiling (compiled for a described v5e; ROADMAP speed item 2). That is
+why ``PipelineConfig.use_fused_decode=None`` resolves to *off* on every
+backend and this path is opt-in via ``True``.
 """
 
 from __future__ import annotations

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.core import schema as schema_lib
 from repro.core import vocab as vocab_lib
 from repro.kernels.fused_decode_vocab import kernel
@@ -58,12 +59,6 @@ def fused_decode_vocab_tier(n_cols: int, vocab_range: int) -> str:
     ``"xla_fallback"`` route through the reference decode + the
     tier-routed decoded-input chain."""
     return fv_ops.fused_vocab_tier(n_cols, vocab_range)
-
-
-def _interpret() -> bool:
-    from repro import kernels as kernels_lib
-
-    return not kernels_lib.resolve_fused()
 
 
 def fused_decode_update(
@@ -128,7 +123,7 @@ def fused_decode_update(
         limits,
         n_fields=n_fields,
         hex_start=hex_start,
-        interpret=_interpret(),
+        interpret=kernels_lib.interpret(),
         block=block,
     )
     # Structurally short rows (fewer delimiters than fields — malformed,

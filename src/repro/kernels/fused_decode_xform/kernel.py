@@ -34,13 +34,11 @@ no bytes-in kernel: the wrapper (ops.py) falls back to the reference
 decode + the tier-routed ``fused_xform`` chain.
 
 ``interpret=True`` on CPU (the repo-wide CI convention), compiled Mosaic
-on TPU (ops.py switches). The CI container is CPU-only, so the compiled
-lowering — in particular the per-byte dynamic VMEM loads/stores — is
-**not** exercised by CI; for that reason
+on TPU (ops.py switches). Mosaic refuses this kernel as written: its
+``(1, block)`` byte-tile block breaks the (8, 128) tiling (compiled for
+a described v5e; ROADMAP speed item 2). That is why
 ``PipelineConfig.use_fused_decode=None`` resolves to *off* on every
-backend and this path is opt-in via ``True``. On first TPU bring-up run
-``tests/test_decode_fuzz.py`` there, then flip the resolver to auto
-(see the ``PipelineConfig`` field comment).
+backend and this path is opt-in via ``True``.
 """
 
 from __future__ import annotations

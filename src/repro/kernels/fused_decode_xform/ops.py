@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.core import vocab as vocab_lib
 from repro.kernels.fused_decode_xform import kernel
 from repro.kernels.fused_xform import ops as fx_ops
@@ -69,12 +70,6 @@ def fused_decode_tier(
     ):
         return "vmem"
     return "hbm"
-
-
-def _interpret() -> bool:
-    from repro import kernels as kernels_lib
-
-    return not kernels_lib.resolve_fused()
 
 
 def fused_decode_transform(
@@ -127,6 +122,6 @@ def fused_decode_transform(
         n_fields=n_fields,
         hex_start=hex_start,
         max_rows=max_rows,
-        interpret=_interpret(),
+        interpret=kernels_lib.interpret(),
         block=block,
     )

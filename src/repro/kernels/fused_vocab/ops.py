@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.core import vocab as vocab_lib
 from repro.kernels.fused_vocab import kernel
 
@@ -74,7 +75,7 @@ def vmem_accounting(
     n_cols: int,
     vocab_range: int,
     *,
-    row_block: int = 256,
+    row_block: int = 1024,
     track_counts: bool = False,
     slab_range: int | None = None,
 ) -> dict[str, int]:
@@ -85,7 +86,8 @@ def vmem_accounting(
     stack on the vmem tier, or one ``[n_cols, slab_range]`` slab on the
     hbm_slab tier (pass ``slab_range``). The carried entries are what
     the tier guards charge against :data:`FUSED_STATE_VMEM_BYTES` /
-    :data:`SLAB_VMEM_BYTES`; the row tiles stream per grid step. This
+    :data:`SLAB_VMEM_BYTES`; the row tiles stream per grid step through
+    SMEM (``sparse_tile`` and ``pos_tile``). This
     dict is the package's declared footprint — ``fused_vocab_tier``
     derives its decision from it, and ``repro.analysis.kernelcheck``
     asserts the two never disagree.
@@ -174,17 +176,9 @@ def vocab_slab_count(
 
 
 def _row_block(rows: int) -> int:
-    return min(256, max(8, rows))
-
-
-def _interpret() -> bool:
-    """Compile through Mosaic on TPU; interpret everywhere else (the
-    repo-wide CPU-CI convention). Decided per backend via
-    ``kernels.resolve_fused`` — the one copy of the backend test
-    (reaching this wrapper implies Pallas already imported)."""
-    from repro import kernels as kernels_lib
-
-    return not kernels_lib.resolve_fused()
+    # The SMEM blocks of the row tile must be the whole array or a
+    # multiple of 1024 entries.
+    return min(1024, rows)
 
 
 def fused_update(
@@ -233,16 +227,15 @@ def fused_update(
     pad = (-rows) % blk
     # Padding rows scatter NEVER at value 0 % V — a min() no-op.
     sparse_p = jnp.pad(sparse, ((0, pad), (0, 0)))
-    pos_tiles = jnp.pad(
-        pos, (0, pad), constant_values=vocab_lib.NEVER
-    ).reshape(-1, blk)
+    pos_p = jnp.pad(pos, (0, pad), constant_values=vocab_lib.NEVER)
+    interpret = kernels_lib.interpret()
     if tier == "vmem" and not track_counts:
         first_pos = kernel.fused_genvocab(
             state.first_pos,
             sparse_p,
-            pos_tiles,
+            pos_p,
             row_block=blk,
-            interpret=_interpret(),
+            interpret=interpret,
         )
         return vocab_lib.VocabState(first_pos=first_pos, rows_seen=rows_seen)
     # hbm_slab — or vmem with tracked counts, which runs the slab kernel
@@ -255,7 +248,8 @@ def fused_update(
         sr = int(slab_range)
     else:
         sr = default_slab_range(n_cols, vocab_range, track_counts)
-    sr = min(sr, vocab_range)
+    # whole 128-lane windows for the kernel's RMW
+    sr = -(-min(sr, vocab_range) // kernel.LANES) * kernel.LANES
     vpad = (-vocab_range) % sr
     first_pos, counts = state.first_pos, state.counts
     if vpad:
@@ -268,11 +262,11 @@ def fused_update(
         first_pos,
         counts,
         sparse_p,
-        pos_tiles,
+        pos_p,
         slab_range=sr,
         vocab_range=vocab_range,
         row_block=blk,
-        interpret=_interpret(),
+        interpret=interpret,
     )
     if vpad:
         first_pos = first_pos[:, :vocab_range]

@@ -30,12 +30,12 @@ composed input pipelines). These kernels collapse the chain:
 
 Both kernels run ``interpret=True`` on CPU (the repo-wide convention —
 tier-1 CI exercises the kernel logic without accelerator hardware).
-ops.py switches to compiled Mosaic on a TPU backend; this CI container
-is CPU-only, so the compiled lowering (in particular the in-kernel 2-D
-``take_along_axis`` gather and the non-lane-aligned table block) is
-**not** exercised by CI — on first TPU bring-up run
-``tests/test_fused_xform.py`` there before trusting the auto-enabled
-default, and set ``PipelineConfig.use_fused_kernel=False`` to opt out.
+ops.py switches to compiled Mosaic on a TPU backend. There Mosaic
+refuses ``fused_transform_kernel``: the in-kernel ``take_along_axis``
+gather fails inside its lowering (tests/test_tpu_compile.py), so the
+plan compiler keeps the VMEM tier off the TPU route and it runs the
+unfused XLA chain. ``fused_mod_dense_kernel`` compiles and is the HBM
+tier's front half on the chip.
 """
 
 from __future__ import annotations

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.core import vocab as vocab_lib
 from repro.kernels.fused_xform import kernel, ref
 
@@ -78,18 +79,6 @@ def _row_block(rows: int) -> int:
     return min(256, max(8, rows))
 
 
-def _interpret() -> bool:
-    """Compile through Mosaic on TPU; interpret everywhere else (the
-    repo-wide CPU-CI convention). Unlike the older kernel packages this
-    wrapper decides per backend, so a TPU deployment gets the compiled
-    kernel without callers having to thread an interpret flag. Delegates
-    to ``kernels.resolve_fused`` — the one copy of the backend test
-    (reaching this wrapper implies Pallas already imported)."""
-    from repro import kernels as kernels_lib
-
-    return not kernels_lib.resolve_fused()
-
-
 def fused_transform(
     vocab: vocab_lib.Vocabulary, sparse: jnp.ndarray, dense: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -110,7 +99,7 @@ def fused_transform(
     dense_p = jnp.pad(dense, ((0, pad), (0, 0)))
     if fused_tier(n_sparse, vocab.vocab_range) == "vmem":
         ids, dense_out = kernel.fused_transform(
-            vocab.table, sparse_p, dense_p, row_block=blk, interpret=_interpret()
+            vocab.table, sparse_p, dense_p, row_block=blk, interpret=kernels_lib.interpret()
         )
     else:
         modded, dense_out = kernel.fused_mod_dense(
@@ -118,7 +107,7 @@ def fused_transform(
             dense_p,
             vocab_range=vocab.vocab_range,
             row_block=blk,
-            interpret=_interpret(),
+            interpret=kernels_lib.interpret(),
         )
         ids = vocab_lib.lookup(vocab, modded)
     return ids[:rows], dense_out[:rows]

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels as kernels_lib
 from repro.core import vocab as vocab_lib
 from repro.kernels.vocab import kernel, ref
 
@@ -26,7 +27,9 @@ def apply_vocab_vmem(table: jnp.ndarray, modded: jnp.ndarray) -> jnp.ndarray:
     blk = min(1024, max(128, rows))
     pad = (-rows) % blk
     vals_t = jnp.pad(modded, ((0, pad), (0, 0))).T
-    ids_t = kernel.apply_vocab(table, vals_t, row_block=blk)
+    ids_t = kernel.apply_vocab(
+        table, vals_t, row_block=blk, interpret=kernels_lib.interpret()
+    )
     return ids_t.T[:rows]
 
 
@@ -44,7 +47,9 @@ def genvocab_update(
     pos = vocab_lib.positions(state.rows_seen, rows, valid)
     vals_t = modded.T
     if state.first_pos.shape[1] <= vocab_lib.VMEM_TIER_MAX:
-        first_pos = kernel.genvocab(state.first_pos, vals_t, pos)
+        first_pos = kernel.genvocab(
+            state.first_pos, vals_t, pos, interpret=kernels_lib.interpret()
+        )
     else:
         first_pos = ref.genvocab(state.first_pos, vals_t, pos)
     rows_seen = vocab_lib.advance_rows_seen(

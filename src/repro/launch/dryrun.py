@@ -41,6 +41,7 @@ import jax
 from repro import configs
 from repro.configs import shapes as shapes_lib
 from repro.distributed import sharding as shard_lib
+from repro.launch import compile_cache
 from repro.launch import hlo as hlo_lib
 from repro.launch import specs as specs_lib
 from repro.launch.mesh import make_production_mesh
@@ -293,6 +294,7 @@ def cell_path(arch: str, shape_name: str, mesh_kind: str, out_dir: str) -> str:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -19,16 +19,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh with Auto axis types (tests, benchmarks).
-
-    ``axis_types`` only exists on newer jax; older versions are
-    Auto-by-construction, so we fall back to the plain constructor.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh with Auto axis types (tests, benchmarks)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models import lm as lm_lib
 from repro.serve import engine as engine_lib
 
@@ -66,6 +67,7 @@ def run_piper_stream(args) -> None:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=configs.ARCH_IDS)
     ap.add_argument("--requests", type=int, default=8)

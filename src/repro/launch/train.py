@@ -20,6 +20,7 @@ import numpy as np
 from repro import configs
 from repro.core import pipeline as pipeline_lib
 from repro.data import loader, synth
+from repro.launch import compile_cache
 from repro.launch import specs as specs_lib
 from repro.train import optimizer as opt_lib
 from repro.train import trainer as trainer_lib
@@ -40,6 +41,7 @@ def preprocess_tokens(schema_rows: int, vocab_size: int, seed: int = 0):
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=configs.ARCH_IDS)
     ap.add_argument("--steps", type=int, default=100)

@@ -123,7 +123,7 @@ def moe_forward(x: jnp.ndarray, params: Params, cfg: ModelConfig, kind: str):
 
 def _moe_forward_ep(x, params, cfg, kind, mesh):
     """Expert-parallel shard_map path (see moe_forward docstring)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import data_axes
@@ -221,7 +221,7 @@ def _moe_forward_ep(x, params, cfg, kind, mesh):
             w_spec_d,
         ),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"]["w"], params["w_gate"], params["w_up"], params["w_down"])
 
     if "shared" in params:

@@ -128,12 +128,12 @@ def test_fused_chunk_carry_property(seed, n_chunks):
 def test_fused_matches_update_both_tiers(vocab_range, tier):
     """Differential equivalence on either side of the VMEM cutoff.
 
-    Row counts straddle the wrapper's padding logic: 300 > 256 forces
-    blk=256 with 212 pad rows, 5 < 8 forces blk=8 with 3 pad rows (the
-    _row_block floor) — padding must scatter nothing."""
+    Row counts straddle the wrapper's padding logic: 1500 > 1024 forces
+    blk=1024 with 548 pad rows, 5 runs as one whole-array block —
+    padding must scatter nothing."""
     assert fv_ops.fused_vocab_tier(1, vocab_range) == tier
     rng = np.random.default_rng(0)
-    for rows in (300, 5):
+    for rows in (1500, 5):
         sparse = _random_inputs(rng, rows, 1)
         valid = jnp.asarray(rng.random(rows) < 0.9)
         _assert_fused_matches_unfused(
@@ -218,7 +218,7 @@ def test_fused_kernel_interpret_mode_row_blocks(row_block):
     got = fv_kernel.fused_genvocab(
         state,  # donated — ref computed first
         sparse,
-        pos.reshape(-1, row_block),
+        pos,
         row_block=row_block,
         interpret=True,
     )

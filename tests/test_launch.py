@@ -9,7 +9,7 @@ import pytest
 
 from repro import configs
 from repro.configs import shapes as shapes_lib
-from repro.hw import roofline_terms
+from repro.hw import TPU_V5E, chip_spec, roofline_terms
 from repro.launch.mesh import data_axes
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
@@ -30,10 +30,16 @@ def test_shape_applicability_matrix():
 
 
 def test_roofline_terms_math():
-    t = roofline_terms(197e12, 819e9, 50e9, n_chips=1)
+    t = roofline_terms(197e12, 819e9, 50e9, n_chips=1, chip=TPU_V5E)
     assert t["compute_s"] == pytest.approx(1.0)
     assert t["memory_s"] == pytest.approx(1.0)
     assert t["collective_s"] == pytest.approx(1.0)
+
+
+def test_chip_spec_keyed_by_device_kind():
+    assert chip_spec("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="no peak rates"):
+        chip_spec("cpu")
 
 
 def test_mesh_factory_shapes():

@@ -213,11 +213,14 @@ def test_no_recompile_after_warmup(criteo_small):
             delta = pipe.vocab_step(delta, jax.tree.map(jnp.asarray, chunk))
         prev = svc.vocab_state
         svc.refresh_vocab(delta)
+        # the loop publishes the merged state before it finalizes and
+        # swaps; the apply counter moves only once the swap is done
+        applied = svc.registry.counter("stream.vocab_apply_total")
         deadline = time.time() + 30
-        while svc.vocab_state is prev:
+        while applied.value < 1:
             assert time.time() < deadline, "vocab swap never applied"
             time.sleep(0.002)
-        assert svc.registry.counter("stream.vocab_apply_total").value >= 1
+        assert svc.vocab_state is not prev
 
         # post-swap: the whole ladder again, still zero recompiles
         handles = [
