@@ -94,7 +94,6 @@ def main() -> None:
         from repro import obs
 
         obs.enable()
-        obs.set_stage_spans(True)  # nested decode spans need split dispatch
     names = (
         args.only.split(",")
         if args.only

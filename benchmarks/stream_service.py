@@ -120,7 +120,6 @@ def run_format(fmt: str, rows: int) -> dict:
 def main(rows: int = ROWS, trace: str | None = None) -> None:
     if trace:
         obs.enable()
-        obs.set_stage_spans(True)  # nested decode spans need split dispatch
     per_fmt = {}
     for fmt in ("utf8", "binary"):
         per_fmt[fmt] = run_format(fmt, rows)

@@ -213,15 +213,6 @@ class PiperPipeline:
         # stream pass would retrace/recompile on every epoch
         self._jit_vocab_step = jax.jit(self.vocab_step)
         self._jit_transform_chunk = jax.jit(self.transform_chunk)
-        # Stage-split entry points for fine-grained tracing
-        # (obs.stage_spans()): decode as its own dispatch, then the
-        # compiled plan's post-decode half on the decoded batch. The
-        # split boundary is all-integer tensors, so outputs are
-        # bit-identical to the monolithic dispatch (tests/test_obs.py);
-        # jit is lazy — nothing compiles unless the mode is on.
-        self._jit_decode_chunk = jax.jit(self.decode_chunk)
-        self._jit_vocab_batch = jax.jit(self.compiled.vocab_step)
-        self._jit_transform_batch = jax.jit(self.compiled.transform)
         # Span labels: the compiled plan's tier + route metadata, stamped
         # on every per-chunk span so the trace says *which* code path
         # (fused/vmem, fused/hbm, unfused, bytes/...) the time went to.
@@ -271,17 +262,6 @@ class PiperPipeline:
                 self._c_rows[loop].add(int((chunk == schema_lib.NEWLINE).sum()))
         else:
             self._c_rows[loop].add(int(chunk["label"].shape[0]))
-
-    def _stage_split(self, bytes_routed: bool) -> bool:
-        """Whether per-chunk work should run as decode + post-decode
-        dispatches for real nested decode spans (trace-collection mode;
-        a bytes-routed loop keeps its single fused dispatch — that
-        fusion is the whole point, the span just carries the route)."""
-        return (
-            obs.stage_spans()
-            and self.config.input_format == "utf8"
-            and not bytes_routed
-        )
 
     # ------------------------------------------------------------------ #
     # Decode stage
@@ -361,7 +341,6 @@ class PiperPipeline:
         re-finalize between serving steps.
         """
         state = self.init_state()
-        split = self._stage_split(self._bytes_vocab)
         cap = self.config.max_rows_per_chunk
         # Host-side stream-length guard: positions are int32, so a stream
         # may carry at most vocab.MAX_ROWS rows (beyond that the kernels
@@ -384,15 +363,7 @@ class PiperPipeline:
             self._note_chunk("loop1", chunk)
             chunk = jax.tree.map(jnp.asarray, chunk)
             with obs.span("loop1/chunk", **self._vocab_span_labels):
-                if split:
-                    with obs.span("decode"):
-                        batch = self._jit_decode_chunk(chunk)
-                    with obs.span(
-                        "vocab_update", route=self.compiled.vocab_route
-                    ):
-                        state = self._jit_vocab_batch(state, batch)
-                else:
-                    state = self._jit_vocab_step(state, chunk)
+                state = self._jit_vocab_step(state, chunk)
         return state
 
     def build_vocab_stream(self, chunks: Iterable) -> vocab_lib.Vocabulary:
@@ -529,26 +500,13 @@ class FrozenVocabTransform:
         pipe._note_chunk("loop2", chunk)
         chunk = jax.tree.map(jnp.asarray, chunk)
         with obs.span("loop2/chunk", **pipe._xform_span_labels):
-            if pipe._stage_split(pipe._bytes_xform):
-                # trace-collection mode: decode as its own dispatch so
-                # the span nests a *real* decode segment (bit-identical —
-                # the split boundary is integer tensors)
-                with obs.span("decode"):
-                    batch = pipe._jit_decode_chunk(chunk)
-                with obs.span("transform", route=pipe.compiled.xform_route):
-                    return pipe._jit_transform_batch(self._vocab, batch)
             return self._jit(self._vocab, chunk)
 
     def compile_cache_size(self) -> int:
         """Number of compiled executables behind this step (jit cache
-        entries, stage-split entry points included). The scheduler's
-        shape discipline pins this: after warmup it must stop growing
-        (tests/test_stream_service.py)."""
-        return (
-            self._jit._cache_size()
-            + self._pipe._jit_decode_chunk._cache_size()
-            + self._pipe._jit_transform_batch._cache_size()
-        )
+        entries). The scheduler's shape discipline pins this: after
+        warmup it must stop growing (tests/test_stream_service.py)."""
+        return self._jit._cache_size()
 
 
 def flatten_processed(

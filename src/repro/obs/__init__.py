@@ -18,20 +18,18 @@ three engines (``PiperPipeline``, ``ShardedPiperPipeline``, the
 Default-on and provably non-semantic: instrumentation never touches the
 computation (spans time host blocks; ``jax.named_scope`` only names
 HLO), every golden/bit-identity test runs with it enabled, and
-:func:`disable` reduces a span to a shared no-op context manager.
+:func:`disable` reduces a span to a shared no-op context manager. Where
+decode time goes is read from the device profile, not from a split of
+the program.
 
-``stage_spans`` (off by default) is the one knob that changes execution
-*structure* without changing results: the utf8 engines split their
-single per-chunk dispatch into a decode dispatch + a post-decode
-dispatch so the trace shows real nested ``decode`` spans. The split is
-at an integer-tensor boundary, so outputs stay bit-identical
-(tests/test_obs.py pins this); it costs one extra dispatch per chunk,
-which is why only trace-collection runs (``--trace``) turn it on.
+The stream service writes one ``stream/request`` and one
+``stream/batch`` record per routed request and batch into
+:func:`tracer` (:meth:`Tracer.complete`); while a ``jax.profiler``
+session runs the tracer leaves ``obs/clock/<perf_counter_ns>``
+anchors in it, which map the ring onto the profile's clock.
 """
 
 from __future__ import annotations
-
-import threading
 
 from repro.obs import counters as counters_lib
 from repro.obs import stall  # noqa: F401  (re-export module)
@@ -41,7 +39,6 @@ from repro.obs.stall import StallClock
 from repro.obs.trace import Tracer, validate_trace
 
 _GLOBAL_TRACER = trace_lib.Tracer()
-_STAGE_SPANS = threading.Event()
 
 
 def tracer() -> Tracer:
@@ -77,19 +74,6 @@ def metrics() -> Registry:
     return counters_lib.default_registry()
 
 
-def set_stage_spans(on: bool) -> None:
-    """Toggle fine-grained stage spans (separate decode dispatch on the
-    utf8 engines — see the module docstring). Off by default."""
-    if on:
-        _STAGE_SPANS.set()
-    else:
-        _STAGE_SPANS.clear()
-
-
-def stage_spans() -> bool:
-    return _STAGE_SPANS.is_set()
-
-
 __all__ = [
     "Counter",
     "Gauge",
@@ -102,9 +86,7 @@ __all__ = [
     "enabled",
     "instant",
     "metrics",
-    "set_stage_spans",
     "span",
-    "stage_spans",
     "stall",
     "tracer",
     "validate_trace",

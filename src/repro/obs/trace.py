@@ -20,14 +20,29 @@ name the lowered HLO rather than host wall time.
 Semantics (documented, not implied): a span measures **host wall time of
 the enclosed block**. For an async JAX dispatch that is the time to
 *launch* the computation, not to finish it — device completion shows up
-in the explicit wait spans (``device/wait``) and in the stall
-attribution (:mod:`repro.obs.stall`).
+in the service's ``stream/ready`` spans and in the stall attribution
+(:mod:`repro.obs.stall`).
+
+One clock: every time the tracer takes is ``time.perf_counter_ns``.
+Event ``ts``/``dur`` are microseconds from the tracer's epoch
+(:attr:`Tracer.epoch_ns`), so ``epoch_ns + ts * 1e3`` is the event's
+start on that clock. A record whose life crosses threads (a request
+from submit to routed) is written with explicit stamps through
+:meth:`Tracer.complete`.
+
+Anchors to the device trace: while a ``jax.profiler`` session runs, the
+tracer enters a short ``TraceAnnotation`` named ``obs/clock/<ns>``,
+where ``<ns>`` is the ``perf_counter_ns`` it read just before — at the
+first span or record it sees in a session and every
+``ANCHOR_EVERY_NS`` after. The annotation's start in the profile minus
+``<ns>`` maps ring times onto the profile's clock. With no session
+running, the check is one call and no anchor is left.
 
 Tracing is **default-on** with a bounded ring (oldest events drop, a
-counter records how many) and negligible overhead: one perf_counter pair
-plus one deque append per span. ``Tracer.enabled = False`` (or
+counter records how many) and negligible overhead: one clock pair plus
+one deque append per span. ``Tracer.enabled = False`` (or
 :func:`repro.obs.disable`) turns a span into a shared no-op context
-manager.
+manager and :meth:`Tracer.complete` into a return.
 
 Run as a module to validate a trace file against the schema::
 
@@ -46,6 +61,12 @@ from collections import deque
 # engines emit (a handful of spans per chunk).
 DEFAULT_MAX_EVENTS = 1 << 16
 
+# Clock anchors: name prefix and the least spacing while a profiler
+# session runs; each anchor annotation lasts ANCHOR_NS.
+ANCHOR_PREFIX = "obs/clock/"
+ANCHOR_EVERY_NS = 250_000_000
+ANCHOR_NS = 1_000
+
 _VALID_PH = {"X", "i", "I", "M", "C", "B", "E"}
 
 
@@ -57,6 +78,17 @@ def _annotation_cls():
         from jax.profiler import TraceAnnotation
 
         return TraceAnnotation
+    except Exception:  # pragma: no cover — bare installs only
+        return None
+
+
+def _profiler_active_fn():
+    """A no-argument callable telling whether a ``jax.profiler`` session
+    is recording (``TraceMe.is_enabled``); None when unavailable."""
+    try:
+        from jax._src.lib import _profiler
+
+        return _profiler.TraceMe.is_enabled
     except Exception:  # pragma: no cover — bare installs only
         return None
 
@@ -94,14 +126,15 @@ class _Span:
         if cls is not None:
             self._annotation = cls(self.name)
             self._annotation.__enter__()
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = time.perf_counter_ns()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         self._tracer._record(self.name, self.cat, self._t0, t1, self.args)
+        self._tracer._anchor()
         return False
 
 
@@ -122,9 +155,16 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=max_events)
         self._appended = 0
-        self._t_epoch = time.perf_counter()
+        self._t_epoch = time.perf_counter_ns()
         self._annotation = _annotation_cls() if annotate else None
+        self._profiler_active = _profiler_active_fn() if self._annotation else None
+        self._last_anchor: int | None = None  # None: no anchor this session
         self._thread_names: dict[int, str] = {}
+
+    @property
+    def epoch_ns(self) -> int:
+        """``perf_counter_ns`` at which event ``ts`` is 0."""
+        return self._t_epoch
 
     # -- recording ----------------------------------------------------- #
     def span(self, name: str, cat: str = "host", **labels):
@@ -134,12 +174,24 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, cat, labels)
 
+    def complete(
+        self, name: str, start_ns: int, end_ns: int, cat: str = "host", **labels
+    ) -> None:
+        """Record one complete event from explicit ``perf_counter_ns``
+        stamps, for a life that no single block on one thread spans
+        (the service's ``stream/request`` and ``stream/batch``
+        records). Not bridged to the profiler; ``labels`` become args."""
+        if not self.enabled:
+            return
+        self._record(name, cat, start_ns, end_ns, labels)
+        self._anchor()
+
     def instant(self, name: str, cat: str = "host", **labels) -> None:
         """Zero-duration marker (``ph:"i"``) — vocab refresh arrivals,
         swap applications, error events."""
         if not self.enabled:
             return
-        ts = (time.perf_counter() - self._t_epoch) * 1e6
+        ts = (time.perf_counter_ns() - self._t_epoch) / 1e3
         self._append(
             {
                 "name": name,
@@ -153,14 +205,33 @@ class Tracer:
             }
         )
 
+    def _anchor(self) -> None:
+        """Leave an ``obs/clock/<ns>`` annotation in a running profiler
+        session: at the first call that sees the session, then at most
+        every ``ANCHOR_EVERY_NS``."""
+        if self._profiler_active is None:
+            return
+        if not self._profiler_active():
+            self._last_anchor = None
+            return
+        t = time.perf_counter_ns()
+        last = self._last_anchor
+        if last is not None and t - last < ANCHOR_EVERY_NS:
+            return
+        self._last_anchor = t
+        with self._annotation(f"{ANCHOR_PREFIX}{t}"):
+            # a non-zero length: the profile's readers drop empty events
+            while time.perf_counter_ns() - t < ANCHOR_NS:
+                pass
+
     def _record(self, name, cat, t0, t1, labels) -> None:
         self._append(
             {
                 "name": name,
                 "cat": cat,
                 "ph": "X",
-                "ts": (t0 - self._t_epoch) * 1e6,
-                "dur": (t1 - t0) * 1e6,
+                "ts": (t0 - self._t_epoch) / 1e3,
+                "dur": (t1 - t0) / 1e3,
                 "pid": os.getpid(),
                 "tid": threading.get_ident(),
                 "args": {k: _argstr(v) for k, v in labels.items()},
@@ -190,7 +261,7 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self._appended = 0
-            self._t_epoch = time.perf_counter()
+            self._t_epoch = time.perf_counter_ns()
 
     def to_chrome(self) -> dict:
         """The Perfetto-loadable document: thread-name metadata events

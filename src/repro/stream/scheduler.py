@@ -33,9 +33,11 @@ columns (Config III).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -45,6 +47,11 @@ from repro.core import vocab as vocab_lib
 
 DEFAULT_BUCKET_ROWS = (1024, 4096, 16384)
 
+# Process-wide ids: requests and batches of every service are numbered
+# in creation order (next() on a count is atomic under the GIL).
+_request_ids = itertools.count()
+_batch_ids = itertools.count()
+
 
 class StreamRequest:
     """One in-flight preprocessing request — also the caller's handle.
@@ -53,14 +60,22 @@ class StreamRequest:
     ``{label, dense, sparse}`` dict of per-row arrays (binary). The
     service fills the timing fields; :meth:`result` blocks until the
     request's rows come back from the device (or the service failed).
+
+    ``id`` is unique and increasing in creation order. ``submit_ns`` and
+    ``taken_ns`` are ``time.perf_counter_ns`` stamps (the tracer's
+    clock): accepted by ``submit``, and taken out of the ingress or the
+    carry into a batch; ``batch_id`` names that batch.
     """
 
     def __init__(self, payload, n_rows: int, n_bytes: int):
+        self.id = next(_request_ids)
         self.payload = payload
         self.n_rows = n_rows
         self.n_bytes = n_bytes
-        self.submit_t: float | None = None
         self.done_t: float | None = None
+        self.submit_ns: int | None = None
+        self.taken_ns: int | None = None
+        self.batch_id: int | None = None
         self._done = threading.Event()
         self._result: dict | None = None
         self._error: BaseException | None = None
@@ -77,6 +92,11 @@ class StreamRequest:
     @property
     def done(self) -> bool:
         return self._done.is_set()
+
+    @property
+    def submit_t(self) -> float | None:
+        """``submit_ns`` in seconds, on ``time.perf_counter``'s clock."""
+        return None if self.submit_ns is None else self.submit_ns * 1e-9
 
     @property
     def latency_s(self) -> float | None:
@@ -192,16 +212,38 @@ class Bucket:
 
 @dataclasses.dataclass
 class MicroBatch:
-    """A packed step: the padded chunk plus per-request output row spans."""
+    """A packed step: the padded chunk plus per-request output row spans.
+
+    ``id`` is unique and increasing in assembly order. The ``*_ns``
+    fields are ``time.perf_counter_ns`` stamps of the batch's life: its
+    requests taken (set by the service), assembled, the dispatch call
+    returned, outputs ready on the device, and outputs copied and sliced
+    per request (``route``)."""
 
     bucket: Bucket
     requests: list[StreamRequest]
     spans: list[tuple[int, int]]
     chunk: object  # uint8 [chunk_bytes] (utf8) or {label,dense,sparse,valid} dict
+    id: int = dataclasses.field(default_factory=lambda: next(_batch_ids))
+    taken_ns: int | None = None
+    assembled_ns: int | None = None
+    dispatched_ns: int | None = None
+    ready_ns: int | None = None
+    routed_ns: int | None = None
 
     @property
     def n_rows(self) -> int:
         return self.spans[-1][1] if self.spans else 0
+
+    @property
+    def request_bytes(self) -> int:
+        return sum(r.n_bytes for r in self.requests)
+
+    @property
+    def bucket_bytes(self) -> int:
+        """The utf8 chunk's byte capacity; 0 for a binary chunk, whose
+        requests carry 0 bytes too."""
+        return self.bucket.chunk_bytes if isinstance(self.chunk, np.ndarray) else 0
 
 
 class MicroBatchScheduler:
@@ -227,9 +269,9 @@ class MicroBatchScheduler:
         byte-fits; smaller values trade buffer memory for the chance that
         the byte axis, not the row axis, picks the bucket.
       registry: the :class:`repro.obs.Registry` the packing metrics land
-        in (bucket occupancy / padding-waste histograms, the recompile
-        counter). The service passes its own; standalone schedulers get
-        a private one.
+        in (exact valid/bucket row and request/bucket byte totals, whose
+        ratios are the row and byte fill; the recompile counter). The
+        service passes its own; standalone schedulers get a private one.
     """
 
     def __init__(
@@ -249,11 +291,17 @@ class MicroBatchScheduler:
         self._c_batches = self.registry.counter(
             "stream.batches_total", "dispatched micro-batches"
         )
-        self._h_occupancy = self.registry.histogram(
-            "stream.bucket_occupancy", "valid rows / bucket capacity per batch"
+        self._c_valid_rows = self.registry.counter(
+            "stream.valid_rows_total", "request rows packed into buckets"
         )
-        self._h_padding = self.registry.histogram(
-            "stream.padding_rows", "wasted (padded) rows per batch"
+        self._c_bucket_rows = self.registry.counter(
+            "stream.bucket_rows_total", "bucket row capacity dispatched"
+        )
+        self._c_request_bytes = self.registry.counter(
+            "stream.request_bytes_total", "utf8 request bytes packed into buckets"
+        )
+        self._c_bucket_bytes = self.registry.counter(
+            "stream.bucket_bytes_total", "utf8 bucket byte capacity dispatched"
         )
         # Steady-state shape discipline, as a first-class signal: any
         # executable compiled past warmup increments this (the
@@ -377,8 +425,8 @@ class MicroBatchScheduler:
         nbytes = sum(r.n_bytes for r in requests)
         bucket = self.select_bucket(row, nbytes)
         self._c_batches.add(1)
-        self._h_occupancy.observe(row / bucket.rows)
-        self._h_padding.observe(bucket.rows - row)
+        self._c_valid_rows.add(row)
+        self._c_bucket_rows.add(bucket.rows)
 
         if self.config.input_format == "utf8":
             chunk = np.zeros(bucket.chunk_bytes, dtype=np.uint8)
@@ -404,7 +452,13 @@ class MicroBatchScheduler:
                 "sparse": sparse,
                 "valid": np.arange(cap) < row,
             }
-        return MicroBatch(bucket=bucket, requests=requests, spans=spans, chunk=chunk)
+        batch = MicroBatch(bucket=bucket, requests=requests, spans=spans, chunk=chunk)
+        for r in requests:
+            r.batch_id = batch.id
+        self._c_request_bytes.add(nbytes)
+        self._c_bucket_bytes.add(batch.bucket_bytes)
+        batch.assembled_ns = time.perf_counter_ns()
+        return batch
 
     # -- execution ----------------------------------------------------- #
     def dispatch(self, batch: MicroBatch) -> schema_lib.ProcessedBatch:
@@ -421,20 +475,29 @@ class MicroBatchScheduler:
         grew = batch.bucket.transform.compile_cache_size() - before
         if grew > 0:
             self._c_recompiles.add(grew)
+        batch.dispatched_ns = time.perf_counter_ns()
         return out
 
     def route(self, batch: MicroBatch, out: schema_lib.ProcessedBatch) -> list[dict]:
-        """Block on the device result and slice it per request (batch
-        order). The caller finishes the requests — the service records
+        """Block on the device result, stamp ``ready_ns``; copy it to the
+        host and slice it per request (batch order), stamp ``routed_ns``.
+        The two phases are the ``stream/ready`` and ``stream/route``
+        spans. The caller finishes the requests — the service records
         latency *before* unblocking waiters, so a metrics reset right
         after ``result()`` returns can never lose the record."""
-        label = np.asarray(out.label)
-        dense = np.asarray(out.dense)
-        sparse = np.asarray(out.sparse)
-        return [
-            {"label": label[lo:hi], "dense": dense[lo:hi], "sparse": sparse[lo:hi]}
-            for (lo, hi) in batch.spans
-        ]
+        with obs.span("stream/ready", cat="stream", batch=batch.id):
+            jax.block_until_ready(out)
+        batch.ready_ns = time.perf_counter_ns()
+        with obs.span("stream/route", cat="stream", batch=batch.id):
+            label = np.asarray(out.label)
+            dense = np.asarray(out.dense)
+            sparse = np.asarray(out.sparse)
+            results = [
+                {"label": label[lo:hi], "dense": dense[lo:hi], "sparse": sparse[lo:hi]}
+                for (lo, hi) in batch.spans
+            ]
+        batch.routed_ns = time.perf_counter_ns()
+        return results
 
     # -- vocab + compile bookkeeping ----------------------------------- #
     @property

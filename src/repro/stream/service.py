@@ -31,7 +31,13 @@ request stream:
     (kernels/fused_decode_vocab) — and the resulting delta merges in
     through the same refresh path;
   * **graceful drain/shutdown** — ``drain`` waits for every accepted
-    request; ``stop`` drains then joins the loop (idempotent).
+    request; ``stop`` drains then joins the loop (idempotent);
+  * **records** — requests and batches carry ids and
+    ``perf_counter_ns`` stamps (submit, taken; taken, assembled,
+    dispatched, ready, routed). When a batch is routed the loop writes
+    one ``stream/request`` record per request (submit → routed) and one
+    ``stream/batch`` record (taken → routed) into ``obs.tracer()``;
+    requests answered from the cache leave none.
 
 Determinism contract: for any interleaving of requests whose rows
 concatenate to a reference dataset, the per-request outputs reassemble
@@ -91,7 +97,7 @@ class StreamingPreprocessService:
       poll_s: loop idle poll interval.
       registry: the :class:`repro.obs.Registry` every service signal
         lands in (request metrics, stall buckets, queue gauges, packing
-        histograms, recompile counter — ONE ``registry.snapshot()`` is
+        counters, recompile counter — ONE ``registry.snapshot()`` is
         the full service view). Default: a private registry per service,
         so concurrent services never mix numbers.
       finalizer: how the service turns the merged state into the serving
@@ -319,7 +325,7 @@ class StreamingPreprocessService:
                 raise RuntimeError("streaming service failed") from self._error
             with self._cond:
                 self._outstanding += 1
-            req.submit_t = time.perf_counter()
+            req.submit_ns = time.perf_counter_ns()
             self.metrics.note_submit(req.submit_t)
             try:
                 # The put blocks while the ingress is full — that IS the
@@ -509,6 +515,7 @@ class StreamingPreprocessService:
                         gathered = self._gather(block=True)
                 else:
                     gathered = self._gather(block=False)
+                taken_ns = time.perf_counter_ns()
                 self._g_qdepth.set(self._ingress.qsize())
                 self._stall.lap("queue_wait")
                 # Cache consult happens HERE — in the loop thread, after
@@ -528,11 +535,15 @@ class StreamingPreprocessService:
                         "stream/assemble", cat="stream", requests=len(gathered)
                     ):
                         batch = self.scheduler.assemble(gathered)
+                    batch.taken_ns = taken_ns
                     self._stall.lap("host_assembly")
                     # async dispatch: device starts on batch i+1's upload +
                     # transform while we still hold batch i's futures
                     with obs.span(
-                        "stream/dispatch", cat="stream", bucket_rows=batch.bucket.rows
+                        "stream/dispatch",
+                        cat="stream",
+                        batch=batch.id,
+                        bucket_rows=batch.bucket.rows,
                     ):
                         nxt = (batch, self.scheduler.dispatch(batch))
                     self._stall.lap("device_dispatch")
@@ -540,12 +551,8 @@ class StreamingPreprocessService:
                         self._c_overlap.add(time.perf_counter() - t_host)
                     gathered = []
                 if inflight is not None:
-                    with obs.span(
-                        "device/wait",
-                        cat="stream",
-                        bucket_rows=inflight[0].bucket.rows,
-                    ):
-                        self._complete(*inflight)
+                    # stream/ready + stream/route spans (scheduler.route)
+                    self._complete(*inflight)
                     self._stall.lap("device_dispatch")
                     inflight = None
                 inflight = nxt
@@ -663,6 +670,7 @@ class StreamingPreprocessService:
         rows = nbytes = 0
         if self._carry is not None:
             r, self._carry = self._carry, None
+            r.taken_ns = time.perf_counter_ns()
             reqs.append(r)
             rows, nbytes = r.n_rows, r.n_bytes
         while True:
@@ -675,6 +683,7 @@ class StreamingPreprocessService:
             except queue.Empty:
                 return reqs
             if self.scheduler.fits(rows, nbytes, r):
+                r.taken_ns = time.perf_counter_ns()
                 reqs.append(r)
                 rows += r.n_rows
                 nbytes += r.n_bytes
@@ -702,3 +711,41 @@ class StreamingPreprocessService:
         with self._cond:
             self._outstanding -= len(batch.requests)
             self._cond.notify_all()
+        self._record(batch)
+
+    def _record(self, batch) -> None:
+        """Write one routed batch's records into ``obs.tracer()``: a
+        ``stream/request`` per request (submit → routed) and one
+        ``stream/batch`` (taken → routed), every stamp on the tracer's
+        ``perf_counter_ns`` clock. Nothing while the tracer is off."""
+        tr = obs.tracer()
+        if not tr.enabled:
+            return
+        end = batch.routed_ns
+        for r in batch.requests:
+            tr.complete(
+                "stream/request",
+                r.submit_ns,
+                end,
+                cat="stream",
+                id=r.id,
+                batch=batch.id,
+                rows=r.n_rows,
+                bytes=r.n_bytes,
+                taken=r.taken_ns,
+            )
+        tr.complete(
+            "stream/batch",
+            batch.taken_ns,
+            end,
+            cat="stream",
+            id=batch.id,
+            bucket_rows=batch.bucket.rows,
+            bucket_bytes=batch.bucket_bytes,
+            rows=batch.n_rows,
+            bytes=batch.request_bytes,
+            requests=len(batch.requests),
+            assembled=batch.assembled_ns,
+            dispatched=batch.dispatched_ns,
+            ready=batch.ready_ns,
+        )

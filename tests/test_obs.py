@@ -1,9 +1,10 @@
-"""Observability layer: tracer/registry/stall units, trace schema, and
-the non-semantic guarantee — instrumentation (including stage spans)
-never changes a single output bit on any engine."""
+"""Observability layer: tracer/registry/stall units, trace schema, the
+service's request and batch records, and the non-semantic guarantee —
+instrumentation never changes a single output bit on any engine."""
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -20,16 +21,13 @@ from repro.stream import metrics as metrics_lib
 
 @pytest.fixture
 def instrumented():
-    """Enable tracing + stage spans for one test, restoring the global
-    toggles (and draining the global tracer ring) afterwards."""
+    """Enable tracing for one test, restoring the global toggle (and
+    draining the global tracer ring) afterwards."""
     was_enabled = obs.enabled()
-    was_stage = obs.stage_spans()
     obs.enable()
-    obs.set_stage_spans(True)
     obs.tracer().reset()
     yield obs.tracer()
     obs.tracer().reset()
-    obs.set_stage_spans(was_stage)
     if not was_enabled:
         obs.disable()
 
@@ -264,33 +262,51 @@ def _run_offline(buf, schema):
 
 
 def test_tracing_and_stage_spans_non_semantic(criteo_small, instrumented):
-    """The acceptance pin: tracing enabled + stage spans (split decode
-    dispatch) produce byte-for-byte the outputs of the uninstrumented
-    run — loop-① state included."""
+    """The acceptance pin: tracing enabled produces byte-for-byte the
+    outputs of the uninstrumented run — loop-① state included — and
+    runs the same program: one dispatch per chunk and loop, no separate
+    decode stage."""
     buf, _, cfg = criteo_small
     obs.disable()
-    obs.set_stage_spans(False)
     ref = _run_offline(buf, cfg.schema)
+    assert obs.tracer().events() == []
     obs.enable()
-    obs.set_stage_spans(True)
     got = _run_offline(buf, cfg.schema)
     for r, g in zip(ref, got):
         np.testing.assert_array_equal(r, g)
     # and the instrumented run actually recorded the span hierarchy
     names = {e["name"] for e in obs.tracer().events()}
-    assert {"loop1/chunk", "loop2/chunk", "decode", "vocab_update"} <= names
+    assert {"loop1/chunk", "loop2/chunk"} <= names
+    assert not names & {"decode", "vocab_update", "transform"}
 
 
 def test_stage_span_labels_carry_tier_and_route(criteo_small, instrumented):
+    """Each chunk of each loop leaves one labelled span, and nothing is
+    nested under it: the route and tier labels name the code path the
+    chunk's single dispatch took."""
     buf, _, cfg = criteo_small
     _run_offline(buf, cfg.schema)
+    n_chunks = len(list(synth.chunk_stream(buf, 16384)))
+    events = obs.tracer().events()
     by_name = {}
-    for e in obs.tracer().events():
-        by_name.setdefault(e["name"], e)
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
     for name in ("loop1/chunk", "loop2/chunk"):
-        args = by_name[name]["args"]
+        # one span per chunk of each pass (run_stream repeats loop 1)
+        assert len(by_name[name]) % n_chunks == 0 and by_name[name]
+        args = by_name[name][0]["args"]
         assert args["engine"] == "piper"
         assert "tier" in args and "route" in args
+        for span in by_name[name]:
+            inside = [
+                e
+                for e in events
+                if e is not span
+                and e["tid"] == span["tid"]
+                and span["ts"] <= e["ts"]
+                and e["ts"] + e.get("dur", 0) <= span["ts"] + span["dur"]
+            ]
+            assert inside == []
     doc = obs.tracer().to_chrome()
     assert trace_lib.validate_trace(doc) == []
 
@@ -346,8 +362,10 @@ def test_service_stall_report_sums_to_wall(criteo_small):
     # and the service's registry carries the queue/packing instruments
     snap = svc.registry.snapshot()
     assert snap["stream.batches_total"]["value"] > 0
-    assert snap["stream.bucket_occupancy"]["count"] > 0
-    assert 0.0 < snap["stream.bucket_occupancy"]["mean"] <= 1.0
+    assert snap["stream.valid_rows_total"]["value"] == 20 * 8
+    assert 0 < snap["stream.valid_rows_total"]["value"] <= snap["stream.bucket_rows_total"]["value"]
+    assert 0 < snap["stream.request_bytes_total"]["value"] <= snap["stream.bucket_bytes_total"]["value"]
+    assert "stream.bucket_occupancy" not in snap and "stream.padding_rows" not in snap
 
 
 def test_service_metrics_is_registry_view_and_bounded():
@@ -367,3 +385,198 @@ def test_service_metrics_is_registry_view_and_bounded():
     m.reset()
     assert m.snapshot()["requests"] == 0
     assert r.get("stream.requests_total").value == 0
+
+
+# --------------------------------------------------------------------- #
+# explicit-stamp records and clock anchors
+# --------------------------------------------------------------------- #
+
+
+def test_tracer_complete_records_explicit_stamps():
+    tr = trace_lib.Tracer()
+    t0 = tr.epoch_ns + 5_000
+    tr.complete("stream/request", t0, t0 + 2_500, cat="stream", id=7, taken=t0 + 1_000)
+    (ev,) = tr.events()
+    assert ev["ph"] == "X" and ev["cat"] == "stream"
+    assert ev["ts"] == pytest.approx(5.0) and ev["dur"] == pytest.approx(2.5)
+    assert tr.epoch_ns + ev["ts"] * 1e3 == pytest.approx(t0)
+    assert ev["args"] == {"id": 7, "taken": t0 + 1_000}
+    assert trace_lib.validate_trace(tr.to_chrome()) == []
+    tr.enabled = False
+    tr.complete("stream/request", t0, t0 + 1)
+    assert len(tr.events()) == 1
+
+
+class _FakeAnnotation:
+    names: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.names.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_tracer_anchors_only_while_a_profiler_session_runs(monkeypatch):
+    """The first span of a session leaves an ``obs/clock/<ns>`` anchor
+    carrying the tracer's clock, then at most one per
+    ``ANCHOR_EVERY_NS``; with no session, none."""
+    _FakeAnnotation.names = []
+    session = {"on": False}
+    tr = trace_lib.Tracer()
+    tr._annotation = _FakeAnnotation
+    tr._profiler_active = lambda: session["on"]
+    with tr.span("a/b"):
+        pass
+    tr.complete("stream/batch", tr.epoch_ns, tr.epoch_ns + 1)
+    anchors = [n for n in _FakeAnnotation.names if n.startswith(trace_lib.ANCHOR_PREFIX)]
+    assert anchors == []
+    session["on"] = True
+    before = time.perf_counter_ns()
+    for _ in range(3):
+        with tr.span("a/b"):
+            pass
+    anchors = [n for n in _FakeAnnotation.names if n.startswith(trace_lib.ANCHOR_PREFIX)]
+    assert len(anchors) == 1  # throttled
+    assert int(anchors[0][len(trace_lib.ANCHOR_PREFIX) :]) >= before
+    monkeypatch.setattr(trace_lib, "ANCHOR_EVERY_NS", 0)
+    tr.complete("stream/batch", tr.epoch_ns, tr.epoch_ns + 1)
+    anchors = [n for n in _FakeAnnotation.names if n.startswith(trace_lib.ANCHOR_PREFIX)]
+    assert len(anchors) == 2
+    # a new session anchors at once, whatever the spacing
+    monkeypatch.setattr(trace_lib, "ANCHOR_EVERY_NS", 10**18)
+    session["on"] = False
+    tr.complete("stream/batch", tr.epoch_ns, tr.epoch_ns + 1)
+    session["on"] = True
+    tr.complete("stream/batch", tr.epoch_ns, tr.epoch_ns + 1)
+    anchors = [n for n in _FakeAnnotation.names if n.startswith(trace_lib.ANCHOR_PREFIX)]
+    assert len(anchors) == 3
+
+
+def test_tracer_without_profiler_bridge_never_anchors():
+    tr = trace_lib.Tracer(annotate=False)
+    assert tr._profiler_active is None
+    with tr.span("a/b"):
+        pass
+    assert [e["name"] for e in tr.events()] == ["a/b"]
+
+
+# --------------------------------------------------------------------- #
+# service: per-request and per-batch records
+# --------------------------------------------------------------------- #
+
+
+def _service(criteo_small, **kw):
+    buf, _, cfg = criteo_small
+    pc = P.PipelineConfig(schema=cfg.schema)
+    state = P.PiperPipeline(pc).build_state_stream(synth.chunk_stream(buf, 16384))
+    return StreamingPreprocessService(pc, state, bucket_rows=(32, 128), **kw)
+
+
+def _records(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def _serve_burst(criteo_small, sizes):
+    """Submit requests of ``sizes`` rows back to back; returns the
+    handles (every one answered) and the service."""
+    buf, _, _ = criteo_small
+    spans = synth.row_spans(buf)
+    svc = _service(criteo_small, queue_depth=64)
+    handles, row = [], 0
+    with svc:
+        for n in sizes:
+            handles.append(svc.submit(buf[spans[row, 0] : spans[row + n - 1, 1]]))
+            row += n
+        svc.drain(timeout=120)
+    for h, n in zip(handles, sizes):
+        assert h.result()["label"].shape[0] == n
+    return handles, svc
+
+
+SIZES = [8, 40, 1, 32, 100, 5, 17, 64, 3, 90]
+
+
+def test_service_records_ids_unique_and_linked(criteo_small, instrumented):
+    handles, _ = _serve_burst(criteo_small, SIZES)
+    events = obs.tracer().events()
+    reqs = _records(events, "stream/request")
+    batches = _records(events, "stream/batch")
+    assert len(reqs) == len(SIZES)
+    assert [r["args"]["id"] for r in reqs] == [h.id for h in handles]  # FIFO
+    assert len({h.id for h in handles}) == len(handles)
+    assert len({b["args"]["id"] for b in batches}) == len(batches) >= 2
+    by_batch = {b["args"]["id"]: b for b in batches}
+    for r, h in zip(reqs, handles):
+        assert r["args"]["batch"] == h.batch_id and h.batch_id in by_batch
+        assert r["args"]["rows"] == h.n_rows and r["args"]["bytes"] == h.n_bytes
+    for bid, b in by_batch.items():
+        members = [r for r in reqs if r["args"]["batch"] == bid]
+        assert b["args"]["requests"] == len(members)
+        assert b["args"]["rows"] == sum(r["args"]["rows"] for r in members)
+        assert b["args"]["bytes"] == sum(r["args"]["bytes"] for r in members)
+        assert b["args"]["bucket_rows"] in (32, 128) and b["args"]["rows"] <= b["args"]["bucket_rows"]
+    # ids grow with time: batches are routed in the order they were built
+    assert [b["args"]["id"] for b in batches] == sorted(by_batch)
+
+
+def test_service_record_phases_are_ordered_and_sum(criteo_small, instrumented):
+    """submit ≤ taken ≤ batch taken ≤ assembled ≤ dispatched ≤ ready ≤
+    routed for every request, and the phases add up to its record's
+    length (submit → routed) exactly."""
+    handles, _ = _serve_burst(criteo_small, SIZES)
+    tr = obs.tracer()
+    events = tr.events()
+    to_ns = lambda e, k: round(tr.epoch_ns + e[k] * 1e3)
+    batches = {b["args"]["id"]: b for b in _records(events, "stream/batch")}
+    for r, h in zip(_records(events, "stream/request"), handles):
+        b = batches[r["args"]["batch"]]
+        a = b["args"]
+        submit, routed = to_ns(r, "ts"), round(tr.epoch_ns + (r["ts"] + r["dur"]) * 1e3)
+        assert abs(submit - h.submit_ns) <= 1 and abs(routed - to_ns(b, "ts") - b["dur"] * 1e3) <= 2
+        stamps = [h.submit_ns, r["args"]["taken"], to_ns(b, "ts"), a["assembled"], a["dispatched"], a["ready"], routed]
+        assert all(x <= y + 1 for x, y in zip(stamps, stamps[1:])), stamps
+        phases = [y - x for x, y in zip(stamps, stamps[1:])]
+        assert sum(phases) == pytest.approx(r["dur"] * 1e3, abs=2)
+
+
+def test_service_fill_counters_by_hand(criteo_small, instrumented):
+    """On a (32, 128) ladder, one request a batch: each batch takes the
+    smallest bucket holding its rows, and the four counters are sums of
+    rows, capacities and bytes computed here."""
+    buf, _, cfg = criteo_small
+    spans = synth.row_spans(buf)
+    svc = _service(criteo_small)
+    sizes, row, nbytes = [8, 40, 1, 32, 100, 33], 0, []
+    with svc:
+        for n in sizes:
+            payload = buf[spans[row, 0] : spans[row + n - 1, 1]]
+            nbytes.append(int(payload.size))
+            svc.submit(payload).result(timeout=120)
+            row += n
+    snap = svc.registry.snapshot()
+    cap = {32: 32, 128: 128}
+    bucket = [32 if n <= 32 else 128 for n in sizes]
+    per_row = cfg.schema.max_row_bytes
+    assert snap["stream.batches_total"]["value"] == len(sizes)
+    assert snap["stream.valid_rows_total"]["value"] == sum(sizes)
+    assert snap["stream.bucket_rows_total"]["value"] == sum(cap[b] for b in bucket)
+    assert snap["stream.request_bytes_total"]["value"] == sum(nbytes)
+    assert snap["stream.bucket_bytes_total"]["value"] == sum(b * per_row for b in bucket)
+    # the batch records carry the same numbers
+    batches = _records(obs.tracer().events(), "stream/batch")
+    assert sum(b["args"]["bucket_bytes"] for b in batches) == snap["stream.bucket_bytes_total"]["value"]
+    assert sum(b["args"]["bytes"] for b in batches) == sum(nbytes)
+
+
+def test_service_records_nothing_when_disabled(criteo_small, instrumented):
+    obs.disable()
+    handles, svc = _serve_burst(criteo_small, SIZES[:4])
+    assert obs.tracer().events() == []
+    # the stamps and counters are the program's own and stay
+    assert all(h.taken_ns >= h.submit_ns for h in handles)
+    assert svc.registry.snapshot()["stream.valid_rows_total"]["value"] == sum(SIZES[:4])
