@@ -34,10 +34,21 @@ collapses the chain:
     dim* plus an input/output alias, generalizing the VMEM kernel's
     grid-carry machinery — stays resident in VMEM and is written back
     to HBM exactly once when the grid advances to the next slab. The
-    Pallas pipeline double-buffers the slab DMAs against compute.
-    Entries whose modded value falls outside the current slab are
-    skipped by a scalar branch, so loop ① stays ONE fused dispatch at
-    ANY ``vocab_range``.
+    Pallas pipeline double-buffers the slab DMAs against compute, and
+    loop ① stays ONE fused dispatch at ANY ``vocab_range``.
+
+    A pre-pass in the same program reduces the chunk modulo
+    ``vocab_range`` (vectorized), lays each row tile out column by
+    column as one int32 key ``value << row_bits | row`` (or the values
+    with their positions as a second operand where that key would not
+    fit 31 bits) and, with more than one slab, sorts each column of the
+    tile by value (which also puts duplicates side by side, earliest
+    row first). It also counts where each slab's run starts, per tile
+    and column. Grid step ``(s, t)`` then runs, for each static column,
+    a loop over just the run of tile ``t`` in slab ``s``: every entry is
+    visited once a chunk, with no modulus and no skip in the kernel.
+    With a single slab (the vmem tier with tracked counts) nothing is
+    sorted, and each column's run is the whole column of the tile.
 
 XLA-fallback tier (degenerate widths where not even one 128-lane slab
 per column fits the slab budget) — there is no kernel: the modulus and
@@ -53,11 +64,13 @@ through Mosaic; each answers a refusal of the TPU compiler
 
   * the scalars come from SMEM: the sparse tile arrives flattened
     (``[row_block * n_cols]``) and the positions as ``[row_block]``,
-    both as SMEM blocks. Mosaic cannot read a scalar out of a vector
-    (``modded[i, c]`` lowers to ``dynamic_slice``), and a ``(1,
-    row_block)`` VMEM block of positions breaks the (8, 128) tiling;
-  * the modulus runs on the scalar in int32 arithmetic (Mosaic has no
-    scalar bitcast to uint32);
+    both as SMEM blocks (so do the slab kernel's entries and each
+    tile's slab offsets, padded to :data:`SMEM_GRAIN` words). Mosaic
+    cannot read a scalar out of a vector (``modded[i, c]`` lowers to
+    ``dynamic_slice``), and a ``(1, row_block)`` VMEM block of positions
+    breaks the (8, 128) tiling;
+  * the VMEM kernel's modulus runs on the scalar in int32 arithmetic
+    (Mosaic has no scalar bitcast to uint32);
   * the RMW touches the 128-lane window that holds the entry, at a
     static column and a dynamic 128-aligned lane offset, and selects
     the entry's lane. Mosaic stores no scalars to VMEM and loads no
@@ -83,6 +96,11 @@ from repro.core import vocab as vocab_lib
 # The RMW window: one vector register row. State widths and slab widths
 # are multiples of it.
 LANES = 128
+# A packed entry key (value << row bits | row) must stay a non-negative
+# int32.
+KEY_BITS = 31
+# 1-D SMEM blocks are whole arrays or multiples of this many words.
+SMEM_GRAIN = 1024
 
 
 def _u32_mod(h: jnp.ndarray, vocab_range: int) -> jnp.ndarray:
@@ -118,15 +136,17 @@ def _compiler_params(n_planes: int, n_cols: int, width: int):
     )
 
 
+def _smem_block(n: int, index_map):
+    return pl.BlockSpec((n,), index_map, memory_space=pltpu.SMEM)
+
+
 def _smem_specs(rows: int, n_cols: int, row_block: int, index_map):
     """SMEM blocks of the flattened sparse tile and of the positions."""
     if rows % row_block:
         raise ValueError(f"rows ({rows}) must divide by row_block ({row_block})")
     return [
-        pl.BlockSpec(
-            (row_block * n_cols,), index_map, memory_space=pltpu.SMEM
-        ),
-        pl.BlockSpec((row_block,), index_map, memory_space=pltpu.SMEM),
+        _smem_block(row_block * n_cols, index_map),
+        _smem_block(row_block, index_map),
     ]
 
 
@@ -204,8 +224,64 @@ def fused_genvocab(
     return out[:, :vocab_range]
 
 
+def key_row_bits(padded_range: int, row_block: int) -> int | None:
+    """Bits of the row index in a packed entry key ``value << bits |
+    row``, or None where such a key would not stay a non-negative int32
+    (then the values travel with their positions as a second operand)."""
+    bits = (row_block - 1).bit_length()
+    return bits if (padded_range - 1) >> (KEY_BITS - bits) == 0 else None
+
+
+def offsets_width(n_cols: int, n_slabs: int) -> int:
+    """Words of one row tile's slab offsets (``n_cols × (n_slabs + 1)``),
+    padded to the SMEM block grain."""
+    return -(-(n_cols * (n_slabs + 1)) // SMEM_GRAIN) * SMEM_GRAIN
+
+
+def _entries_by_slab(
+    sparse, pos, *, vocab_range, slab_range, n_slabs, row_block, row_bits
+):
+    """The pre-pass of the slab kernel: each row tile's entries reduced
+    modulo ``vocab_range``, laid out column by column and, with more
+    than one slab, sorted by value. Returns the flat entries (``[n_tiles
+    × n_cols × row_block]``, a tile's column ``c`` at ``[c·row_block,
+    (c+1)·row_block)`` of its block), the positions (``[rows]`` read by
+    the packed key's row, or laid out beside the values), and per tile
+    the ``n_slabs + 1`` starts of each column's slab runs, as indices
+    into the tile's block (``[n_tiles × offsets_width]``)."""
+    rows, n_cols = sparse.shape
+    n_tiles = rows // row_block
+    v = _u32_mod(sparse, vocab_range)
+    v = v.reshape(n_tiles, row_block, n_cols).transpose(0, 2, 1)
+    if row_bits is not None:
+        # one int32 key; sorted, duplicates lie earliest row first
+        row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
+        entries = (v << row_bits) | row
+        if n_slabs > 1:
+            entries = jax.lax.sort(entries, dimension=2)
+            v = entries >> row_bits
+    else:
+        p = jnp.broadcast_to(pos.reshape(n_tiles, 1, row_block), v.shape)
+        if n_slabs > 1:
+            v, p = jax.lax.sort((v, p), dimension=2, num_keys=1)
+        entries, pos = v, p
+    bounds = jnp.arange(n_slabs + 1, dtype=jnp.int32) * slab_range
+    starts = jnp.sum(
+        v[:, :, None, :] < bounds[:, None], axis=-1, dtype=jnp.int32
+    ) + (jnp.arange(n_cols, dtype=jnp.int32) * row_block)[:, None]
+    starts = starts.reshape(n_tiles, n_cols * (n_slabs + 1))
+    pad = offsets_width(n_cols, n_slabs) - starts.shape[1]
+    starts = jnp.pad(starts, ((0, 0), (0, pad)))
+    return entries.reshape(-1), pos.reshape(-1), starts.reshape(-1)
+
+
 def _fused_genvocab_slab_kernel(
-    *refs, n_cols: int, vocab_range: int, slab_range: int, track_counts: bool
+    *refs,
+    n_cols: int,
+    n_slabs: int,
+    slab_range: int,
+    track_counts: bool,
+    row_bits: int | None,
 ):
     # grid = (n_slabs, n_row_tiles), slab index outermost: for a fixed
     # slab the row-tile dim iterates innermost, so the slab's state (and
@@ -213,12 +289,13 @@ def _fused_genvocab_slab_kernel(
     # resident in VMEM across the whole chunk and is written back to HBM
     # once, when the slab index advances.
     if track_counts:
-        (sparse_ref, pos_ref, state_in_ref, counts_in_ref,
+        (entries_ref, pos_ref, off_ref, state_in_ref, counts_in_ref,
          state_ref, counts_ref) = refs
     else:
-        sparse_ref, pos_ref, state_in_ref, state_ref = refs
+        entries_ref, pos_ref, off_ref, state_in_ref, state_ref = refs
         counts_in_ref = counts_ref = None
-    lo = pl.program_id(0) * slab_range
+    s = pl.program_id(0)
+    lo = s * slab_range
 
     @pl.when(pl.program_id(1) == 0)
     def _init():  # first row tile of this slab: seed from the HBM block
@@ -228,26 +305,28 @@ def _fused_genvocab_slab_kernel(
 
     never = jnp.int32(vocab_lib.NEVER)
 
-    def row_body(i, _):
-        p = pos_ref[i]
-        for c in range(n_cols):
-            # Modulus by the TRUE vocab_range (the state may be padded to
-            # a slab multiple; the pad region is never a target).
-            local = _u32_mod(sparse_ref[i * n_cols + c], vocab_range) - lo
+    # Each column's entries of this tile that fall in slab s form one
+    # run of the tile's block: walk that run alone.
+    for c in range(n_cols):
 
-            @pl.when((local >= 0) & (local < slab_range))
-            def _hit(c=c, local=local):
-                # the FPGA's II=2 RMW, streamed slab by slab
-                _rmw(state_ref, c, local, lambda w: jnp.minimum(w, p))
-                if track_counts:
-                    # p == NEVER marks padding/invalid/past-ceiling rows —
-                    # they drop from the counts exactly as from the state.
-                    inc = jnp.where(p != never, 1, 0)
-                    _rmw(counts_ref, c, local, lambda w: w + inc)
+        def entry(j, _, c=c):
+            k = entries_ref[j]
+            if row_bits is None:
+                local, p = k - lo, pos_ref[j]
+            else:
+                local = (k >> row_bits) - lo
+                p = pos_ref[k & ((1 << row_bits) - 1)]
+            # the FPGA's II=2 RMW, streamed slab by slab
+            _rmw(state_ref, c, local, lambda w: jnp.minimum(w, p))
+            if track_counts:
+                # p == NEVER marks padding/invalid/past-ceiling rows —
+                # they drop from the counts exactly as from the state.
+                inc = jnp.where(p != never, 1, 0)
+                _rmw(counts_ref, c, local, lambda w: w + inc)
+            return 0
 
-        return 0
-
-    jax.lax.fori_loop(0, pos_ref.shape[0], row_body, 0)
+        base = c * (n_slabs + 1) + s
+        jax.lax.fori_loop(off_ref[base], off_ref[base + 1], entry, 0)
 
 
 @functools.partial(
@@ -279,6 +358,11 @@ def fused_genvocab_slabs(
     vocab_range — the TRUE modulus range (≤ padded_range)
     → (updated first_pos, updated counts | None), same padded shapes.
 
+    A pre-pass in this same program (:func:`_entries_by_slab`) reduces
+    the entries and, with more than one slab, buckets each row tile's
+    entries by slab; grid step ``(s, t)`` walks only tile ``t``'s
+    entries in slab ``s``.
+
     ``state`` (and ``counts``) are donated-into: each slab block is
     aliased input→output, the same in-place convention as
     :func:`fused_genvocab`. ``row_block`` as there.
@@ -298,27 +382,44 @@ def fused_genvocab_slabs(
     if pos.shape != (rows,):
         raise ValueError(f"pos shape {pos.shape} != {(rows,)}")
     track_counts = counts is not None
+    row_bits = key_row_bits(padded_range, row_block)
+    entries, pos_in, offsets = _entries_by_slab(
+        sparse,
+        pos,
+        vocab_range=vocab_range,
+        slab_range=slab_range,
+        n_slabs=n_slabs,
+        row_block=row_block,
+        row_bits=row_bits,
+    )
+
+    def tile(s, r):  # the row tile's SMEM blocks, whatever the slab
+        return (r,)
+
+    in_specs = _smem_specs(rows, n_cols, row_block, tile)
+    if row_bits is None:  # positions laid out beside the values
+        in_specs[1] = _smem_block(row_block * n_cols, tile)
+    in_specs.append(_smem_block(offsets_width(n_cols, n_slabs), tile))
     slab_spec = pl.BlockSpec((n_cols, slab_range), lambda s, r: (0, s))
-    in_specs = _smem_specs(rows, n_cols, row_block, lambda s, r: (r,)) + [
-        slab_spec
-    ]
+    in_specs.append(slab_spec)
     out_shape = [jax.ShapeDtypeStruct((n_cols, padded_range), jnp.int32)]
-    operands = [sparse.reshape(-1), pos, state]
-    aliases = {2: 0}
+    operands = [entries, pos_in, offsets, state]
+    aliases = {3: 0}
     if track_counts:
         in_specs.append(slab_spec)
         out_shape.append(
             jax.ShapeDtypeStruct((n_cols, padded_range), jnp.int32)
         )
         operands.append(counts)
-        aliases[3] = 1
+        aliases[4] = 1
     out = pl.pallas_call(
         functools.partial(
             _fused_genvocab_slab_kernel,
             n_cols=n_cols,
-            vocab_range=vocab_range,
+            n_slabs=n_slabs,
             slab_range=slab_range,
             track_counts=track_counts,
+            row_bits=row_bits,
         ),
         grid=(n_slabs, rows // row_block),
         in_specs=in_specs,

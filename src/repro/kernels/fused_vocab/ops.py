@@ -22,7 +22,10 @@ Tier policy (paper §3.2, §4.4.6) — THREE tiers, graded by where the
     the slab block carried across the inner row-tile dim and written
     back when the slab advances — so loop ① keeps the single-fused-
     dispatch property at ANY ``vocab_range`` instead of dropping to the
-    unfused XLA oracle.
+    unfused XLA oracle. A pre-pass in the same program sorts each row
+    tile's entries by slab, so grid step ``(s, t)`` walks only tile
+    ``t``'s entries of slab ``s``: every entry is visited once a chunk,
+    not once per slab (with a single slab nothing is sorted).
 
   * **xla_fallback** — degenerate widths where not even one 128-lane
     slab per column fits the slab budget (thousands of vocab columns):
@@ -33,11 +36,12 @@ Tier policy (paper §3.2, §4.4.6) — THREE tiers, graded by where the
     differential-test oracle.
 
 All tiers are **bit-identical** to the unfused ``positive_modulus`` →
-``vocab.update`` chain: scatter-min is order-independent, padding rows
-carry ``NEVER`` positions (the min identity), out-of-slab lanes scatter
-the identity at local index 0, and the valid-row count advances exactly
-as ``vocab.update`` advances it (saturating at the int32 position
-ceiling — see ``vocab.positions``). When the state tracks occurrence
+``vocab.update`` chain: scatter-min (and the count's sum) is order-
+independent, so bucketing entries by slab changes no result; padding
+rows carry ``NEVER`` positions (the min identity), every entry updates
+exactly the one slab that holds its value, and the valid-row count
+advances exactly as ``vocab.update`` advances it (saturating at the
+int32 position ceiling — see ``vocab.positions``). When the state tracks occurrence
 counts, the vmem tier runs the slab kernel with a single resident slab
 so the counts ride the same dispatch.
 """
@@ -87,7 +91,11 @@ def vmem_accounting(
     hbm_slab tier (pass ``slab_range``). The carried entries are what
     the tier guards charge against :data:`FUSED_STATE_VMEM_BYTES` /
     :data:`SLAB_VMEM_BYTES`; the row tiles stream per grid step through
-    SMEM (``sparse_tile`` and ``pos_tile``). This
+    SMEM (``sparse_tile`` and ``pos_tile``; wherever the slab kernel
+    runs, the tile's entries arrive bucketed by slab with
+    ``offsets_tile``, the start of each column's run of each slab, and
+    the positions laid out beside the entries where a packed key would
+    not fit). This
     dict is the package's declared footprint — ``fused_vocab_tier``
     derives its decision from it, and ``repro.analysis.kernelcheck``
     asserts the two never disagree.
@@ -100,6 +108,12 @@ def vmem_accounting(
     }
     if track_counts:
         acct["counts_stack"] = n_cols * width * 4
+    if slab_range or track_counts:  # the slab kernel runs
+        sr = -(-min(width, vocab_range) // kernel.LANES) * kernel.LANES
+        n_slabs = -(-vocab_range // sr)
+        acct["offsets_tile"] = kernel.offsets_width(n_cols, n_slabs) * 4
+        if kernel.key_row_bits(n_slabs * sr, row_block) is None:
+            acct["pos_tile"] = row_block * n_cols * 4
     return acct
 
 

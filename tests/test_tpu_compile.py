@@ -74,17 +74,18 @@ def test_genvocab_vmem_tier_compiles_5k(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("planes", [1, 2], ids=["first_pos", "with_counts"])
-def test_genvocab_slab_tier_compiles_1m(one_chip, planes):
-    """Loop ①, 1M: [26, 1M] state streamed through VMEM slab by slab
-    (with the optional count plane too: two carried planes)."""
-    sr = fv_ops.default_slab_range(N_SPARSE, 1_000_000, track_counts=planes == 2)
-    width = -(-1_000_000 // sr) * sr
+def _compile_slabs(one_chip, vocab_range, planes, slab_range=None):
+    """Compile the slab kernel for ``[26, vocab_range]`` with one or two
+    carried planes; returns the optimized HLO text."""
+    sr = slab_range or fv_ops.default_slab_range(
+        N_SPARSE, vocab_range, track_counts=planes == 2
+    )
+    width = -(-vocab_range // sr) * sr
     state = _spec(one_chip, (N_SPARSE, width))
     counts = state if planes == 2 else None
-    hlo = _compile(
+    return _compile(
         lambda st, ct, sp, pos: fv_kernel.fused_genvocab_slabs(
-            st, ct, sp, pos, slab_range=sr, vocab_range=1_000_000,
+            st, ct, sp, pos, slab_range=sr, vocab_range=vocab_range,
             row_block=1024, interpret=False,
         ),
         state,
@@ -92,6 +93,30 @@ def test_genvocab_slab_tier_compiles_1m(one_chip, planes):
         _spec(one_chip, (ROWS, N_SPARSE)),
         _spec(one_chip, (ROWS,)),
     )
+
+
+@pytest.mark.parametrize("planes", [1, 2], ids=["first_pos", "with_counts"])
+def test_genvocab_slab_tier_compiles_1m(one_chip, planes):
+    """Loop ①, 1M: [26, 1M] state streamed through VMEM slab by slab
+    (with the optional count plane too: two carried planes)."""
+    assert "tpu_custom_call" in _compile_slabs(one_chip, 1_000_000, planes)
+
+
+@pytest.mark.parametrize("planes", [1, 2], ids=["first_pos", "with_counts"])
+def test_genvocab_slab_tier_compiles_10m(one_chip, planes):
+    """Loop ①, 10M: past the packed int32 key's range, so each tile's
+    values are sorted with their positions as a second operand and the
+    positions arrive as a whole entry tile in SMEM."""
+    sr = fv_ops.default_slab_range(N_SPARSE, 10_000_000, track_counts=planes == 2)
+    assert fv_kernel.key_row_bits(-(-10_000_000 // sr) * sr, 1024) is None
+    assert "tpu_custom_call" in _compile_slabs(one_chip, 10_000_000, planes)
+
+
+def test_genvocab_one_slab_compiles_5k_counts(one_chip):
+    """Loop ①, 5K with occurrence counts: the vmem tier runs the slab
+    kernel with one resident slab (each column's run the whole column)."""
+    width = -(-5000 // fv_kernel.LANES) * fv_kernel.LANES
+    hlo = _compile_slabs(one_chip, 5000, planes=2, slab_range=width)
     assert "tpu_custom_call" in hlo
 
 

@@ -255,6 +255,185 @@ def test_auto_tier_above_vmem_cutoff_uses_slabs():
 
 
 # --------------------------------------------------------------------- #
+# slab tier: entries bucketed by slab before the grid
+# --------------------------------------------------------------------- #
+
+
+def _in_range(rng, rows, n_cols, lo, hi):
+    """Hashes already in ``[lo, hi)`` (each its own modulus)."""
+    return jnp.asarray(rng.integers(lo, hi, size=(rows, n_cols)).astype(np.int32))
+
+
+def _case_one_slab_first(rng):
+    return _in_range(rng, 1500, 3, 0, 128), jnp.ones(1500, bool), 1000, 128
+
+
+def _case_one_slab_last(rng):
+    return _in_range(rng, 1500, 3, 896, 1000), jnp.ones(1500, bool), 1000, 128
+
+
+def _case_empty_slabs(rng):
+    # values only in slabs 1 and 5 of 8: the other six runs are empty
+    vals = np.where(
+        rng.random((1200, 3)) < 0.5,
+        rng.integers(128, 256, size=(1200, 3)),
+        rng.integers(640, 768, size=(1200, 3)),
+    ).astype(np.int32)
+    return jnp.asarray(vals), jnp.ones(1200, bool), 1000, 128
+
+
+def _case_padded_last_slab(rng):
+    # 1000 is no multiple of 384: three slabs, the last 232 wide + 152 pad
+    vals = _in_range(rng, 1100, 2, 600, 1000)
+    return vals, jnp.asarray(rng.random(1100) < 0.9), 1000, 384
+
+
+def _case_negative_hashes(rng):
+    # every hash has the top bit set: the uint32 modulus, not int32's
+    vals = rng.integers(-(2**31), 0, size=(1300, 3), dtype=np.int64)
+    return jnp.asarray(vals.astype(np.int32)), jnp.ones(1300, bool), 997, 256
+
+
+def _case_never_rows_partial_tile(rng):
+    # three row tiles: the middle one all invalid (NEVER), the last one
+    # 552 rows and 472 padding rows
+    valid = np.ones(2600, bool)
+    valid[1024:2048] = False
+    valid[2048:] = rng.random(552) < 0.5
+    return _hashes(rng, 2600, 2), jnp.asarray(valid), 1000, 256
+
+
+def _case_hot_key(rng):
+    # one hot value in slab 3 on most rows of every tile, the rest spread
+    # over every slab
+    vals = np.asarray(_in_range(rng, 2100, 3, 0, 1000))
+    hot = rng.random((2100, 3)) < 0.6
+    vals = np.where(hot, 400, vals).astype(np.int32)
+    return jnp.asarray(vals), jnp.asarray(rng.random(2100) < 0.8), 1000, 128
+
+
+def _case_single_slab(rng):
+    # slab_range ≥ vocab_range: one slab, walked as it comes
+    return _hashes(rng, 1500, 3), jnp.ones(1500, bool), 1000, 1024
+
+
+_BUCKET_CASES = {
+    "one_slab_first": _case_one_slab_first,
+    "one_slab_last": _case_one_slab_last,
+    "empty_slabs": _case_empty_slabs,
+    "padded_last_slab": _case_padded_last_slab,
+    "negative_hashes": _case_negative_hashes,
+    "never_rows_partial_tile": _case_never_rows_partial_tile,
+    "hot_key": _case_hot_key,
+    "single_slab": _case_single_slab,
+}
+
+
+def _check_bucketed(case, track_counts, seed=7):
+    """The forced slab dispatch ≡ ref.py, the unfused chain, the VMEM
+    tier and (tracked) the numpy counts, bit for bit; returns its slab
+    count."""
+    from repro.kernels.fused_vocab import ref as fv_ref
+
+    sparse, valid, vocab_range, slab_range = _BUCKET_CASES[case](
+        np.random.default_rng(seed)
+    )
+    n_cols = sparse.shape[1]
+    offset = 12345
+    got = ops.fused_vocab_update(
+        _fresh(n_cols, vocab_range, offset, track_counts),
+        sparse,
+        valid,
+        use_kernel=True,
+        slab_range=slab_range,
+    )
+    want = ops.fused_vocab_update(
+        _fresh(n_cols, vocab_range, offset, track_counts),
+        sparse,
+        valid,
+        use_kernel=False,
+    )
+    _assert_states_equal(got, want)
+    vmem = ops.fused_vocab_update(
+        _fresh(n_cols, vocab_range, offset, track_counts),
+        sparse,
+        valid,
+        use_kernel=True,
+    )
+    _assert_states_equal(got, vmem)
+    pos = vocab_lib.positions(jnp.int32(offset), sparse.shape[0], valid)
+    np.testing.assert_array_equal(
+        np.asarray(got.first_pos),
+        np.asarray(
+            fv_ref.fused_genvocab(
+                _fresh(n_cols, vocab_range).first_pos, sparse, pos
+            )
+        ),
+    )
+    if track_counts:
+        np.testing.assert_array_equal(
+            np.asarray(got.counts), _np_counts(sparse, valid, vocab_range)
+        )
+    return fv_ops.vocab_slab_count(
+        n_cols, vocab_range, slab_range=slab_range, track_counts=track_counts
+    )
+
+
+@pytest.mark.parametrize("track_counts", [False, True], ids=["first_pos", "counts"])
+@pytest.mark.parametrize("case", sorted(_BUCKET_CASES))
+def test_bucketed_slab_cases(case, track_counts):
+    """Entries bucketed by slab before the slab grid: every chunk shape
+    that moves where the runs start — all entries in the first or the
+    last slab, empty slabs, a padded last slab, hashes with the top bit
+    set, all-NEVER tiles and a partial last tile, a hot key across tiles
+    and slabs, and a single slab (each column's run the whole column,
+    nothing sorted) — with and without the count plane."""
+    n_slabs = _check_bucketed(case, track_counts)
+    assert (n_slabs > 1) == (case != "single_slab")
+
+
+@pytest.fixture
+def fresh_jit_caches():
+    """Clear JAX's caches around a test that steers a trace-time choice,
+    so neither it nor a later test runs a program traced the other way."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", ["hot_key", "never_rows_partial_tile", "negative_hashes"])
+def test_bucketed_slab_two_operand_sort(case, monkeypatch, fresh_jit_caches):
+    """Where a packed int32 key (value << row bits | row) would not fit,
+    the values sort with their positions as a second operand; narrowing
+    the key to 12 bits takes that path on small shapes."""
+    from repro.kernels.fused_vocab import kernel as fv_kernel
+
+    monkeypatch.setattr(fv_kernel, "KEY_BITS", 12)
+    assert fv_kernel.key_row_bits(1024, 1024) is None
+    assert fv_kernel.key_row_bits(4, 4) == 2
+    _check_bucketed(case, track_counts=True)
+
+
+def test_slab_offsets_in_declared_footprint():
+    """The bucketed tiles' SMEM blocks are in ``vmem_accounting``: the
+    per-tile slab offsets (padded to the SMEM grain), and the positions
+    as a whole entry tile where a packed key does not fit."""
+    from repro.kernels.fused_vocab import kernel as fv_kernel
+
+    sr = fv_ops.default_slab_range(26, 1_000_000)
+    acct = fv_ops.vmem_accounting(26, 1_000_000, slab_range=sr)
+    assert acct["offsets_tile"] == fv_kernel.offsets_width(26, 25) * 4 == 1024 * 4
+    assert acct["pos_tile"] == 1024 * 4  # packed key: 20 value + 10 row bits
+    sr10 = fv_ops.default_slab_range(26, 10_000_000)
+    wide = fv_ops.vmem_accounting(26, 10_000_000, slab_range=sr10)
+    assert wide["pos_tile"] == wide["sparse_tile"]
+    assert "offsets_tile" not in fv_ops.vmem_accounting(26, 5000)
+    # the vmem tier with counts runs the slab kernel with one slab
+    counted = fv_ops.vmem_accounting(26, 5000, track_counts=True)
+    assert counted["offsets_tile"] == fv_kernel.offsets_width(26, 1) * 4
+
+
+# --------------------------------------------------------------------- #
 # capped finalizers
 # --------------------------------------------------------------------- #
 
