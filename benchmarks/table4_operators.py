@@ -71,12 +71,13 @@ def main() -> None:
         vocab_lib.update(state, modded, jnp.ones(ROWS, bool))
     )
     table_dicts = [
-        {int(v): i for i, v in enumerate(np.argsort(np.asarray(vocab.table[c]))[: int(vocab.sizes[c])])}
+        {int(v): i for i, v in enumerate(np.argsort(np.asarray(vocab.column(c)))[: int(vocab.sizes[c])])}
         for c in range(schema.n_sparse)
     ]
     sec = time_fn(lambda: vocab_lib.lookup(vocab, modded))
     emit("table4/applyvocab/jnp_gather", sec, "HBM tier")
-    sec = time_fn(lambda: v_kernel.apply_vocab(vocab.table, modded.T, row_block=1000))
+    rect = vocab.layout.rect(vocab.table)
+    sec = time_fn(lambda: v_kernel.apply_vocab(rect, modded.T, row_block=1000))
     emit("table4/applyvocab/pallas_interpret", sec, "VMEM tier (alg check)")
 
     # Neg2Zero + Logarithm

@@ -104,24 +104,22 @@ def _abstract_batch(schema: schema_lib.TableSchema, rows: int):
 
 def _abstract_state(compiled):
     sds = jax.ShapeDtypeStruct
-    n = max(compiled.n_vocab_columns, 1)
+    lay = compiled.vocab_layout
     return vocab_lib.VocabState(
-        first_pos=sds((n, compiled.vocab_range), jnp.int32),
+        first_pos=sds((lay.entries,), jnp.int32),
         rows_seen=sds((), jnp.int32),
-        counts=(
-            sds((n, compiled.vocab_range), jnp.int32)
-            if compiled.track_counts
-            else None
-        ),
+        counts=sds((lay.entries,), jnp.int32) if compiled.track_counts else None,
+        layout=lay,
     )
 
 
 def _abstract_vocab(compiled):
     sds = jax.ShapeDtypeStruct
-    n = max(compiled.n_vocab_columns, 1)
+    lay = compiled.vocab_layout
     return vocab_lib.Vocabulary(
-        table=sds((n, compiled.vocab_range), jnp.int32),
-        sizes=sds((n,), jnp.int32),
+        table=sds((lay.entries,), jnp.int32),
+        sizes=sds((lay.n_columns,), jnp.int32),
+        layout=lay,
     )
 
 

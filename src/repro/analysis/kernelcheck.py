@@ -97,13 +97,13 @@ def check_routes(compiled, *, max_rows=None, context="plan") -> list[Finding]:
                     f"exceeds the {VMEM_TIER_MAX} cutoff",
                 )
             continue
-        if tier in ("hbm", "hbm_slab", "xla_fallback"):
+        if tier in ("hbm", "hbm_slab"):
             # demotion must be forced: the full-width resident set
             # (stack at full vocab_range, counts included) must not fit.
             if name == "vocab":
+                # the whole state as one resident run (no slabs)
                 full_acct = fv_ops.vmem_accounting(
-                    entry["n_columns"],
-                    vr,
+                    vocab_lib.VocabLayout(entry["ranges"]),
                     track_counts=compiled.track_counts,
                 )
                 full = full_acct["state_stack"] + full_acct.get(

@@ -16,10 +16,10 @@ runtime silently assumes:
   * order-dependent hazards: ``Logarithm`` reachable with a
     provably-negative lower bound and no preceding ``Neg2Zero`` /
     ``Clip`` (PL110 — log1p(x) is NaN for x < -1), and a vocab
-    column whose modulus range disagrees with the schema's declared
-    ``vocab_range`` (PL103 — states built from the plan are not
-    mergeable with schema-sized states, the stream service would
-    reject the delta at ingestion);
+    column whose modulus range disagrees with the range the schema
+    declares for its source column (PL103 — states built from the plan
+    are not mergeable with schema-sized states, the stream service
+    would reject the delta at ingestion);
   * dead / no-op stages: an op the interval proves is the identity
     (PL120) and ``GenVocab`` state nothing ever applies (PL121).
 
@@ -35,6 +35,7 @@ import math
 
 from repro.analysis.findings import Finding
 from repro.core import plan as plan_lib
+from repro.core import plan_compiler
 from repro.core import schema as schema_lib
 from repro.core import vocab as vocab_lib
 
@@ -70,17 +71,21 @@ def _initial(kind: str) -> _Absval:
     return _Absval("i32", -(2**31), INT32_MAX)
 
 
-def _effective_vocab_range(
-    plan: plan_lib.PreprocPlan, schema: schema_lib.TableSchema
-) -> int:
-    """The shared Modulus range of the plan's vocab columns (validate_plan
-    guarantees there is at most one), defaulting to the schema's."""
-    for spec in plan.specs("sparse"):
-        if any(o.name == "GenVocab" for o in spec.ops):
-            for o in spec.ops:
-                if o.name == "Modulus":
-                    return int(o.param("range", schema.vocab_range))
-    return schema.vocab_range
+def _default_width(spec: plan_lib.ColumnSpec, schema: schema_lib.TableSchema) -> int:
+    """The range the schema declares for the column's source."""
+    if isinstance(spec.source, int):
+        return schema.ranges[spec.source]
+    return max(schema.ranges, default=0)
+
+
+def _state_width(spec: plan_lib.ColumnSpec, schema: schema_lib.TableSchema) -> int:
+    """The width of a vocab column's own segment of the state: its
+    Modulus range (each vocab column keeps its own), else the schema's
+    range for its source."""
+    mod = next((o for o in spec.ops if o.name == "Modulus"), None)
+    if mod is None:
+        return _default_width(spec, schema)
+    return int(plan_compiler.modulus_range(schema, spec, mod))
 
 
 def lint_plan(
@@ -106,7 +111,6 @@ def lint_plan(
             )
         )
 
-    state_width = _effective_vocab_range(plan, schema)
     applied_vocab = any(
         o.name == "ApplyVocab" for c in plan.columns for o in c.ops
     )
@@ -114,6 +118,7 @@ def lint_plan(
     for spec in plan.columns:
         col = spec.name or f"{spec.kind}:{spec.source}"
         val = _initial(spec.kind)
+        state_width = _state_width(spec, schema) if spec.kind == "sparse" else 0
         for o in spec.ops:
             opdef = plan_lib.REGISTRY[o.name]
             if opdef.stage == "decode":
@@ -142,7 +147,7 @@ def _step(emit, col, o, val, spec, schema, state_width) -> _Absval:
         # mixes two raw hashes into raw bits — any uint32 value
         return _Absval("u32bits", 0, UINT32_MAX)
     if name == "Modulus":
-        rng = int(o.param("range", schema.vocab_range))
+        rng = int(plan_compiler.modulus_range(schema, spec, o))
         if rng - 1 > INT32_MAX:
             emit(
                 "PL101",
@@ -172,16 +177,15 @@ def _step(emit, col, o, val, spec, schema, state_width) -> _Absval:
                 f"GenVocab scatter index range [{val.lo}, {val.hi}] is "
                 f"not provably inside the VocabState width {state_width}",
             )
-        mod = next((p for p in spec.ops if p.name == "Modulus"), None)
-        eff = int(mod.param("range", schema.vocab_range)) if mod else None
-        if eff is not None and eff != schema.vocab_range:
+        default = _default_width(spec, schema)
+        if state_width != default:
             emit(
                 "PL103",
                 "warning",
                 col,
-                f"vocab column modulus range {eff} != schema.vocab_range "
-                f"{schema.vocab_range}: states built from this plan are "
-                "not mergeable with schema-sized states "
+                f"vocab column modulus range {state_width} != the schema's "
+                f"range {default} for its source: states built from this "
+                "plan are not mergeable with schema-sized states "
                 "(vocab.check_compatible rejects the delta)",
             )
         return val  # GenVocab emits its input (loop-②'s view)

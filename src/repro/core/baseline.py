@@ -97,9 +97,10 @@ def decode_rows_serial(
     }
 
 
-def positive_modulus(sparse: np.ndarray, vocab_range: int) -> np.ndarray:
-    """Paper's Modulus op: hash values are unsigned; mod into [0, range)."""
-    return (sparse.view(np.uint32) % np.uint32(vocab_range)).astype(np.int32)
+def positive_modulus(sparse: np.ndarray, vocab_range) -> np.ndarray:
+    """Paper's Modulus op: hash values are unsigned; mod into [0, range).
+    ``vocab_range`` is one int or one range per column."""
+    return (sparse.view(np.uint32) % np.asarray(vocab_range, np.uint32)).astype(np.int32)
 
 
 @dataclasses.dataclass
@@ -148,7 +149,7 @@ def apply_vocab(
     schema: schema_lib.TableSchema,
 ) -> dict[str, np.ndarray]:
     """AV step: map sparse→vocab id, Neg2Zero + log1p on dense."""
-    modded = positive_modulus(decoded["sparse"], schema.vocab_range)
+    modded = positive_modulus(decoded["sparse"], schema.ranges)
     sparse_ids = np.empty_like(modded)
     for c in range(schema.n_sparse):
         table = vocab[c]
@@ -200,7 +201,7 @@ def run_pipeline(
 
     per_thread_subdicts = [
         generate_vocab_thread(
-            positive_modulus(p["sparse"], schema.vocab_range), schema
+            positive_modulus(p["sparse"], schema.ranges), schema
         )
         for p in decoded_parts
     ]

@@ -21,13 +21,20 @@ import jax.numpy as jnp
 from repro.core import vocab as vocab_lib
 
 
-def positive_modulus(sparse: jnp.ndarray, vocab_range: int) -> jnp.ndarray:
+def positive_modulus(sparse: jnp.ndarray, vocab_range) -> jnp.ndarray:
     """Modulus: map unsigned 32-bit hashes into [0, vocab_range).
 
     The decoder stores hashes as int32 bitcasts; the modulus is defined on
     the uint32 value (sparse features "are always positive", paper §3.2).
+    ``vocab_range`` is one int, or one range per column of ``sparse``
+    (``[rows, n_cols]``).
     """
     u = jax.lax.bitcast_convert_type(sparse, jnp.uint32)
+    if isinstance(vocab_range, (tuple, list)):
+        ranges = tuple(int(r) for r in vocab_range)
+        if len(set(ranges)) > 1:
+            return (u % jnp.asarray(ranges, jnp.uint32)[None, :]).astype(jnp.int32)
+        vocab_range = ranges[0] if ranges else 1
     return (u % jnp.uint32(vocab_range)).astype(jnp.int32)
 
 
@@ -93,6 +100,7 @@ def fused_transform(
     sparse: jnp.ndarray,
     dense: jnp.ndarray,
     use_kernel: bool = True,
+    columns: tuple[int, ...] | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Whole loop-② chain — Modulus → ApplyVocab ∥ Neg2Zero → Logarithm —
     as ONE dispatch (paper §3.2/§4.4: the row streams through the entire
@@ -105,15 +113,22 @@ def fused_transform(
     unfused ops above compose — same results (ids bit-identical, dense
     identical formula), used as the differential oracle.
 
-    sparse int32 [rows, n_sparse] (raw hash bitcasts); dense [rows, n_dense]
-    → (ids int32 [rows, n_sparse], dense float32 [rows, n_dense]).
+    sparse int32 [rows, k] (raw hash bitcasts); dense [rows, n_dense]
+    → (ids int32 [rows, k], dense float32 [rows, n_dense]). Column ``j``
+    of ``sparse`` maps through the vocabulary's column ``columns[j]``
+    (default: column ``j``).
     """
     if use_kernel:
         from repro.kernels.fused_xform import ops as fx_ops
 
-        return fx_ops.fused_transform(vocab, sparse, dense)
-    modded = positive_modulus(sparse, vocab.vocab_range)
-    return apply_vocab(vocab, modded), dense_transform(dense)
+        return fx_ops.fused_transform(vocab, sparse, dense, columns)
+    modded = positive_modulus(sparse, column_ranges(vocab.ranges, columns))
+    return apply_vocab(vocab, modded, columns=columns), dense_transform(dense)
+
+
+def column_ranges(ranges: tuple[int, ...], columns: tuple[int, ...] | None):
+    """The ranges of ``columns`` (all of them when None)."""
+    return ranges if columns is None else tuple(ranges[c] for c in columns)
 
 
 def fused_vocab_update(
@@ -150,7 +165,7 @@ def fused_vocab_update(
         from repro.kernels.fused_vocab import ops as fv_ops
 
         return fv_ops.fused_update(state, sparse, valid, slab_range=slab_range)
-    modded = positive_modulus(sparse, int(state.first_pos.shape[1]))
+    modded = positive_modulus(sparse, state.layout.ranges)
     return vocab_lib.update(state, modded, valid)
 
 
@@ -203,7 +218,7 @@ def fused_decode_transform(
         n_dense=n_dense,
         n_sparse=n_sparse,
     )
-    modded = positive_modulus(sparse, vocab.vocab_range)
+    modded = positive_modulus(sparse, vocab.ranges)
     return label, dense_transform(dense), apply_vocab(vocab, modded), valid
 
 
@@ -248,26 +263,33 @@ def fused_decode_vocab_update(
         n_dense=n_dense,
         n_sparse=n_sparse,
     )
-    modded = positive_modulus(sparse, int(state.first_pos.shape[1]))
+    modded = positive_modulus(sparse, state.layout.ranges)
     return vocab_lib.update(state, modded, valid)
 
 
 def apply_vocab(
-    vocab: vocab_lib.Vocabulary, modded: jnp.ndarray, use_kernel: bool = False
+    vocab: vocab_lib.Vocabulary,
+    modded: jnp.ndarray,
+    use_kernel: bool = False,
+    columns: tuple[int, ...] | None = None,
 ) -> jnp.ndarray:
     """ApplyVocab-2 with memory-tier dispatch (paper §3.2 / §4.4.6).
 
-    VMEM tier (small tables): Pallas kernel holding per-column tables in
-    VMEM — the FPGA's on-chip-SRAM mode. HBM tier (large tables): XLA
+    VMEM tier (small tables of one range): Pallas kernel holding
+    per-column tables in VMEM — the FPGA's on-chip-SRAM mode. HBM tier (large tables): XLA
     gather against the HBM-resident table — the FPGA's HBM mode, where the
     paper recovers II≈1 by interleaving columns across HBM channels; XLA's
     batched gather provides the same many-outstanding-reads behaviour.
     """
-    if use_kernel and vocab.vocab_range <= vocab_lib.VMEM_TIER_MAX:
+    lay = vocab.layout
+    if use_kernel and lay.uniform and max(lay.ranges) <= vocab_lib.VMEM_TIER_MAX:
         from repro.kernels.vocab import ops as vocab_ops
 
-        return vocab_ops.apply_vocab_vmem(vocab.table, modded)
-    return vocab_lib.lookup(vocab, modded)
+        rect = lay.rect(vocab.table)
+        if columns is not None:
+            rect = rect[jnp.asarray(columns, jnp.int32)]
+        return vocab_ops.apply_vocab_vmem(rect, modded)
+    return vocab_lib.lookup(vocab, modded, columns)
 
 
 def concatenate(parts: list[jnp.ndarray], axis: int = 0) -> jnp.ndarray:

@@ -210,8 +210,10 @@ class PiperPipeline:
         )
         self._hex_table = jnp.asarray(self.schema.field_is_hex())
         # jitted chunk steps are cached on the instance: re-jitting per
-        # stream pass would retrace/recompile on every epoch
-        self._jit_vocab_step = jax.jit(self.vocab_step)
+        # stream pass would retrace/recompile on every epoch. Loop ①'s
+        # step consumes its state (the fused kernels update it in place;
+        # without the donation XLA copies the whole state every chunk).
+        self._jit_vocab_step = jax.jit(self.vocab_step, donate_argnums=0)
         self._jit_transform_chunk = jax.jit(self.transform_chunk)
         # Span labels: the compiled plan's tier + route metadata, stamped
         # on every per-chunk span so the trace says *which* code path
@@ -368,7 +370,9 @@ class PiperPipeline:
 
     def build_vocab_stream(self, chunks: Iterable) -> vocab_lib.Vocabulary:
         """Loop ① over a host iterator (out-of-core / network path)."""
-        return vocab_lib.finalize(self.build_state_stream(chunks))
+        state = self.build_state_stream(chunks)
+        with obs.span("vocab/finalize"):
+            return vocab_lib.finalize(state)
 
     @functools.partial(jax.jit, static_argnums=0)
     def _build_vocab_scan(self, stacked_chunks) -> vocab_lib.VocabState:

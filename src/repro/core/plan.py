@@ -29,8 +29,9 @@ op              domain  semantics
                         sparse columns into one synthetic sparse column
                         (``ops.hash_cross``). Must be the first compute op
                         and requires a pair source.
-``Modulus``     sparse  uint32 ``% range`` (param ``range``, default =
-                        ``schema.vocab_range``).
+``Modulus``     sparse  uint32 ``% range`` (param ``range``, default = the
+                        schema's range for the source column; each vocab
+                        column may keep its own).
 ``GenVocab``    sparse  loop ①: accumulate first-occurrence vocabulary state
                         for this column. Requires a preceding ``Modulus``.
 ``ApplyVocab``  sparse  loop ②: map modded values through the finalized

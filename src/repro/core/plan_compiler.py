@@ -16,10 +16,11 @@ Compilation passes
 1. **Validate** against the :class:`~repro.core.schema.TableSchema`:
    every source column exists, op domains match column kinds, chains are
    well-ordered (``ApplyVocab`` needs ``GenVocab`` needs ``Modulus``;
-   ``HashCross`` heads a pair-sourced chain), params are sane, and all
-   vocab columns share one modulus range (the rectangular
-   :class:`~repro.core.vocab.VocabState` contract). Failures raise
-   :class:`PlanError` naming the offending column.
+   ``HashCross`` heads a pair-sourced chain) and params are sane. Each
+   vocab column keeps its own modulus range: the
+   :class:`~repro.core.vocab.VocabState` is one flat, offset-indexed
+   state (``vocab.VocabLayout``). Failures raise :class:`PlanError`
+   naming the offending column.
 2. **Group by op-chain signature** — columns with the same canonical
    chain (decode-stage ops stripped) become one
    :class:`ColumnGroup` and execute as one vectorized ``[rows, k]``
@@ -75,6 +76,30 @@ def _col_label(spec: plan_lib.ColumnSpec) -> str:
     return spec.name or f"{spec.kind}:{spec.source}"
 
 
+def _default_range(schema: schema_lib.TableSchema, source) -> int:
+    """The Modulus range a sparse column gets without a ``range`` param:
+    its source column's range in the schema. A crossed pair has no one
+    source column, so it takes the schema's range only when every
+    column shares one."""
+    if isinstance(source, tuple):
+        if not schema.uniform:
+            raise PlanError(
+                f"sparse:{source}: a crossed column needs an explicit "
+                "Modulus range when the schema's ranges differ by column"
+            )
+        return schema.ranges[0]
+    return schema.ranges[source]
+
+
+def modulus_range(
+    schema: schema_lib.TableSchema, spec: plan_lib.ColumnSpec, op: plan_lib.OpSpec
+):
+    """The range of ``spec``'s ``Modulus`` op ``op``: its ``range`` param,
+    else the schema's range for the column's source."""
+    rng = op.param("range")
+    return _default_range(schema, spec.source) if rng is None else rng
+
+
 def validate_plan(
     plan: plan_lib.PreprocPlan, schema: schema_lib.TableSchema
 ) -> None:
@@ -84,10 +109,7 @@ def validate_plan(
     names = [c.name for c in plan.columns if c.name]
     if len(names) != len(set(names)):
         raise PlanError("duplicate column names in plan")
-    # keyed by plan position, not label — unnamed specs sharing a source
-    # would otherwise collide and mask a range mismatch
-    vocab_ranges: dict[int, int] = {}
-    for idx, spec in enumerate(plan.columns):
+    for spec in plan.columns:
         label = _col_label(spec)
         if spec.kind not in ("dense", "sparse"):
             raise PlanError(f"{label}: unknown column kind {spec.kind!r}")
@@ -138,7 +160,7 @@ def validate_plan(
             if o.name == "ApplyVocab" and not seen["GenVocab"]:
                 raise PlanError(f"{label}: ApplyVocab requires a preceding GenVocab")
             if o.name == "Modulus":
-                rng = o.param("range", schema.vocab_range)
+                rng = modulus_range(schema, spec, o)
                 if not isinstance(rng, int) or rng <= 0:
                     raise PlanError(f"{label}: Modulus range must be a positive int")
             if o.name in ("Clip", "MinMaxScale"):
@@ -159,15 +181,6 @@ def validate_plan(
             raise PlanError(
                 f"{label}: a pair source needs a HashCross op to combine it"
             )
-        if seen["GenVocab"]:
-            chain = _canonical_chain(spec)
-            mod = next(o for o in chain if o.name == "Modulus")
-            vocab_ranges[idx] = int(mod.param("range", schema.vocab_range))
-    if len(set(vocab_ranges.values())) > 1:
-        raise PlanError(
-            "all GenVocab columns must share one Modulus range (rectangular "
-            f"VocabState), got {sorted(set(vocab_ranges.values()))}"
-        )
 
 
 # --------------------------------------------------------------------- #
@@ -222,6 +235,12 @@ def _is_dense_canonical(sig: tuple[plan_lib.OpSpec, ...]) -> bool:
     return [o.name for o in sig] == ["Neg2Zero", "Logarithm"]
 
 
+def _ranges_label(ranges: tuple[int, ...]) -> str:
+    if len(set(ranges)) <= 1:
+        return str(ranges[0]) if ranges else "-"
+    return f"{min(ranges)}..{max(ranges)} (Σ {sum(ranges)})"
+
+
 # --------------------------------------------------------------------- #
 # the compiled program
 # --------------------------------------------------------------------- #
@@ -269,25 +288,31 @@ class CompiledPlan:
             if any(o.name == "GenVocab" for o in spec.ops)
         )
         self.n_vocab_columns = len(self._vocab_sources)
-        self.vocab_range = schema.vocab_range
+        # each vocab row's own Modulus range, in vocab-row order
+        ranges: list[int] = []
         vocab_row_of: dict[int, int] = {}
-        row = 0
         for slot, spec in enumerate(sparse_specs):
             chain = _canonical_chain(spec)
             if any(o.name == "GenVocab" for o in chain):
                 mod = next(o for o in chain if o.name == "Modulus")
-                self.vocab_range = int(mod.param("range", schema.vocab_range))
-                vocab_row_of[slot] = row
-                row += 1
+                vocab_row_of[slot] = len(ranges)
+                ranges.append(int(modulus_range(schema, spec, mod)))
+        self.vocab_ranges: tuple[int, ...] = tuple(ranges)
+        # The one state layout of every engine and path (the loop-① tier
+        # policy sizes its slabs).
+        self.vocab_layout = vocab_lib.layout(
+            self.vocab_ranges,
+            track_counts=track_counts,
+            slab_range=vocab_slab_range,
+        )
 
         # group by signature, then route: vocab-apply groups merge into the
         # single fused dispatch; everything else composes as XLA stages.
         sparse_groups = _group_specs(sparse_specs)
         dense_groups = _group_specs(dense_specs)
-        # the fused dispatch's real width (ApplyVocab columns only — a
+        # the fused dispatch's sparse width (ApplyVocab columns only — a
         # GenVocab-without-ApplyVocab column adds a vocab row but never
-        # enters the gather), so `tier` matches what fused_tier() picks
-        # at runtime.
+        # enters the gather)
         self._n_apply_columns = sum(
             len(slots) for sig, slots, _ in sparse_groups if _is_vocab_apply(sig)
         )
@@ -366,8 +391,11 @@ class CompiledPlan:
         # wrappers also fall back to on the HBM tier).
         identity_sparse = tuple(range(schema.n_sparse))
         identity_dense = tuple(range(schema.n_dense))
+        # the bytes-in kernels take the rectangular view of one range
+        uniform = self.vocab_layout.uniform
         self.decode_vocab_dispatch = (
             fused_decode
+            and uniform
             and schema.n_sparse > 0
             and self._vocab_sources == identity_sparse
             # the bytes-in kernel carries no count plane
@@ -375,6 +403,7 @@ class CompiledPlan:
         )
         self.decode_xform_dispatch = (
             fused_decode
+            and uniform
             and schema.n_sparse > 0
             and schema.n_dense > 0
             and self.n_sparse_out == schema.n_sparse
@@ -392,10 +421,10 @@ class CompiledPlan:
     @property
     def tier(self) -> str:
         """Memory tier of the vocab-apply dispatch (paper §3.2/§4.4.6) —
-        computed from the columns the fused gather actually carries."""
+        computed from the flat table the fused gather reads."""
         from repro.kernels.fused_xform import ops as fx_ops
 
-        return fx_ops.fused_tier(max(self._n_apply_columns, 1), self.vocab_range)
+        return fx_ops.fused_tier(self.vocab_layout)
 
     @property
     def vocab_tier(self) -> str:
@@ -406,8 +435,7 @@ class CompiledPlan:
         from repro.kernels.fused_vocab import ops as fv_ops
 
         return fv_ops.fused_vocab_tier(
-            max(self.n_vocab_columns, 1),
-            self.vocab_range,
+            self.vocab_layout.ranges,
             slab_range=self.vocab_slab_range,
             track_counts=self.track_counts,
         )
@@ -416,24 +444,14 @@ class CompiledPlan:
     def vocab_slabs(self) -> int:
         """How many state slabs loop ① streams per chunk (1 off the
         hbm_slab tier) — the obs spans tag dispatches with it."""
-        from repro.kernels.fused_vocab import ops as fv_ops
-
-        return fv_ops.vocab_slab_count(
-            max(self.n_vocab_columns, 1),
-            self.vocab_range,
-            slab_range=self.vocab_slab_range,
-            track_counts=self.track_counts,
-        )
+        return self.vocab_layout.n_slabs if self.vocab_tier == "hbm_slab" else 1
 
     @property
     def vocab_route(self) -> str:
         """Where the compiler sent the vocab-building half:
-        ``"fused/vmem"``, ``"fused/hbm_slab"``, ``"xla_fallback"``
-        (fusion requested but only the oracle admissible), or
-        ``"unfused"``."""
+        ``"fused/vmem"``, ``"fused/hbm_slab"``, or ``"unfused"``."""
         if self._fused_vocab_dispatch:
-            tier = self.vocab_tier
-            return tier if tier == "xla_fallback" else f"fused/{tier}"
+            return f"fused/{self.vocab_tier}"
         return "unfused"
 
     @property
@@ -448,10 +466,10 @@ class CompiledPlan:
     @property
     def decode_vocab_route(self) -> str:
         """Where a utf8 engine's loop ① enters: ``"bytes/vmem"`` (the
-        bytes-in kernel), ``"bytes/hbm_slab"`` / ``"bytes/xla_fallback"``
-        (bytes-in requested but the state over-budget — ref decode + the
-        tier-routed decoded-input chain), or ``"decoded"`` (decode runs
-        as its own dispatch)."""
+        bytes-in kernel), ``"bytes/hbm_slab"`` (bytes-in requested but
+        the state over-budget — ref decode + the tier-routed
+        decoded-input chain), or ``"decoded"`` (decode runs as its own
+        dispatch)."""
         if self.decode_vocab_dispatch:
             return f"bytes/{self.vocab_tier}"
         return "decoded"
@@ -467,7 +485,7 @@ class CompiledPlan:
         return "bytes/" + fdx_ops.fused_decode_tier(
             self.schema.n_dense,
             self.schema.n_sparse,
-            self.vocab_range,
+            self.vocab_ranges[0],
             max_rows,
         )
 
@@ -489,25 +507,17 @@ class CompiledPlan:
         n_apply = max(self._n_apply_columns, 1)
         n_vocab = max(self.n_vocab_columns, 1)
         vocab_tier = self.vocab_tier
-        slab = None
-        if vocab_tier == "hbm_slab":
-            slab = (
-                self.vocab_slab_range
-                if self.vocab_slab_range is not None
-                else fv_ops.default_slab_range(
-                    n_vocab, self.vocab_range, self.track_counts
-                )
-            )
+        lay = self.vocab_layout
+        widest = max(lay.ranges, default=1)
         routes = {
             "xform": {
                 "route": self.xform_route,
                 "tier": self.tier,
                 "n_columns": n_apply,
-                "vocab_range": self.vocab_range,
+                "vocab_range": widest,
+                "ranges": lay.ranges,
                 "footprint": fx_ops.vmem_accounting(
-                    n_apply,
-                    self.vocab_range,
-                    n_dense=len(self._fused_dense_slots),
+                    lay, n_apply, n_dense=len(self._fused_dense_slots)
                 ),
                 "carried": ("table_stack",),
                 "budget": fx_ops.FUSED_TABLE_VMEM_BYTES,
@@ -516,13 +526,11 @@ class CompiledPlan:
                 "route": self.vocab_route,
                 "tier": vocab_tier,
                 "n_columns": n_vocab,
-                "vocab_range": self.vocab_range,
+                "vocab_range": widest,
+                "ranges": lay.ranges,
                 "slabs": self.vocab_slabs,
                 "footprint": fv_ops.vmem_accounting(
-                    n_vocab,
-                    self.vocab_range,
-                    track_counts=self.track_counts,
-                    slab_range=slab,
+                    lay, track_counts=self.track_counts
                 ),
                 "carried": ("state_stack", "counts_stack"),
                 "budget": (
@@ -535,10 +543,9 @@ class CompiledPlan:
                 "route": self.decode_vocab_route,
                 "tier": self.vocab_tier,
                 "n_columns": n_vocab,
-                "vocab_range": self.vocab_range,
-                "footprint": fdv_ops.vmem_accounting(
-                    n_vocab, self.vocab_range
-                ),
+                "vocab_range": widest,
+                "ranges": lay.ranges,
+                "footprint": fdv_ops.vmem_accounting(n_vocab, widest),
                 "carried": ("state_stack",),
                 "budget": fv_ops.FUSED_STATE_VMEM_BYTES,
             },
@@ -548,12 +555,10 @@ class CompiledPlan:
                 "route": self.decode_xform_route(max_rows),
                 "tier": self.decode_xform_route(max_rows).split("/")[-1],
                 "n_columns": self.schema.n_sparse,
-                "vocab_range": self.vocab_range,
+                "vocab_range": widest,
+                "ranges": lay.ranges,
                 "footprint": fdx_ops.vmem_accounting(
-                    self.schema.n_dense,
-                    self.schema.n_sparse,
-                    self.vocab_range,
-                    max_rows,
+                    self.schema.n_dense, self.schema.n_sparse, widest, max_rows
                 ),
                 "carried": ("table_stack", "out_table"),
                 "budget": fx_ops.FUSED_TABLE_VMEM_BYTES,
@@ -564,7 +569,7 @@ class CompiledPlan:
         head = (
             f"CompiledPlan: {self.n_dense_out} dense + {self.n_sparse_out} "
             f"sparse out, {self.n_vocab_columns} vocab columns @ range "
-            f"{self.vocab_range}, fused={self.fused} "
+            f"{_ranges_label(self.vocab_ranges)}, fused={self.fused} "
             f"(dispatch={self.xform_route})"
         )
         vocab_half = (
@@ -604,15 +609,11 @@ class CompiledPlan:
             return dense[:, :0]
         return dense[:, np.asarray(sources, np.int32)]
 
-    def _vocab_subset(
-        self, vocabulary: vocab_lib.Vocabulary, rows: tuple[int, ...]
-    ) -> vocab_lib.Vocabulary:
-        if rows == tuple(range(int(vocabulary.table.shape[0]))):
-            return vocabulary
-        idx = np.asarray(rows, np.int32)
-        return vocab_lib.Vocabulary(
-            table=vocabulary.table[idx], sizes=vocabulary.sizes[idx]
-        )
+    def _apply_columns(self) -> tuple[int, ...] | None:
+        """The vocabulary columns the fused gather reads, or None when
+        they are every column in order (no-op for criteo_default)."""
+        rows = self._apply_vocab_rows
+        return None if rows == tuple(range(self.n_vocab_columns)) else rows
 
     @staticmethod
     def _assemble(pieces, n_out: int, rows, dtype) -> jnp.ndarray:
@@ -629,16 +630,21 @@ class CompiledPlan:
         return jnp.stack(cols, axis=1)
 
     # -- op evaluation for XLA-routed groups --------------------------- #
-    def _eval_sparse(self, raw: jnp.ndarray, sig) -> jnp.ndarray:
+    def _eval_sparse(self, raw: jnp.ndarray, sig, sources) -> jnp.ndarray:
         x = raw
         for o in sig:
             if o.name == "HashCross":
                 pass  # applied at gather time (pair sources)
             elif o.name == "Modulus":
-                # default = schema.vocab_range, matching validate_plan —
-                # NOT the vocab columns' (possibly overridden) range.
+                # default = each source column's schema range, matching
+                # validate_plan — NOT the vocab columns' (possibly
+                # overridden) range.
+                rng = o.param("range")
                 x = ops.positive_modulus(
-                    x, int(o.param("range", self.schema.vocab_range))
+                    x,
+                    tuple(_default_range(self.schema, s) for s in sources)
+                    if rng is None
+                    else int(rng),
                 )
             elif o.name == "GenVocab":
                 pass  # loop-①-only (the column emits its modded values)
@@ -673,9 +679,7 @@ class CompiledPlan:
     # -- loop ① — vocab-building half ---------------------------------- #
     def init_state(self) -> vocab_lib.VocabState:
         return vocab_lib.VocabState.init(
-            self.n_vocab_columns,
-            self.vocab_range,
-            track_counts=self.track_counts,
+            self.vocab_layout, track_counts=self.track_counts
         )
 
     def vocab_step(
@@ -695,7 +699,7 @@ class CompiledPlan:
             return ops.fused_vocab_update(
                 state, raw, batch.valid, slab_range=self.vocab_slab_range
             )
-        modded = ops.positive_modulus(raw, self.vocab_range)
+        modded = ops.positive_modulus(raw, self.vocab_ranges)
         if self.use_kernels:
             from repro.kernels.vocab import ops as vocab_ops
 
@@ -736,9 +740,9 @@ class CompiledPlan:
         :attr:`decode_xform_dispatch` is set; ids/labels bit-identical
         and dense identical-formula to ``transform`` on the decoded
         chunk, padding rows included."""
-        vsub = self._vocab_subset(vocabulary, self._apply_vocab_rows)
+        # the dispatch requires the identity plan: every column in order
         label, dense, ids, valid = ops.fused_decode_transform(
-            vsub,
+            vocabulary,
             byte_buf,
             n_fields=self.schema.n_fields,
             n_dense=self.schema.n_dense,
@@ -760,14 +764,20 @@ class CompiledPlan:
         if self._apply_slots:
             sp_in = self._gather_sparse(batch.sparse, self._apply_sources)
             de_in = self._gather_dense(batch.dense, self._fused_dense_sources)
-            vsub = self._vocab_subset(vocabulary, self._apply_vocab_rows)
+            columns = self._apply_columns()
             if self._fused_dispatch:
                 # Piper's dataflow: the whole chain in one on-chip pass —
                 # no modded/ids/dense intermediates round-tripping HBM.
-                ids, dfx = ops.fused_transform(vsub, sp_in, de_in)
+                ids, dfx = ops.fused_transform(
+                    vocabulary, sp_in, de_in, columns=columns
+                )
             else:
-                modded = ops.positive_modulus(sp_in, self.vocab_range)
-                ids = ops.apply_vocab(vsub, modded, use_kernel=self.use_kernels)
+                modded = ops.positive_modulus(
+                    sp_in, ops.column_ranges(vocabulary.ranges, columns)
+                )
+                ids = ops.apply_vocab(
+                    vocabulary, modded, use_kernel=self.use_kernels, columns=columns
+                )
                 dfx = ops.dense_transform(de_in, use_kernel=self.use_kernels)
             sparse_pieces.append((self._apply_slots, ids))
             if self._fused_dense_slots:
@@ -775,7 +785,7 @@ class CompiledPlan:
 
         for sig, slots, sources in self._sparse_xla:
             raw = self._gather_sparse(batch.sparse, sources)
-            sparse_pieces.append((slots, self._eval_sparse(raw, sig)))
+            sparse_pieces.append((slots, self._eval_sparse(raw, sig, sources)))
         for sig, slots, sources in self._dense_xla:
             raw = self._gather_dense(batch.dense, sources)
             dense_pieces.append((slots, self._eval_dense(raw, sig)))

@@ -41,12 +41,37 @@ class TableSchema:
 
     n_dense: int = 13
     n_sparse: int = 26
-    # Modulus range for sparse features == embedding-table row count.
-    # The paper evaluates 5K ("SRAM/VMEM" tier) and 1M ("HBM" tier).
-    vocab_range: int = 5000
+    # Modulus range for sparse features == embedding-table row count: one
+    # int for every sparse column, or one range per sparse column (kept
+    # as a tuple so the schema stays hashable). The paper evaluates 5K
+    # ("SRAM/VMEM" tier) and 1M ("HBM" tier); MLPerf's DLRM sizes each
+    # Criteo 1TB column on its own (up to 40M).
+    vocab_range: int | tuple[int, ...] = 5000
     # Maximum encoded width of one row in bytes (used to size decode buffers:
     # label ≤2B + 13 dense ≤12B each + 26 sparse ≤17B each + 40 delimiters).
     max_row_bytes: int = 640
+
+    def __post_init__(self):
+        if not isinstance(self.vocab_range, int):
+            ranges = tuple(int(r) for r in self.vocab_range)
+            if len(ranges) != self.n_sparse:
+                raise ValueError(
+                    f"vocab_range lists {len(ranges)} ranges for "
+                    f"{self.n_sparse} sparse columns"
+                )
+            object.__setattr__(self, "vocab_range", ranges)
+
+    @property
+    def ranges(self) -> tuple[int, ...]:
+        """The modulus range of each sparse column."""
+        if isinstance(self.vocab_range, int):
+            return (self.vocab_range,) * self.n_sparse
+        return self.vocab_range
+
+    @property
+    def uniform(self) -> bool:
+        """True when every sparse column shares one modulus range."""
+        return len(set(self.ranges)) <= 1
 
     @property
     def n_fields(self) -> int:

@@ -64,6 +64,13 @@ class ShardedPiper:
 
     def __post_init__(self):
         self.schema = self.config.schema
+        if not self.schema.uniform:
+            raise ValueError(
+                "ShardedPiper shards a rectangular [columns, range] state "
+                "over the model axis and needs one range for every column; "
+                f"schema {self.schema} has per-column ranges — use "
+                "ShardedPiperPipeline (data-parallel) instead"
+            )
         self.row_axes = _row_axes(self.mesh)
         self.n_row_shards = 1
         for a in self.row_axes:
@@ -152,8 +159,7 @@ class ShardedPiper:
             first_pos = jax.lax.with_sharding_constraint(
                 first_pos, NamedSharding(self.mesh, P("model", None))
             )
-            table, sizes = vocab_lib._finalize(first_pos)
-            return table, sizes
+            return vocab_lib._rank_present(first_pos)
 
         table, sizes = _fin(state)
         return vocab_lib.Vocabulary(table=table, sizes=sizes)

@@ -33,6 +33,8 @@ transforms — for any shard count (tests/test_sharded_pipeline.py).
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax import shard_map
@@ -153,8 +155,8 @@ class ShardedPiperPipeline:
             def body(carry, xs):
                 first_pos, counts, n_valid = carry
                 chunk, off = xs
-                st = vocab_lib.VocabState(
-                    first_pos=first_pos, rows_seen=off, counts=counts
+                st = dataclasses.replace(
+                    init, first_pos=first_pos, rows_seen=off, counts=counts
                 )
                 st = self._pipe.vocab_step(st, chunk)
                 # vocab_step advances rows_seen by the chunk's valid rows
@@ -166,8 +168,8 @@ class ShardedPiperPipeline:
                 (init.first_pos, init.counts, init.rows_seen),
                 (chunks_local, offs),
             )
-            state = vocab_lib.VocabState(
-                first_pos=first_pos, rows_seen=n_valid, counts=counts
+            state = dataclasses.replace(
+                init, first_pos=first_pos, rows_seen=n_valid, counts=counts
             )
             return jax.tree.map(lambda x: x[None], state)
 
@@ -179,11 +181,10 @@ class ShardedPiperPipeline:
                 P(self.row_axes, None),
             ),
             out_specs=vocab_lib.VocabState(
-                first_pos=P(self.row_axes, None, None),
+                first_pos=P(self.row_axes, None),
                 rows_seen=P(self.row_axes),
-                counts=(
-                    P(self.row_axes, None, None) if track_counts else None
-                ),
+                counts=P(self.row_axes, None) if track_counts else None,
+                layout=self.compiled.vocab_layout,
             ),
             check_vma=False,
         )(chunks, offsets)
@@ -225,7 +226,9 @@ class ShardedPiperPipeline:
           to what the single-device engine builds from the same chunk
           sequence.
         """
-        return vocab_lib.finalize(self.build_state_scan(chunks, offsets))
+        state = self.build_state_scan(chunks, offsets)
+        with obs.span("vocab/finalize"):
+            return vocab_lib.finalize(state)
 
     # -------------------------------------------------------------- #
     # loop ② — embarrassingly parallel ApplyVocab + dense transforms
@@ -251,7 +254,8 @@ class ShardedPiperPipeline:
             local,
             mesh=self.mesh,
             in_specs=(
-                vocab_lib.Vocabulary(table=P(), sizes=P()),  # replicated
+                # replicated
+                vocab_lib.Vocabulary(table=P(), sizes=P(), layout=vocabulary.layout),
                 self._feed_specs(chunks),
             ),
             out_specs=schema_lib.ProcessedBatch(

@@ -114,7 +114,7 @@ def _fused_decode_genvocab_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_fields", "hex_start", "interpret", "block"),
+    static_argnames=("n_fields", "hex_start", "vocab_range", "interpret", "block"),
     donate_argnums=(0,),
 )
 def fused_decode_genvocab(
@@ -124,12 +124,15 @@ def fused_decode_genvocab(
     *,
     n_fields: int,
     hex_start: int,
+    vocab_range: int | None = None,
     interpret: bool = True,
     block: int = BLOCK,
 ) -> jnp.ndarray:
     """Bytes-in loop ① — decode → Modulus → scatter-min, state in VMEM.
 
-    first_pos int32 [n_fields - hex_start, vocab_range] — the accumulator
+    first_pos int32 [n_fields - hex_start, width] — the accumulator
+    vocab_range — the modulus (``width`` when None; a wider state's
+              lanes past it are padding)
     byte_buf  uint8 [B] — whole rows + zero padding; B must divide by
               ``block`` (ops.py pads; zero bytes are inert to the decode)
     limits    int32 [2] — (min(row count, max_rows), global row offset)
@@ -138,7 +141,8 @@ def fused_decode_genvocab(
     The buffer is donated-into: ``first_pos`` is aliased to the output,
     the same in-place convention as ``fused_vocab.fused_genvocab``.
     """
-    n_cols, vocab_range = first_pos.shape
+    n_cols, width = first_pos.shape
+    vocab_range = vocab_range or width
     n = byte_buf.shape[0]
     if n % block:
         raise ValueError(f"buffer ({n}) must be a multiple of block ({block})")
@@ -155,10 +159,10 @@ def fused_decode_genvocab(
         in_specs=[
             pl.BlockSpec((1, block), lambda i: (i, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_cols, vocab_range), lambda i: (0, 0)),
+            pl.BlockSpec((n_cols, width), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((n_cols, vocab_range), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_cols, vocab_range), jnp.int32),
+        out_specs=pl.BlockSpec((n_cols, width), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_cols, width), jnp.int32),
         scratch_shapes=[pltpu.SMEM((4,), jnp.int32)],
         input_output_aliases={2: 0},
         interpret=interpret,

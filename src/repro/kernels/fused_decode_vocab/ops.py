@@ -5,19 +5,19 @@ the bytes-in kernel carries the same VMEM-resident ``first_pos`` stack,
 so it is admissible iff ``fused_vocab_tier`` says ``"vmem"`` (range
 within the per-column cutoff AND the whole stack within the shared
 8 MiB :data:`~repro.kernels.fused_vocab.ops.FUSED_STATE_VMEM_BYTES`
-residency budget).
+residency budget) and every column shares one range (the kernel takes
+the state's rectangular ``[n_cols, range]`` view).
 
   * **VMEM tier** — ONE Pallas dispatch from raw UTF-8 bytes to the
     updated state: decode (shared ``decode_block`` scan) → uint32
     Modulus → scatter-min, the byte tile and the state both on-chip.
     The only HBM traffic is the byte read.
 
-  * **hbm_slab / xla_fallback tiers, tracked counts, degenerate
-    shapes** — no bytes-in kernel: the chunk decodes through the
-    reference scan and the decoded matrix takes the existing tier-routed
-    ``fused_vocab`` chain (the slab-streaming kernel on ``hbm_slab``,
-    the XLA modulus + scatter-min oracle on the fallback) — shared
-    implementations, not copies; ``ref.py`` stays the standalone oracle.
+  * **hbm_slab tier, ranges that differ by column, tracked counts,
+    degenerate shapes** — no bytes-in kernel: the chunk decodes through
+    the reference scan and the decoded matrix takes the existing
+    tier-routed ``fused_vocab`` chain — a shared implementation, not a
+    copy; ``ref.py`` stays the standalone oracle.
 
 Both tiers are **bit-identical** to decode → ``positive_modulus`` →
 ``vocab.update``: the kernel's dead lanes scatter ``NEVER`` (the min
@@ -52,13 +52,12 @@ def vmem_accounting(
     }
 
 
-def fused_decode_vocab_tier(n_cols: int, vocab_range: int) -> str:
+def fused_decode_vocab_tier(ranges: tuple[int, ...]) -> str:
     """Which tier the bytes-in loop-① dispatch picks — the state residency
     condition is identical to the decoded-input fused kernel's. Only the
-    ``"vmem"`` tier has a bytes-in kernel; ``"hbm_slab"`` /
-    ``"xla_fallback"`` route through the reference decode + the
-    tier-routed decoded-input chain."""
-    return fv_ops.fused_vocab_tier(n_cols, vocab_range)
+    ``"vmem"`` tier has a bytes-in kernel; ``"hbm_slab"`` routes through
+    the reference decode + the tier-routed decoded-input chain."""
+    return fv_ops.fused_vocab_tier(ranges)
 
 
 def fused_decode_update(
@@ -84,7 +83,7 @@ def fused_decode_update(
     through, as every engine's loop ① does.
     """
     n_cols = n_fields - hex_start
-    vocab_range = int(state.first_pos.shape[1])
+    lay = state.layout
     n = int(byte_buf.shape[0])
     # conservative host-side ceiling guard (rows ≤ max_rows per chunk);
     # traced offsets rely on the kernel's saturating position arithmetic
@@ -93,7 +92,8 @@ def fused_decode_update(
         n_cols <= 0
         or n == 0
         or state.counts is not None
-        or fused_decode_vocab_tier(n_cols, vocab_range) != "vmem"
+        or not lay.uniform
+        or fused_decode_vocab_tier(lay.ranges) != "vmem"
     ):
         # Over-budget state / tracked counts (the bytes-in kernel carries
         # no count plane) / no vocab columns: reference decode + the
@@ -118,11 +118,12 @@ def fused_decode_update(
     offset = state.rows_seen.astype(jnp.int32)
     limits = jnp.stack([n_cap, offset])
     first_pos = kernel.fused_decode_genvocab(
-        state.first_pos,
+        lay.rect(state.first_pos),
         byte_buf,
         limits,
         n_fields=n_fields,
         hex_start=hex_start,
+        vocab_range=lay.ranges[0],
         interpret=kernels_lib.interpret(),
         block=block,
     )
@@ -146,6 +147,7 @@ def fused_decode_update(
     fill = jnp.where(r_miss < n_cap, fill_sat, vocab_lib.NEVER)
     first_pos = first_pos.at[:, 0].min(fill)
     return vocab_lib.VocabState(
-        first_pos=first_pos,
+        first_pos=lay.pack(first_pos, vocab_lib.NEVER),
+        layout=lay,
         rows_seen=vocab_lib.advance_rows_seen(state.rows_seen, n_cap),
     )

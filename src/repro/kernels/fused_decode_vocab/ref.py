@@ -41,6 +41,5 @@ def fused_decode_genvocab(
         n_dense=n_dense,
         n_sparse=n_sparse,
     )
-    vocab_range = int(state.first_pos.shape[1])
-    modded = core_ops.positive_modulus(sparse, vocab_range)
+    modded = core_ops.positive_modulus(sparse, state.layout.ranges)
     return vocab_lib.update(state, modded, valid)

@@ -117,7 +117,9 @@ def _fused_decode_transform_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_fields", "hex_start", "max_rows", "interpret", "block"),
+    static_argnames=(
+        "n_fields", "hex_start", "max_rows", "vocab_range", "interpret", "block"
+    ),
 )
 def fused_decode_transform(
     table: jnp.ndarray,
@@ -126,19 +128,22 @@ def fused_decode_transform(
     n_fields: int,
     hex_start: int,
     max_rows: int,
+    vocab_range: int | None = None,
     interpret: bool = True,
     block: int = BLOCK,
 ):
     """Bytes-in loop ② — decode → Modulus → ApplyVocab ∥ Neg2Zero+Log1p.
 
-    table    int32 [n_fields - hex_start, vocab_range] — finalized vocab
+    table    int32 [n_fields - hex_start, width] — finalized vocab
+    vocab_range — the modulus (``width`` when None)
     byte_buf uint8 [B] — whole rows + zero padding; B must divide by
              ``block`` (ops.py pads; zero bytes are inert)
     → (label int32 [max_rows], dense f32 [max_rows, hex_start - 1],
        ids int32 [max_rows, n_sparse], valid bool [max_rows]) — exactly
     ``ref.decode_bytes`` + the loop-② transform, padding rows included.
     """
-    n_sparse, vocab_range = table.shape
+    n_sparse, width = table.shape
+    vocab_range = vocab_range or width
     n = byte_buf.shape[0]
     if n % block:
         raise ValueError(f"buffer ({n}) must be a multiple of block ({block})")
@@ -155,7 +160,7 @@ def fused_decode_transform(
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((n_sparse, vocab_range), lambda i: (0, 0)),
+            pl.BlockSpec((n_sparse, width), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((max_rows + 1, n_fields), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((max_rows + 1, n_fields), jnp.int32),

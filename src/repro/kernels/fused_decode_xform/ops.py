@@ -96,7 +96,8 @@ def fused_decode_transform(
         n_sparse == 0
         or n_dense == 0
         or n == 0
-        or fused_decode_tier(n_dense, n_sparse, vocab.vocab_range, max_rows)
+        or not vocab.layout.uniform
+        or fused_decode_tier(n_dense, n_sparse, vocab.ranges[0], max_rows)
         == "hbm"
     ):
         # HBM tier / degenerate widths: reference decode + the tier-routed
@@ -117,11 +118,12 @@ def fused_decode_transform(
     if pad:
         byte_buf = jnp.pad(byte_buf, (0, pad))
     return kernel.fused_decode_transform(
-        vocab.table,
+        vocab.layout.rect(vocab.table),
         byte_buf,
         n_fields=n_fields,
         hex_start=hex_start,
         max_rows=max_rows,
+        vocab_range=vocab.ranges[0],
         interpret=kernels_lib.interpret(),
         block=block,
     )

@@ -45,7 +45,7 @@ def fused_decode_transform(
         n_dense=n_dense,
         n_sparse=n_sparse,
     )
-    modded = core_ops.positive_modulus(sparse, vocab.vocab_range)
+    modded = core_ops.positive_modulus(sparse, vocab.ranges)
     ids = vocab_lib.lookup(vocab, modded)
     dfx = core_ops.dense_transform(dense)
     return label, dfx, ids, valid

@@ -11,8 +11,8 @@ collapses the chain:
 
 ``fused_genvocab_kernel`` (VMEM tier)
     One grid step per row tile. The raw sparse tile (int32 hash
-    bitcasts, straight out of Decode) is reduced modulo ``vocab_range``
-    as uint32 *inside* the kernel, then scatter-min'd into
+    bitcasts, straight out of Decode) is reduced modulo each column's
+    range as uint32 *inside* the kernel, then scatter-min'd into
     the :class:`~repro.core.vocab.VocabState` ``first_pos`` accumulator
     — which uses a **constant index map** plus an input/output alias,
     so Pallas DMAs the whole state into VMEM once at the first grid
@@ -25,10 +25,10 @@ collapses the chain:
     it is bit-identical to the vectorized XLA scatter-min oracle.
 
 ``fused_genvocab_slab_kernel`` (HBM-slab tier)
-    The same chain for state stacks that exceed the VMEM residency
-    budget. ``first_pos`` (and the optional occurrence-count plane)
-    lives in HBM partitioned into ``[n_cols, slab_range]`` **slabs**;
-    the grid is ``(n_slabs, n_row_tiles)`` with the slab index
+    The same chain for states that exceed the VMEM residency budget.
+    ``first_pos`` (and the optional occurrence-count plane) lives in HBM
+    as a flat, offset-indexed array (``vocab.VocabLayout``) cut into
+    runs of ``slab`` entries — the **slabs**; the grid is ``(n_slabs, n_row_tiles)`` with the slab index
     outermost, so for each slab the whole chunk streams through while
     that slab's block — a constant index map *over the inner row-tile
     dim* plus an input/output alias, generalizing the VMEM kernel's
@@ -37,18 +37,20 @@ collapses the chain:
     Pallas pipeline double-buffers the slab DMAs against compute, and
     loop ① stays ONE fused dispatch at ANY ``vocab_range``.
 
-    A pre-pass in the same program reduces the chunk modulo
-    ``vocab_range`` (vectorized), lays each row tile out column by
-    column as one int32 key ``value << row_bits | row`` (or the values
-    with their positions as a second operand where that key would not
-    fit 31 bits) and, with more than one slab, sorts each column of the
-    tile by value (which also puts duplicates side by side, earliest
-    row first). It also counts where each slab's run starts, per tile
-    and column. Grid step ``(s, t)`` then runs, for each static column,
-    a loop over just the run of tile ``t`` in slab ``s``: every entry is
-    visited once a chunk, with no modulus and no skip in the kernel.
-    With a single slab (the vmem tier with tracked counts) nothing is
-    sorted, and each column's run is the whole column of the tile.
+    A pre-pass in the same program reduces the chunk modulo each
+    column's range (vectorized), lays each row tile out column by column
+    and, with more than one slab, sorts each column of the tile by value
+    — as one int32 key ``value << row_bits | row``, or as the values
+    with their rows as a second operand where that key would not fit 31
+    bits (ranges past about 2.1M). Columns lie in offset order, so the
+    tile is then sorted by flat key, duplicates side by side, earliest
+    row first. Each entry becomes one int32 ``local << row_bits | row``
+    (``local`` its index inside its slab), and the pre-pass counts
+    where each slab's run starts in the tile. Grid step ``(s, t)`` then
+    walks just tile ``t``'s run of slab ``s`` — one loop, whatever the
+    columns: every entry is visited once a chunk, with no modulus and no
+    skip in the kernel. With a single slab (the vmem tier with tracked
+    counts) nothing is sorted, and the run is the whole tile.
 
 XLA-fallback tier (degenerate widths where not even one 128-lane slab
 per column fits the slab budget) — there is no kernel: the modulus and
@@ -71,15 +73,15 @@ through Mosaic; each answers a refusal of the TPU compiler
     breaks the (8, 128) tiling;
   * the VMEM kernel's modulus runs on the scalar in int32 arithmetic
     (Mosaic has no scalar bitcast to uint32);
-  * the RMW touches the 128-lane window that holds the entry, at a
-    static column and a dynamic 128-aligned lane offset, and selects
-    the entry's lane. Mosaic stores no scalars to VMEM and loads no
-    vector at an unaligned dynamic index. The state width is therefore
-    padded to a multiple of 128 lanes, so every window lies inside the
-    block in interpret mode too.
+  * the RMW touches the (8, 128) tile that holds the entry — the state
+    block is viewed as ``[entries // 1024, 8, 128]`` and indexed on its
+    leading axis — and selects the entry's sublane and lane. Mosaic
+    stores no scalars to VMEM and loads no vector at an unaligned
+    dynamic index; a leading-axis index is plain address arithmetic.
+    The layout keeps the state a whole number of tiles.
 
-The column loop is unrolled (``n_cols`` is static and small), which is
-what keeps the column index static.
+The VMEM kernel's column loop is unrolled (``n_cols`` is static and
+small), which keeps each column's range and offset static.
 """
 
 from __future__ import annotations
@@ -93,9 +95,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import vocab as vocab_lib
 
-# The RMW window: one vector register row. State widths and slab widths
-# are multiples of it.
+# The RMW window: one (8, 128) vector register tile of the state.
 LANES = 128
+SUBLANES = 8
+TILE = SUBLANES * LANES
+TILE_BITS = TILE.bit_length() - 1
 # A packed entry key (value << row bits | row) must stay a non-negative
 # int32.
 KEY_BITS = 31
@@ -113,26 +117,30 @@ def _u32_mod(h: jnp.ndarray, vocab_range: int) -> jnp.ndarray:
     return jnp.where(r < 0, r + jnp.int32(vocab_range), r)
 
 
-def _rmw(ref, c: int, v, fn) -> None:
-    """``ref[c, v] = fn(ref[c, v])`` through the 128-lane window that
-    holds ``v`` (static row ``c``, dynamic 128-aligned lane offset)."""
-    base = pl.multiple_of((v // LANES) * LANES, LANES)
-    win = ref[pl.ds(c, 1), pl.ds(base, LANES)]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
-    ref[pl.ds(c, 1), pl.ds(base, LANES)] = jnp.where(
-        lane == v - base, fn(win), win
-    )
+def _rmw(ref, k, fn) -> None:
+    """``flat[k] = fn(flat[k])`` through the (8, 128) tile that holds
+    entry ``k`` (non-negative) of a ``[tiles, 8, 128]`` block (dynamic
+    leading index)."""
+    t = k >> TILE_BITS
+    win = ref[t]
+    idx = jax.lax.broadcasted_iota(
+        jnp.int32, (SUBLANES, LANES), 0
+    ) * LANES + jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+    ref[t] = jnp.where(idx == (k & (TILE - 1)), fn(win), win)
 
 
-def _compiler_params(n_planes: int, n_cols: int, width: int):
-    """Scoped-VMEM limit for ``n_planes`` carried ``[n_cols, width]``
-    blocks. Each is allocated as an input and an output block, both
-    double-buffered, with ``n_cols`` padded to 8 sublanes: at the 1M
-    point one slab plane takes 4 x 32 x 40064 x 4 B = 20.5 MB, past the
-    compiler's default 16 MiB scope (v5e has 128 MiB of VMEM)."""
-    block = -(-n_cols // 8) * 8 * width * 4
+def _tiles(flat: jnp.ndarray) -> jnp.ndarray:
+    return flat.reshape(-1, SUBLANES, LANES)
+
+
+def _compiler_params(n_planes: int, entries: int):
+    """Scoped-VMEM limit for ``n_planes`` carried blocks of ``entries``
+    int32 each. Each is allocated as an input and an output block, both
+    double-buffered: at the 1M point one slab plane takes 4 x 1,040,384
+    x 4 B = 16.6 MB, past the compiler's default 16 MiB scope (v5e has
+    128 MiB of VMEM)."""
     return pltpu.CompilerParams(
-        vmem_limit_bytes=4 * n_planes * block + (4 << 20)
+        vmem_limit_bytes=4 * n_planes * entries * 4 + (4 << 20)
     )
 
 
@@ -140,149 +148,143 @@ def _smem_block(n: int, index_map):
     return pl.BlockSpec((n,), index_map, memory_space=pltpu.SMEM)
 
 
-def _smem_specs(rows: int, n_cols: int, row_block: int, index_map):
-    """SMEM blocks of the flattened sparse tile and of the positions."""
+def _check_rows(rows: int, row_block: int, pos) -> None:
     if rows % row_block:
         raise ValueError(f"rows ({rows}) must divide by row_block ({row_block})")
-    return [
-        _smem_block(row_block * n_cols, index_map),
-        _smem_block(row_block, index_map),
-    ]
+    if pos.shape != (rows,):
+        raise ValueError(f"pos shape {pos.shape} != {(rows,)}")
 
 
 def _fused_genvocab_kernel(
-    sparse_ref, pos_ref, state_in_ref, state_ref, *, n_cols, vocab_range
+    sparse_ref, pos_ref, state_in_ref, state_ref, *, ranges, offsets
 ):
     # sparse_ref:   int32 [R_BLK * n_cols] SMEM — raw hash bitcasts
     # pos_ref:      int32 [R_BLK] SMEM — global row positions (NEVER = padding)
-    # state_in_ref: int32 [n_cols, width] — prior first_pos (aliased)
-    # state_ref:    int32 [n_cols, width] — accumulator, constant index
+    # state_in_ref: int32 [tiles, 8, 128] — prior first_pos (aliased)
+    # state_ref:    int32 [tiles, 8, 128] — accumulator, constant index
     #               map: resident in VMEM and carried across all grid steps
     @pl.when(pl.program_id(0) == 0)
     def _init():  # first tile: seed the accumulator from the carried state
         state_ref[...] = state_in_ref[...]
 
+    n_cols = len(ranges)
+
     def row_body(i, _):
         p = pos_ref[i]
         for c in range(n_cols):
-            v = _u32_mod(sparse_ref[i * n_cols + c], vocab_range)
-            _rmw(state_ref, c, v, lambda w: jnp.minimum(w, p))  # II=2 RMW
+            k = offsets[c] + _u32_mod(sparse_ref[i * n_cols + c], ranges[c])
+            _rmw(state_ref, k, lambda w: jnp.minimum(w, p))  # II=2 RMW
         return 0
 
     jax.lax.fori_loop(0, pos_ref.shape[0], row_body, 0)
 
 
-def _pad_lanes(state: jnp.ndarray) -> jnp.ndarray:
-    pad = (-state.shape[1]) % LANES
-    if not pad:
-        return state
-    return jnp.pad(state, ((0, 0), (0, pad)), constant_values=vocab_lib.NEVER)
-
-
 @functools.partial(
-    jax.jit, static_argnames=("row_block", "interpret"), donate_argnums=(0,)
+    jax.jit,
+    static_argnames=("ranges", "offsets", "row_block", "interpret"),
+    donate_argnums=(0,),
 )
 def fused_genvocab(
     state: jnp.ndarray,
     sparse: jnp.ndarray,
     pos: jnp.ndarray,
     *,
+    ranges: tuple[int, ...],
+    offsets: tuple[int, ...],
     row_block: int = 1024,
     interpret: bool = True,
 ) -> jnp.ndarray:
     """Whole loop-① chain per row tile, state resident in VMEM.
 
-    state  int32 [n_cols, vocab_range] — first_pos accumulator
+    state  int32 [entries] — flat first_pos (a whole number of tiles)
     sparse int32 [rows, n_cols] (raw hash bitcasts, pre-modulus)
     pos    int32 [rows] global positions (``vocab.NEVER`` for
            padding/invalid rows)
-    → updated first_pos int32 [n_cols, vocab_range]
+    ranges, offsets — each column's modulus range and its first entry
+    → updated first_pos int32 [entries]
 
     ``rows`` must divide by ``row_block`` (ops.py pads; padding rows
     carry NEVER positions, which min() ignores). On a TPU ``row_block``
     must also be ``rows`` or a multiple of 1024 (the SMEM tiling).
     """
-    n_cols, vocab_range = state.shape
-    rows = sparse.shape[0]
-    if pos.shape != (rows,):
-        raise ValueError(f"pos shape {pos.shape} != {(rows,)}")
-    wide = _pad_lanes(state)
-    width = wide.shape[1]
+    (entries,) = state.shape
+    rows, n_cols = sparse.shape
+    _check_rows(rows, row_block, pos)
+    block = pl.BlockSpec((entries // TILE, SUBLANES, LANES), lambda r: (0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _fused_genvocab_kernel, n_cols=n_cols, vocab_range=vocab_range
-        ),
+        functools.partial(_fused_genvocab_kernel, ranges=ranges, offsets=offsets),
         grid=(rows // row_block,),
-        in_specs=_smem_specs(rows, n_cols, row_block, lambda r: (r,))
-        + [pl.BlockSpec((n_cols, width), lambda r: (0, 0))],
-        out_specs=pl.BlockSpec((n_cols, width), lambda r: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_cols, width), jnp.int32),
+        in_specs=[
+            _smem_block(row_block * n_cols, lambda r: (r,)),
+            _smem_block(row_block, lambda r: (r,)),
+            block,
+        ],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(
+            (entries // TILE, SUBLANES, LANES), jnp.int32
+        ),
         input_output_aliases={2: 0},
-        compiler_params=_compiler_params(1, n_cols, width),
+        compiler_params=_compiler_params(1, entries),
         interpret=interpret,
-    )(sparse.reshape(-1), pos, wide)
-    return out[:, :vocab_range]
+    )(sparse.reshape(-1), pos, _tiles(state))
+    return out.reshape(-1)
 
 
-def key_row_bits(padded_range: int, row_block: int) -> int | None:
-    """Bits of the row index in a packed entry key ``value << bits |
-    row``, or None where such a key would not stay a non-negative int32
-    (then the values travel with their positions as a second operand)."""
+def key_row_bits(max_range: int, row_block: int) -> int | None:
+    """Bits of the row index in the pre-pass's packed sort key ``value
+    << bits | row``, or None where such a key would not stay a
+    non-negative int32 for values below ``max_range`` (then the values
+    are sorted with their rows as a second operand)."""
     bits = (row_block - 1).bit_length()
-    return bits if (padded_range - 1) >> (KEY_BITS - bits) == 0 else None
+    return bits if (max_range - 1) >> (KEY_BITS - bits) == 0 else None
 
 
-def offsets_width(n_cols: int, n_slabs: int) -> int:
-    """Words of one row tile's slab offsets (``n_cols × (n_slabs + 1)``),
-    padded to the SMEM block grain."""
-    return -(-(n_cols * (n_slabs + 1)) // SMEM_GRAIN) * SMEM_GRAIN
+def offsets_width(n_slabs: int) -> int:
+    """Words of one row tile's slab offsets (``n_slabs + 1``), padded to
+    the SMEM block grain."""
+    return -(-(n_slabs + 1) // SMEM_GRAIN) * SMEM_GRAIN
 
 
-def _entries_by_slab(
-    sparse, pos, *, vocab_range, slab_range, n_slabs, row_block, row_bits
-):
+def _entries_by_slab(sparse, *, ranges, offsets, slab, n_slabs, row_block):
     """The pre-pass of the slab kernel: each row tile's entries reduced
-    modulo ``vocab_range``, laid out column by column and, with more
-    than one slab, sorted by value. Returns the flat entries (``[n_tiles
-    × n_cols × row_block]``, a tile's column ``c`` at ``[c·row_block,
-    (c+1)·row_block)`` of its block), the positions (``[rows]`` read by
-    the packed key's row, or laid out beside the values), and per tile
-    the ``n_slabs + 1`` starts of each column's slab runs, as indices
-    into the tile's block (``[n_tiles × offsets_width]``)."""
+    modulo their column's range, laid out column by column and, with
+    more than one slab, sorted by value within each column — so sorted
+    by flat key over the tile. Returns the entries (``[n_tiles × n_cols
+    × row_block]``, one int32 ``local << row_bits | row`` each, ``local``
+    the entry's index inside its slab) and per tile the ``n_slabs + 1``
+    starts of the slabs' runs, as indices into the tile's block
+    (``[n_tiles × offsets_width]``)."""
     rows, n_cols = sparse.shape
     n_tiles = rows // row_block
-    v = _u32_mod(sparse, vocab_range)
+    bits = (row_block - 1).bit_length()
+    u = jax.lax.bitcast_convert_type(sparse, jnp.uint32)
+    v = (u % jnp.asarray(ranges, jnp.uint32)[None, :]).astype(jnp.int32)
     v = v.reshape(n_tiles, row_block, n_cols).transpose(0, 2, 1)
-    if row_bits is not None:
-        # one int32 key; sorted, duplicates lie earliest row first
-        row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
-        entries = (v << row_bits) | row
-        if n_slabs > 1:
-            entries = jax.lax.sort(entries, dimension=2)
-            v = entries >> row_bits
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
+    if n_slabs > 1:
+        if key_row_bits(max(ranges), row_block) is not None:
+            packed = jax.lax.sort((v << bits) | row, dimension=2)
+            v, row = packed >> bits, packed & ((1 << bits) - 1)
+        else:
+            v, row = jax.lax.sort((v, row), dimension=2, num_keys=1)
+    flat = (v + jnp.asarray(offsets, jnp.int32)[None, :, None]).reshape(n_tiles, -1)
+    local = flat % slab
+    entries = (local << bits) | row.reshape(n_tiles, -1)
+    if n_slabs > 1:
+        bounds = jnp.arange(n_slabs + 1, dtype=jnp.int32) * slab
+        starts = jax.vmap(lambda f: jnp.searchsorted(f, bounds))(flat)
     else:
-        p = jnp.broadcast_to(pos.reshape(n_tiles, 1, row_block), v.shape)
-        if n_slabs > 1:
-            v, p = jax.lax.sort((v, p), dimension=2, num_keys=1)
-        entries, pos = v, p
-    bounds = jnp.arange(n_slabs + 1, dtype=jnp.int32) * slab_range
-    starts = jnp.sum(
-        v[:, :, None, :] < bounds[:, None], axis=-1, dtype=jnp.int32
-    ) + (jnp.arange(n_cols, dtype=jnp.int32) * row_block)[:, None]
-    starts = starts.reshape(n_tiles, n_cols * (n_slabs + 1))
-    pad = offsets_width(n_cols, n_slabs) - starts.shape[1]
-    starts = jnp.pad(starts, ((0, 0), (0, pad)))
-    return entries.reshape(-1), pos.reshape(-1), starts.reshape(-1)
+        starts = jnp.broadcast_to(
+            jnp.asarray([0, n_cols * row_block], jnp.int32), (n_tiles, 2)
+        )
+    starts = jnp.pad(
+        starts.astype(jnp.int32),
+        ((0, 0), (0, offsets_width(n_slabs) - (n_slabs + 1))),
+    )
+    return entries.reshape(-1), starts.reshape(-1)
 
 
-def _fused_genvocab_slab_kernel(
-    *refs,
-    n_cols: int,
-    n_slabs: int,
-    slab_range: int,
-    track_counts: bool,
-    row_bits: int | None,
-):
+def _fused_genvocab_slab_kernel(*refs, row_bits: int, track_counts: bool):
     # grid = (n_slabs, n_row_tiles), slab index outermost: for a fixed
     # slab the row-tile dim iterates innermost, so the slab's state (and
     # count) block — index map constant over that inner dim — stays
@@ -295,7 +297,6 @@ def _fused_genvocab_slab_kernel(
         entries_ref, pos_ref, off_ref, state_in_ref, state_ref = refs
         counts_in_ref = counts_ref = None
     s = pl.program_id(0)
-    lo = s * slab_range
 
     @pl.when(pl.program_id(1) == 0)
     def _init():  # first row tile of this slab: seed from the HBM block
@@ -305,33 +306,27 @@ def _fused_genvocab_slab_kernel(
 
     never = jnp.int32(vocab_lib.NEVER)
 
-    # Each column's entries of this tile that fall in slab s form one
-    # run of the tile's block: walk that run alone.
-    for c in range(n_cols):
+    # The tile's entries that fall in slab s form one run of its block:
+    # walk that run alone.
+    def entry(j, _):
+        k = entries_ref[j]
+        local = k >> row_bits
+        p = pos_ref[k & ((1 << row_bits) - 1)]
+        # the FPGA's II=2 RMW, streamed slab by slab
+        _rmw(state_ref, local, lambda w: jnp.minimum(w, p))
+        if track_counts:
+            # p == NEVER marks padding/invalid/past-ceiling rows — they
+            # drop from the counts exactly as from the state.
+            inc = jnp.where(p != never, 1, 0)
+            _rmw(counts_ref, local, lambda w: w + inc)
+        return 0
 
-        def entry(j, _, c=c):
-            k = entries_ref[j]
-            if row_bits is None:
-                local, p = k - lo, pos_ref[j]
-            else:
-                local = (k >> row_bits) - lo
-                p = pos_ref[k & ((1 << row_bits) - 1)]
-            # the FPGA's II=2 RMW, streamed slab by slab
-            _rmw(state_ref, c, local, lambda w: jnp.minimum(w, p))
-            if track_counts:
-                # p == NEVER marks padding/invalid/past-ceiling rows —
-                # they drop from the counts exactly as from the state.
-                inc = jnp.where(p != never, 1, 0)
-                _rmw(counts_ref, c, local, lambda w: w + inc)
-            return 0
-
-        base = c * (n_slabs + 1) + s
-        jax.lax.fori_loop(off_ref[base], off_ref[base + 1], entry, 0)
+    jax.lax.fori_loop(off_ref[s], off_ref[s + 1], entry, 0)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("slab_range", "vocab_range", "row_block", "interpret"),
+    static_argnames=("ranges", "offsets", "slab", "row_block", "interpret"),
     donate_argnums=(0, 1),
 )
 def fused_genvocab_slabs(
@@ -340,23 +335,23 @@ def fused_genvocab_slabs(
     sparse: jnp.ndarray,
     pos: jnp.ndarray,
     *,
-    slab_range: int,
-    vocab_range: int,
+    ranges: tuple[int, ...],
+    offsets: tuple[int, ...],
+    slab: int,
     row_block: int = 1024,
     interpret: bool = True,
 ):
-    """Whole loop-① chain at any ``vocab_range`` — ONE dispatch, the
-    HBM-resident state streamed through VMEM slab by slab.
+    """Whole loop-① chain at any ranges — ONE dispatch, the HBM-resident
+    flat state streamed through VMEM slab by slab.
 
-    state     int32 [n_cols, padded_range] — first_pos, padded to a
-              ``slab_range`` multiple (pad entries NEVER; ops.py slices)
-    counts    int32 [n_cols, padded_range] occurrence counts, or None
+    state     int32 [entries] — flat first_pos, a whole number of slabs
+    counts    int32 [entries] occurrence counts, or None
     sparse    int32 [rows, n_cols] (raw hash bitcasts, pre-modulus)
     pos       int32 [rows] global positions (``vocab.NEVER`` for
               padding/invalid rows)
-    slab_range — a multiple of :data:`LANES`
-    vocab_range — the TRUE modulus range (≤ padded_range)
-    → (updated first_pos, updated counts | None), same padded shapes.
+    ranges, offsets — each column's modulus range and its first entry
+    slab      — entries per slab, a multiple of :data:`TILE`
+    → (updated first_pos, updated counts | None), same shapes.
 
     A pre-pass in this same program (:func:`_entries_by_slab`) reduces
     the entries and, with more than one slab, buckets each row tile's
@@ -367,68 +362,63 @@ def fused_genvocab_slabs(
     aliased input→output, the same in-place convention as
     :func:`fused_genvocab`. ``row_block`` as there.
     """
-    n_cols, padded_range = state.shape
-    if slab_range % LANES:
-        raise ValueError(f"slab_range ({slab_range}) must divide by {LANES}")
-    if padded_range % slab_range:
+    (entries,) = state.shape
+    if slab % TILE or entries % slab:
         raise ValueError(
-            f"state width ({padded_range}) must divide by slab_range "
-            f"({slab_range}); ops.py pads"
+            f"state of {entries} entries must hold whole slabs of "
+            f"{slab} (a multiple of {TILE}); the layout sizes both"
         )
-    if not 0 < vocab_range <= padded_range:
-        raise ValueError(f"vocab_range {vocab_range} vs padded {padded_range}")
-    n_slabs = padded_range // slab_range
-    rows = sparse.shape[0]
-    if pos.shape != (rows,):
-        raise ValueError(f"pos shape {pos.shape} != {(rows,)}")
+    n_slabs = entries // slab
+    rows, n_cols = sparse.shape
+    _check_rows(rows, row_block, pos)
+    row_bits = (row_block - 1).bit_length()
+    if (slab - 1) >> (31 - row_bits):  # local << row_bits | row: an int32
+        raise ValueError(f"slab ({slab}) too wide for a packed entry key")
     track_counts = counts is not None
-    row_bits = key_row_bits(padded_range, row_block)
-    entries, pos_in, offsets = _entries_by_slab(
+    packed, starts = _entries_by_slab(
         sparse,
-        pos,
-        vocab_range=vocab_range,
-        slab_range=slab_range,
+        ranges=ranges,
+        offsets=offsets,
+        slab=slab,
         n_slabs=n_slabs,
         row_block=row_block,
-        row_bits=row_bits,
     )
 
     def tile(s, r):  # the row tile's SMEM blocks, whatever the slab
         return (r,)
 
-    in_specs = _smem_specs(rows, n_cols, row_block, tile)
-    if row_bits is None:  # positions laid out beside the values
-        in_specs[1] = _smem_block(row_block * n_cols, tile)
-    in_specs.append(_smem_block(offsets_width(n_cols, n_slabs), tile))
-    slab_spec = pl.BlockSpec((n_cols, slab_range), lambda s, r: (0, s))
-    in_specs.append(slab_spec)
-    out_shape = [jax.ShapeDtypeStruct((n_cols, padded_range), jnp.int32)]
-    operands = [entries, pos_in, offsets, state]
+    slab_spec = pl.BlockSpec(
+        (slab // TILE, SUBLANES, LANES), lambda s, r: (s, 0, 0)
+    )
+    in_specs = [
+        _smem_block(row_block * n_cols, tile),
+        _smem_block(row_block, tile),
+        _smem_block(offsets_width(n_slabs), tile),
+        slab_spec,
+    ]
+    shape = jax.ShapeDtypeStruct((entries // TILE, SUBLANES, LANES), jnp.int32)
+    out_shape = [shape]
+    operands = [packed, pos, starts, _tiles(state)]
     aliases = {3: 0}
     if track_counts:
         in_specs.append(slab_spec)
-        out_shape.append(
-            jax.ShapeDtypeStruct((n_cols, padded_range), jnp.int32)
-        )
-        operands.append(counts)
+        out_shape.append(shape)
+        operands.append(_tiles(counts))
         aliases[4] = 1
     out = pl.pallas_call(
         functools.partial(
             _fused_genvocab_slab_kernel,
-            n_cols=n_cols,
-            n_slabs=n_slabs,
-            slab_range=slab_range,
-            track_counts=track_counts,
             row_bits=row_bits,
+            track_counts=track_counts,
         ),
         grid=(n_slabs, rows // row_block),
         in_specs=in_specs,
         out_specs=[slab_spec] * len(out_shape),
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_compiler_params(len(out_shape), n_cols, slab_range),
+        compiler_params=_compiler_params(len(out_shape), slab),
         interpret=interpret,
     )(*operands)
     if track_counts:
-        return out[0], out[1]
-    return out[0], None
+        return out[0].reshape(-1), out[1].reshape(-1)
+    return out[0].reshape(-1), None

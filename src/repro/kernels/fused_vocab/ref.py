@@ -10,21 +10,28 @@ vectorized XLA scatter must agree exactly.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("ranges", "offsets"))
 def fused_genvocab(
-    first_pos: jnp.ndarray, sparse: jnp.ndarray, pos: jnp.ndarray
+    first_pos: jnp.ndarray,
+    sparse: jnp.ndarray,
+    pos: jnp.ndarray,
+    *,
+    ranges: tuple[int, ...],
+    offsets: tuple[int, ...],
 ) -> jnp.ndarray:
-    """first_pos int32 [n_cols, vocab_range]; sparse int32 [rows, n_cols]
-    (raw hashes, pre-modulus); pos int32 [rows] (NEVER for invalid rows)
+    """first_pos int32 [entries] (flat, ``vocab.VocabLayout``); sparse
+    int32 [rows, n_cols] (raw hashes, pre-modulus); pos int32 [rows]
+    (NEVER for invalid rows); each column's range and offset
     → updated first_pos."""
-    vocab_range = first_pos.shape[1]
     u = jax.lax.bitcast_convert_type(sparse, jnp.uint32)
-    modded = (u % jnp.uint32(vocab_range)).astype(jnp.int32)
-    cols = jnp.arange(sparse.shape[1], dtype=jnp.int32)[None, :]
-    return first_pos.at[
-        jnp.broadcast_to(cols, modded.shape), modded
-    ].min(jnp.broadcast_to(pos[:, None], modded.shape))
+    modded = (u % jnp.asarray(ranges, jnp.uint32)[None, :]).astype(jnp.int32)
+    keys = modded + jnp.asarray(offsets, jnp.int32)[None, :]
+    return first_pos.at[keys.reshape(-1)].min(
+        jnp.broadcast_to(pos[:, None], keys.shape).reshape(-1)
+    )

@@ -2,8 +2,8 @@
 
 Tier policy (paper §3.2, §4.4.6, same cutoff as ``core.ops.apply_vocab``):
 
-  * **VMEM tier** — ``vocab_range ≤ vocab.VMEM_TIER_MAX`` *and* the whole
-    table stack fits the fused kernel's residency budget
+  * **VMEM tier** — every range ``≤ vocab.VMEM_TIER_MAX`` *and* the whole
+    flat table fits the fused kernel's residency budget
     (:data:`FUSED_TABLE_VMEM_BYTES`): one Pallas kernel does modulus +
     table gather + dense transform per row tile, every column table
     resident in VMEM for the whole call. The extra bytes condition is
@@ -39,8 +39,8 @@ FUSED_TABLE_VMEM_BYTES = 8 * 1024 * 1024
 
 
 def vmem_accounting(
-    n_sparse: int,
-    vocab_range: int,
+    layout: vocab_lib.VocabLayout,
+    n_sparse: int | None = None,
     *,
     n_dense: int = 0,
     row_block: int = 256,
@@ -48,15 +48,16 @@ def vmem_accounting(
     """Bytes of each VMEM-resident buffer the fused kernel carries.
 
     ``table_stack`` is the grid-carried block (constant index map — the
-    whole per-column vocabulary stack resident for the call) and is the
-    only entry charged against :data:`FUSED_TABLE_VMEM_BYTES`; the tiles
-    stream per grid step and live in the budget's other half. This dict
-    is the kernel package's declared footprint — ``fused_tier`` derives
-    its decision from it, and ``repro.analysis.kernelcheck`` asserts the
-    two never disagree.
+    whole flat vocabulary resident for the call) and is the only entry
+    charged against :data:`FUSED_TABLE_VMEM_BYTES`; the tiles stream per
+    grid step and live in the budget's other half. This dict is the
+    kernel package's declared footprint — ``fused_tier`` derives its
+    decision from it, and ``repro.analysis.kernelcheck`` asserts the two
+    never disagree.
     """
+    n_sparse = layout.n_columns if n_sparse is None else n_sparse
     return {
-        "table_stack": n_sparse * vocab_range * 4,
+        "table_stack": layout.entries * 4,
         "sparse_tile": row_block * n_sparse * 4,
         "dense_tile": row_block * n_dense * 4,
         "ids_tile": row_block * n_sparse * 4,
@@ -64,11 +65,11 @@ def vmem_accounting(
     }
 
 
-def fused_tier(n_sparse: int, vocab_range: int) -> str:
+def fused_tier(layout: vocab_lib.VocabLayout) -> str:
     """Which tier the fused dispatch picks: ``"vmem"`` or ``"hbm"``."""
-    table_bytes = vmem_accounting(n_sparse, vocab_range)["table_stack"]
+    table_bytes = vmem_accounting(layout)["table_stack"]
     if (
-        vocab_range <= vocab_lib.VMEM_TIER_MAX
+        max(layout.ranges, default=0) <= vocab_lib.VMEM_TIER_MAX
         and table_bytes <= FUSED_TABLE_VMEM_BYTES
     ):
         return "vmem"
@@ -80,34 +81,41 @@ def _row_block(rows: int) -> int:
 
 
 def fused_transform(
-    vocab: vocab_lib.Vocabulary, sparse: jnp.ndarray, dense: jnp.ndarray
+    vocab: vocab_lib.Vocabulary,
+    sparse: jnp.ndarray,
+    dense: jnp.ndarray,
+    columns: tuple[int, ...] | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Loop ②'s per-chunk chain in one dispatch, tier-routed.
 
-    sparse int32 [rows, n_sparse] (raw hash bitcasts);
+    sparse int32 [rows, k] (raw hash bitcasts), column ``j`` mapped
+    through the vocabulary's column ``columns[j]`` (default ``j``);
     dense int/float [rows, n_dense] (raw decoded values)
-    → (ids int32 [rows, n_sparse], dense float32 [rows, n_dense]).
+    → (ids int32 [rows, k], dense float32 [rows, n_dense]).
     """
     rows, n_sparse = sparse.shape
     n_dense = dense.shape[1]
+    lay = vocab.layout
     if rows == 0 or n_sparse == 0 or n_dense == 0:
         # Degenerate tiles have no Pallas grid; the oracle is exact.
-        return ref.fused_transform(vocab.table, sparse, dense)
+        return ref.fused_transform(vocab, sparse, dense, columns)
     blk = _row_block(rows)
     pad = (-rows) % blk
     sparse_p = jnp.pad(sparse, ((0, pad), (0, 0)))
     dense_p = jnp.pad(dense, ((0, pad), (0, 0)))
-    if fused_tier(n_sparse, vocab.vocab_range) == "vmem":
+    cols = columns if columns is not None else tuple(range(lay.n_columns))
+    cols = dict(
+        ranges=tuple(lay.ranges[c] for c in cols),
+        offsets=tuple(lay.offsets[c] for c in cols),
+        row_block=blk,
+    )
+    if fused_tier(lay) == "vmem":
         ids, dense_out = kernel.fused_transform(
-            vocab.table, sparse_p, dense_p, row_block=blk, interpret=kernels_lib.interpret()
+            vocab.table, sparse_p, dense_p, interpret=kernels_lib.interpret(), **cols
         )
     else:
-        modded, dense_out = kernel.fused_mod_dense(
-            sparse_p,
-            dense_p,
-            vocab_range=vocab.vocab_range,
-            row_block=blk,
-            interpret=kernels_lib.interpret(),
+        keys, dense_out = kernel.fused_mod_dense(
+            sparse_p, dense_p, interpret=kernels_lib.interpret(), **cols
         )
-        ids = vocab_lib.lookup(vocab, modded)
+        ids = vocab.table[keys]
     return ids[:rows], dense_out[:rows]

@@ -9,20 +9,25 @@ rtol 1e-6 on the dense floats.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.core import ops as core_ops
+from repro.core import vocab as vocab_lib
 
-@jax.jit
+
 def fused_transform(
-    table: jnp.ndarray, sparse: jnp.ndarray, dense: jnp.ndarray
+    vocab: vocab_lib.Vocabulary,
+    sparse: jnp.ndarray,
+    dense: jnp.ndarray,
+    columns: tuple[int, ...] | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """table [n_sparse, V]; sparse int32 [rows, n_sparse]; dense [rows, n_dense]
-    → (ids int32 [rows, n_sparse], dense float32 [rows, n_dense])."""
-    vocab_range = table.shape[1]
-    u = jax.lax.bitcast_convert_type(sparse, jnp.uint32)
-    modded = (u % jnp.uint32(vocab_range)).astype(jnp.int32)
-    cols = jnp.arange(sparse.shape[1], dtype=jnp.int32)[None, :]
-    ids = table[jnp.broadcast_to(cols, modded.shape), modded]
+    """vocab: a finalized Vocabulary; sparse int32 [rows, k] (column
+    ``j`` through vocabulary column ``columns[j]``, default ``j``);
+    dense [rows, n_dense] → (ids int32 [rows, k], dense float32
+    [rows, n_dense])."""
+    modded = core_ops.positive_modulus(
+        sparse, core_ops.column_ranges(vocab.ranges, columns)
+    )
+    ids = vocab_lib.lookup(vocab, modded, columns)
     dense_out = jnp.log1p(jnp.maximum(dense.astype(jnp.float32), 0.0))
     return ids, dense_out

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro import kernels as kernels_lib
 from repro.core import vocab as vocab_lib
-from repro.kernels.vocab import kernel, ref
+from repro.kernels.vocab import kernel
 
 
 def apply_vocab_vmem(table: jnp.ndarray, modded: jnp.ndarray) -> jnp.ndarray:
@@ -38,20 +38,20 @@ def genvocab_update(
 ) -> vocab_lib.VocabState:
     """Chunk update of the first-occurrence state through the Pallas kernel.
 
-    Only the VMEM tier routes to the kernel; the HBM tier uses the
+    Only a uniform layout on the VMEM tier routes to the kernel (on its
+    rectangular view of the state); every other layout uses the
     vectorized scatter-min oracle (identical results — property-tested).
     """
+    lay = state.layout
+    if not (lay.uniform and max(lay.ranges) <= vocab_lib.VMEM_TIER_MAX):
+        return vocab_lib.update(state, modded, valid)
     rows = modded.shape[0]
     vocab_lib.check_row_ceiling(state.rows_seen, rows)
     # overflow-safe positions: saturate at NEVER past the int32 ceiling
     pos = vocab_lib.positions(state.rows_seen, rows, valid)
-    vals_t = modded.T
-    if state.first_pos.shape[1] <= vocab_lib.VMEM_TIER_MAX:
-        first_pos = kernel.genvocab(
-            state.first_pos, vals_t, pos, interpret=kernels_lib.interpret()
-        )
-    else:
-        first_pos = ref.genvocab(state.first_pos, vals_t, pos)
+    rect = kernel.genvocab(
+        lay.rect(state.first_pos), modded.T, pos, interpret=kernels_lib.interpret()
+    )
     rows_seen = vocab_lib.advance_rows_seen(
         state.rows_seen, jnp.sum(valid.astype(jnp.int32))
     )
@@ -59,12 +59,13 @@ def genvocab_update(
     if counts is not None:
         # the per-column kernel carries no count plane — accumulate via
         # the same scatter-add the oracle uses (bit-identical)
-        cols = jnp.arange(modded.shape[1], dtype=jnp.int32)[None, :]
-        bcols = jnp.broadcast_to(cols, modded.shape)
         inc = (pos < vocab_lib.NEVER).astype(jnp.int32)
-        counts = counts.at[bcols, modded].add(
-            jnp.broadcast_to(inc[:, None], modded.shape)
+        counts = counts.at[vocab_lib.keys(lay, modded).reshape(-1)].add(
+            jnp.broadcast_to(inc[:, None], modded.shape).reshape(-1)
         )
     return vocab_lib.VocabState(
-        first_pos=first_pos, rows_seen=rows_seen, counts=counts
+        first_pos=lay.pack(rect, vocab_lib.NEVER),
+        rows_seen=rows_seen,
+        counts=counts,
+        layout=lay,
     )

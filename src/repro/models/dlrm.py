@@ -32,6 +32,15 @@ class DLRMConfig:
     bottom_mlp: tuple[int, ...] = (512, 256, 64)
     top_mlp: tuple[int, ...] = (512, 256, 1)
 
+    def __post_init__(self):
+        # The embedding is one [n_sparse, vocab_range, dim] stack: a
+        # schema whose columns have ranges of their own has no such stack.
+        if not isinstance(self.vocab_range, int):
+            raise ValueError(
+                "DLRM's embedding stack needs one vocab_range for every "
+                f"sparse column, got per-column ranges {self.vocab_range}"
+            )
+
     @property
     def n_pairs(self) -> int:
         f = self.n_sparse + 1  # +1 for the bottom-MLP dense vector

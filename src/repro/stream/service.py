@@ -48,6 +48,7 @@ later first-occurrences.
 
 from __future__ import annotations
 
+import dataclasses
 import queue
 import threading
 import time
@@ -151,15 +152,16 @@ class StreamingPreprocessService:
         )
         self.plan = self.scheduler.plan
         # Fail at construction, not at first dispatch: a state built with a
-        # different plan (wrong vocab-column count or modulus range) would
+        # different plan (wrong vocab-column count or modulus ranges) would
         # otherwise surface as a shape error deep inside the first jit.
         compiled = self.scheduler.compiled
-        want = (compiled.n_vocab_columns, compiled.vocab_range)
-        got = tuple(int(x) for x in vocab_state.first_pos.shape)
+        want = compiled.vocab_layout.ranges
+        got = vocab_state.layout.ranges if vocab_state.layout else None
         if got != want:
             raise ValueError(
-                f"vocab_state shape {got} does not match the plan's vocab "
-                f"layout {want}; build loop ① with the same PipelineConfig.plan"
+                f"vocab_state layout (ranges {got}) does not match the plan's "
+                f"vocab layout (ranges {want}); build loop ① with the same "
+                "PipelineConfig.plan"
             )
         if (vocab_state.counts is not None) != compiled.track_counts:
             raise ValueError(
@@ -460,21 +462,15 @@ class StreamingPreprocessService:
                 for k in ("label", "dense", "sparse"):
                     chunk[k][: req.n_rows] = req.payload[k]
             base = self._ingest.init_state()
-            base = vocab_lib.VocabState(
-                first_pos=base.first_pos,
-                rows_seen=jnp.int32(row_offset),
-                counts=base.counts,
-            )
+            base = dataclasses.replace(base, rows_seen=jnp.int32(row_offset))
             with obs.span("loop1/absorb", **self._ingest._vocab_span_labels):
                 st = self._ingest_step(base, jax.tree.map(jnp.asarray, chunk))
             self._c_absorb.add(1)
             # the delta carries only ITS valid-row count: merge() sums
             # rows_seen, so the offset must not be double-counted (counts
             # started from zero, so they already are the delta's own)
-            delta = vocab_lib.VocabState(
-                first_pos=st.first_pos,
-                rows_seen=st.rows_seen - jnp.int32(row_offset),
-                counts=st.counts,
+            delta = dataclasses.replace(
+                st, rows_seen=st.rows_seen - jnp.int32(row_offset)
             )
             self.refresh_vocab(delta)
 

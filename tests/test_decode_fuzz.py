@@ -21,6 +21,7 @@ property tests skip but the deterministic corpus below still pins every
 class on every path.
 """
 
+import dataclasses
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -143,9 +144,7 @@ def _assert_vocab_agree(buf, n_dense, n_sparse, max_rows, vocab_range, offset):
 
     def fresh():
         st0 = vocab_lib.VocabState.init(n_sparse, vocab_range)
-        return vocab_lib.VocabState(
-            first_pos=st0.first_pos, rows_seen=jnp.int32(offset)
-        )
+        return dataclasses.replace(st0, rows_seen=jnp.int32(offset))
 
     got = fdv_ops.fused_decode_update(
         fresh(),

@@ -85,9 +85,7 @@ def test_fused_equals_update_property(rows, n_cols, seed, offset, vocab_range):
     sparse = _random_inputs(rng, rows, n_cols)
     valid = jnp.asarray(rng.random(rows) < 0.7)
     st0 = vocab_lib.VocabState.init(n_cols, vocab_range)
-    st0 = vocab_lib.VocabState(
-        first_pos=st0.first_pos, rows_seen=jnp.int32(offset)
-    )
+    st0 = dataclasses.replace(st0, rows_seen=jnp.int32(offset))
     _assert_fused_matches_unfused(st0, sparse, valid)
 
 
@@ -131,7 +129,7 @@ def test_fused_matches_update_both_tiers(vocab_range, tier):
     Row counts straddle the wrapper's padding logic: 1500 > 1024 forces
     blk=1024 with 548 pad rows, 5 runs as one whole-array block —
     padding must scatter nothing."""
-    assert fv_ops.fused_vocab_tier(1, vocab_range) == tier
+    assert fv_ops.fused_vocab_tier((vocab_range,)) == tier
     rng = np.random.default_rng(0)
     for rows in (1500, 5):
         sparse = _random_inputs(rng, rows, 1)
@@ -148,8 +146,8 @@ def test_fused_state_budget_routes_to_hbm():
     kernel)."""
     vocab_range = vocab_lib.VMEM_TIER_MAX  # per-column: fits
     n_over = fv_ops.FUSED_STATE_VMEM_BYTES // (vocab_range * 4) + 1
-    assert fv_ops.fused_vocab_tier(n_over, vocab_range) == "hbm_slab"
-    assert fv_ops.fused_vocab_tier(1, vocab_range) == "vmem"
+    assert fv_ops.fused_vocab_tier((vocab_range,) * n_over) == "hbm_slab"
+    assert fv_ops.fused_vocab_tier((vocab_range,)) == "vmem"
 
 
 def test_fused_duplicate_keys_min_combine():
@@ -164,7 +162,7 @@ def test_fused_duplicate_keys_min_combine():
         vocab_lib.VocabState.init(4, 7), sparse, valid
     )
     # non-vacuous: all 7 buckets of every column were hit
-    assert (np.asarray(upd.first_pos) < vocab_lib.NEVER).all()
+    assert all((np.asarray(upd.column(c)) < vocab_lib.NEVER).all() for c in range(4))
 
 
 def test_fused_all_invalid_chunk_sweep():
@@ -190,12 +188,14 @@ def test_fused_empty_shapes():
     upd = ops.fused_vocab_update(
         st0, jnp.zeros((0, 2), jnp.int32), jnp.zeros(0, bool)
     )
-    assert upd.first_pos.shape == (2, 40) and int(upd.rows_seen) == 0
+    assert upd.first_pos.shape == st0.first_pos.shape and int(upd.rows_seen) == 0
+    assert upd.layout == st0.layout and upd.layout.ranges == (40, 40)
     st1 = vocab_lib.VocabState.init(0, 40)
     upd1 = ops.fused_vocab_update(
         st1, jnp.zeros((16, 0), jnp.int32), jnp.ones(16, bool)
     )
-    assert upd1.first_pos.shape == (0, 40) and int(upd1.rows_seen) == 16
+    assert upd1.layout.ranges == () and int(upd1.rows_seen) == 16
+    assert (np.asarray(upd1.first_pos) == vocab_lib.NEVER).all()
 
 
 @pytest.mark.parametrize("row_block", [8, 64, 256])
@@ -207,20 +207,23 @@ def test_fused_kernel_interpret_mode_row_blocks(row_block):
     rows = row_block * 3
     sparse = _random_inputs(rng, rows, 3)
     pos = jnp.arange(rows, dtype=jnp.int32)
-    state = jnp.asarray(
-        np.where(
-            rng.random((3, 97)) < 0.3,
-            rng.integers(0, 50, size=(3, 97)),
-            vocab_lib.NEVER,
-        ).astype(np.int32)
+    lay = vocab_lib.VocabLayout((97, 5, 300))
+    state = lay.pack(
+        [
+            np.where(rng.random(r) < 0.3, rng.integers(0, 50, size=r), vocab_lib.NEVER)
+            for r in lay.ranges
+        ],
+        vocab_lib.NEVER,
     )
-    expect = fv_ref.fused_genvocab(state, sparse, pos)
+    cols = dict(ranges=lay.ranges, offsets=lay.offsets)
+    expect = fv_ref.fused_genvocab(state, sparse, pos, **cols)
     got = fv_kernel.fused_genvocab(
         state,  # donated — ref computed first
         sparse,
         pos,
         row_block=row_block,
         interpret=True,
+        **cols,
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
 
@@ -236,7 +239,7 @@ def test_fused_modulus_uint32_semantics():
         st0, jnp.asarray(edge), jnp.ones(6, bool), use_kernel=True
     )
     exp_vals = edge.view(np.uint32)[:, 0] % np.uint32(5000)
-    fp = np.asarray(upd.first_pos)[0]
+    fp = np.asarray(upd.column(0))
     for i, v in enumerate(exp_vals):
         assert fp[int(v)] <= i  # first occurrence at (or before) row i
     assert (fp < vocab_lib.NEVER).sum() == len(set(exp_vals.tolist()))
@@ -331,7 +334,7 @@ def test_fused_vocab_with_crossed_plan():
         )
         states[fv] = pipe.build_state_stream([chunk])
     # n_sparse plain columns + 1 cross, each with its own vocab row
-    assert states[True].first_pos.shape[0] == schema.n_sparse + 1
+    assert states[True].layout.n_columns == schema.n_sparse + 1
     np.testing.assert_array_equal(
         np.asarray(states[True].first_pos), np.asarray(states[False].first_pos)
     )

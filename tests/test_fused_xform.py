@@ -36,9 +36,12 @@ def _random_vocab(rng, n_cols: int, vocab_range: int) -> vocab_lib.Vocabulary:
     fp = rng.integers(0, 100_000, size=(n_cols, vocab_range)).astype(np.int32)
     seen = rng.random((n_cols, vocab_range)) < 0.6
     fp = np.where(seen, fp, vocab_lib.NEVER)
+    lay = vocab_lib.layout((vocab_range,) * n_cols)
     return vocab_lib.finalize(
         vocab_lib.VocabState(
-            first_pos=jnp.asarray(fp), rows_seen=jnp.int32(0)
+            first_pos=lay.pack(fp, vocab_lib.NEVER),
+            rows_seen=jnp.int32(0),
+            layout=lay,
         )
     )
 
@@ -128,7 +131,7 @@ def test_fused_matches_unfused_both_tiers(vocab_range, tier):
     Row counts deliberately straddle the wrapper's padding logic:
     300 > 256 forces blk=256 with 212 pad rows sliced back off; 5 < 8
     forces blk=8 with 3 pad rows (the _row_block floor)."""
-    assert fx_ops.fused_tier(2, vocab_range) == tier
+    assert fx_ops.fused_tier(vocab_lib.layout((vocab_range,) * 2)) == tier
     rng = np.random.default_rng(0)
     vocab = _random_vocab(rng, 2, vocab_range)
     for rows in (300, 5):
@@ -142,8 +145,8 @@ def test_fused_table_budget_routes_to_hbm():
     column tables resident, unlike the one-column-at-a-time vocab kernel)."""
     vocab_range = vocab_lib.VMEM_TIER_MAX  # per-column: fits
     n_cols_over = fx_ops.FUSED_TABLE_VMEM_BYTES // (vocab_range * 4) + 1
-    assert fx_ops.fused_tier(n_cols_over, vocab_range) == "hbm"
-    assert fx_ops.fused_tier(1, vocab_range) == "vmem"
+    assert fx_ops.fused_tier(vocab_lib.layout((vocab_range,) * n_cols_over)) == "hbm"
+    assert fx_ops.fused_tier(vocab_lib.layout((vocab_range,))) == "vmem"
 
 
 def test_fused_dense_special_values():
@@ -210,20 +213,25 @@ def test_fused_kernel_interpret_mode_row_blocks(row_block):
     block specs, and padding interplay the CPU CI must pin down."""
     rng = np.random.default_rng(4)
     rows = row_block * 3
-    table = jnp.asarray(rng.integers(0, 97, size=(3, 97), dtype=np.int64).astype(np.int32))
+    lay = vocab_lib.VocabLayout((97, 5, 300))
+    vocab = vocab_lib.Vocabulary(
+        table=jnp.asarray(rng.integers(0, 97, size=lay.entries).astype(np.int32)),
+        sizes=jnp.full((3,), 97, jnp.int32),
+        layout=lay,
+    )
+    cols = dict(ranges=lay.ranges, offsets=lay.offsets, row_block=row_block)
     sparse, dense = _random_inputs(rng, rows, 3, 2)
     ids, den = fx_kernel.fused_transform(
-        table, sparse, dense, row_block=row_block, interpret=True
+        vocab.table, sparse, dense, interpret=True, **cols
     )
-    ids_r, den_r = fx_ref.fused_transform(table, sparse, dense)
+    ids_r, den_r = fx_ref.fused_transform(vocab, sparse, dense)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids_r))
     np.testing.assert_allclose(np.asarray(den), np.asarray(den_r), rtol=1e-6)
 
-    modded, den2 = fx_kernel.fused_mod_dense(
-        sparse, dense, vocab_range=97, row_block=row_block, interpret=True
-    )
-    exp_mod = (np.asarray(sparse).view(np.uint32) % np.uint32(97)).astype(np.int32)
-    np.testing.assert_array_equal(np.asarray(modded), exp_mod)
+    keys, den2 = fx_kernel.fused_mod_dense(sparse, dense, interpret=True, **cols)
+    exp_mod = np.asarray(sparse).view(np.uint32) % np.asarray(lay.ranges, np.uint32)
+    exp_keys = exp_mod.astype(np.int32) + np.asarray(lay.offsets, np.int32)
+    np.testing.assert_array_equal(np.asarray(keys), exp_keys)
     np.testing.assert_allclose(np.asarray(den2), np.asarray(den_r), rtol=1e-6)
 
 
@@ -237,7 +245,7 @@ def test_fused_modulus_uint32_semantics():
     )
     dense = jnp.zeros((6, 1), jnp.int32)
     ids_f, _ = ops.fused_transform(vocab, jnp.asarray(edge), dense, use_kernel=True)
-    exp = np.asarray(vocab.table)[0, edge.view(np.uint32) % np.uint32(5000)]
+    exp = np.asarray(vocab.column(0))[edge.view(np.uint32) % np.uint32(5000)]
     np.testing.assert_array_equal(np.asarray(ids_f), exp.reshape(6, 1))
 
 

@@ -376,7 +376,7 @@ def test_modulus_only_column_keeps_schema_default_range():
         P.PipelineConfig(schema=SMALL, input_format="binary",
                          plan=PreprocPlan(cols), use_fused_kernel=False)
     )
-    assert pipe.compiled.vocab_range == 1000
+    assert pipe.compiled.vocab_ranges == (1000,)
     vocabulary = vocab_lib.finalize(
         pipe.vocab_step(pipe.init_state(), dataclass_chunk(batch))
     )
@@ -386,8 +386,10 @@ def test_modulus_only_column_keeps_schema_default_range():
 
 
 def test_tier_uses_apply_columns_not_vocab_rows():
-    """GenVocab-without-ApplyVocab columns add vocab rows but never enter
-    the fused gather — the reported tier must match the dispatch width."""
+    """GenVocab-without-ApplyVocab columns add vocab rows that the fused
+    gather never reads, but they live in the one flat table the gather
+    holds — the reported tier must be the tier of that table, the one
+    the dispatch picks at run time."""
     big = schema_lib.TableSchema(n_dense=1, n_sparse=8, vocab_range=500_000)
     # 7 GenVocab-only columns inflate the vocab table stack past the fused
     # residency budget; the single apply column still fits VMEM.
@@ -403,8 +405,10 @@ def test_tier_uses_apply_columns_not_vocab_rows():
     assert compiled.n_vocab_columns == 8
     from repro.kernels.fused_xform import ops as fx_ops
 
-    assert compiled.tier == fx_ops.fused_tier(1, big.vocab_range) == "vmem"
-    assert fx_ops.fused_tier(8, big.vocab_range) == "hbm"  # the old, wrong basis
+    assert compiled._apply_columns() == (7,)
+    assert compiled.tier == fx_ops.fused_tier(compiled.vocab_layout) == "hbm"
+    one = vocab_lib.VocabLayout((big.vocab_range,))
+    assert fx_ops.fused_tier(one) == "vmem"  # one column's table alone fits
 
 
 def test_fused_hint_without_canonical_dense_routes_unfused():
@@ -439,7 +443,7 @@ def test_vocab_range_override_routes_tier():
         for j in range(SMALL.n_sparse)
     ) + (ColumnSpec(kind="dense", source=0, ops=plan_lib.DENSE_CANONICAL),)
     compiled = plan_compiler.compile_plan(PreprocPlan(cols), SMALL, fused=True)
-    assert compiled.vocab_range == 2_000_000
+    assert compiled.vocab_ranges == (2_000_000,) * SMALL.n_sparse
     assert compiled.tier == "hbm"
     small = plan_compiler.compile_plan(plan_lib.criteo_default(SMALL), SMALL, fused=True)
     assert small.tier == "vmem"
@@ -471,22 +475,21 @@ def test_validation_errors():
         _compile([ColumnSpec(kind="sparse", source=0, ops=(op("HashCross"),))])
     with pytest.raises(PlanError, match="HashCross"):
         _compile([ColumnSpec(kind="sparse", source=(0, 1), ops=(op("Modulus"),))])
-    with pytest.raises(PlanError, match="share one Modulus range"):
-        _compile([
-            ColumnSpec(kind="sparse", source=0,
-                       ops=(op("Modulus", range=7), op("GenVocab"), op("ApplyVocab"))),
-            ColumnSpec(kind="sparse", source=1,
-                       ops=(op("Modulus", range=8), op("GenVocab"), op("ApplyVocab"))),
-        ])
-    # two UNNAMED specs over the same source must not mask the mismatch
-    # (regression: the uniformity check was once keyed by column label)
-    with pytest.raises(PlanError, match="share one Modulus range"):
-        _compile([
-            ColumnSpec(kind="sparse", source=0,
-                       ops=(op("Modulus", range=7), op("GenVocab"), op("ApplyVocab"))),
-            ColumnSpec(kind="sparse", source=0,
-                       ops=(op("Modulus", range=8), op("GenVocab"), op("ApplyVocab"))),
-        ])
+    # each vocab column keeps its own range (one flat, offset-indexed
+    # state), two UNNAMED specs over the same source included
+    assert _compile([
+        ColumnSpec(kind="sparse", source=0,
+                   ops=(op("Modulus", range=7), op("GenVocab"), op("ApplyVocab"))),
+        ColumnSpec(kind="sparse", source=0,
+                   ops=(op("Modulus", range=8), op("GenVocab"), op("ApplyVocab"))),
+    ]).vocab_ranges == (7, 8)
+    for bad in (0, -3):
+        with pytest.raises(PlanError, match="positive int"):
+            _compile([
+                ColumnSpec(kind="sparse", source=0,
+                           ops=(op("Modulus", range=bad), op("GenVocab"),
+                                op("ApplyVocab"))),
+            ])
     with pytest.raises(PlanError, match="boundaries"):
         _compile([ColumnSpec(kind="dense", source=0,
                              ops=(op("Bucketize", boundaries=(3.0, 1.0)),))])

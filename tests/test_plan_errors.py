@@ -160,17 +160,27 @@ def test_pair_source_needs_hashcross():
         _validate([sparse([op("Modulus")], source=(0, 1))])
 
 
-def test_vocab_ranges_must_agree():
+def test_vocab_ranges_may_differ():
     mk = lambda src, rng: sparse(
         [op("Modulus", range=rng), op("GenVocab"), op("ApplyVocab")],
         source=src,
     )
+    _validate([mk(0, 7), mk(1, 8)])  # one flat state, an offset per column
+    compiled = plan_compiler.compile_plan(
+        PreprocPlan((mk(0, 7), mk(1, 8))), SMALL, fused=False
+    )
+    assert compiled.vocab_ranges == (7, 8)
+    assert compiled.vocab_layout.offsets == (0, 128)
+
+
+@pytest.mark.parametrize("rng", [0, -1])
+def test_vocab_range_must_be_positive(rng):
     with pytest.raises(
-        PlanError,
-        match=r"all GenVocab columns must share one Modulus range "
-        r"\(rectangular VocabState\), got \[7, 8\]",
+        PlanError, match=r"^sparse:0: Modulus range must be a positive int$"
     ):
-        _validate([mk(0, 7), mk(1, 8)])
+        _validate([
+            sparse([op("Modulus", range=rng), op("GenVocab"), op("ApplyVocab")])
+        ])
 
 
 # -- the two compiler branches only reachable by direct call ----------- #
@@ -186,7 +196,7 @@ def test_eval_sparse_unhandled_op():
     with pytest.raises(
         PlanError, match=r"^unhandled sparse op ApplyVocab in compiler$"
     ):
-        compiled._eval_sparse(raw, (op("ApplyVocab"),))
+        compiled._eval_sparse(raw, (op("ApplyVocab"),), (0,))
 
 
 def test_eval_dense_unhandled_op():

@@ -128,9 +128,11 @@ def _rand_state(rng, n_cols=3, vocab_range=41) -> vocab_lib.VocabState:
     fp = rng.integers(0, 10_000, size=(n_cols, vocab_range)).astype(np.int32)
     seen = rng.random((n_cols, vocab_range)) < 0.5
     fp = np.where(seen, fp, vocab_lib.NEVER)
+    lay = vocab_lib.layout((vocab_range,) * n_cols)
     return vocab_lib.VocabState(
-        first_pos=jnp.asarray(fp),
+        first_pos=lay.pack(fp, vocab_lib.NEVER),
         rows_seen=jnp.int32(int(rng.integers(0, 1000))),
+        layout=lay,
     )
 
 
@@ -160,9 +162,7 @@ def test_merge_identity():
     """VocabState.init is the monoid identity element."""
     rng = np.random.default_rng(2)
     a = _rand_state(rng)
-    ident = vocab_lib.VocabState.init(
-        a.first_pos.shape[0], a.first_pos.shape[1]
-    )
+    ident = vocab_lib.VocabState.init(a.layout)
     _assert_state_equal(vocab_lib.merge(a, ident), a)
     _assert_state_equal(vocab_lib.merge(ident, a), a)
 
@@ -175,6 +175,7 @@ def test_merge_tree_matches_sequential_reduce(n_shards):
     stacked = vocab_lib.VocabState(
         first_pos=jnp.stack([s.first_pos for s in shards]),
         rows_seen=jnp.stack([s.rows_seen for s in shards]),
+        layout=shards[0].layout,
     )
     _assert_state_equal(
         vocab_lib.merge_tree(stacked), functools.reduce(vocab_lib.merge, shards)

@@ -1,6 +1,7 @@
 """Online streaming service: bit-identity with the offline engine,
 shape discipline (no steady-state recompiles), vocab refresh, lifecycle."""
 
+import dataclasses
 import time
 
 import jax
@@ -125,9 +126,7 @@ def test_mid_stream_vocab_refresh(criteo_small, fmt):
     # loop-① delta over the second half with *global* row positions: seed
     # rows_seen with the split offset, exactly how a follow-up offline job
     # over new data would report its state
-    delta = vocab_lib.VocabState(
-        first_pos=pipe.init_state().first_pos, rows_seen=jnp.int32(half)
-    )
+    delta = dataclasses.replace(pipe.init_state(), rows_seen=jnp.int32(half))
     for chunk in delta_chunks:
         delta = pipe.vocab_step(delta, jax.tree.map(jnp.asarray, chunk))
 
@@ -206,9 +205,7 @@ def test_no_recompile_after_warmup(criteo_small):
 
         # an atomic vocab refresh swaps the table as a jit *argument* —
         # same shapes, so it must not invalidate any bucket executable
-        delta = vocab_lib.VocabState(
-            first_pos=pipe.init_state().first_pos, rows_seen=jnp.int32(rows)
-        )
+        delta = dataclasses.replace(pipe.init_state(), rows_seen=jnp.int32(rows))
         for chunk in synth.chunk_stream(buf, 16384):
             delta = pipe.vocab_step(delta, jax.tree.map(jnp.asarray, chunk))
         prev = svc.vocab_state

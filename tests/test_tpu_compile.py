@@ -23,7 +23,6 @@ from repro.core import pipeline as P
 from repro.core import schema as schema_lib
 from repro.core import vocab as vocab_lib
 from repro.kernels.fused_vocab import kernel as fv_kernel
-from repro.kernels.fused_vocab import ops as fv_ops
 from repro.kernels.fused_xform import kernel as fx_kernel
 
 ROWS = 1 << 14  # PipelineConfig.max_rows_per_chunk
@@ -61,32 +60,43 @@ def _spec(one_chip, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+# MLPerf DLRM's per-column sizes on Criteo 1TB (--num_embeddings_per_feature)
+MLPERF_40M = (
+    40000000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63, 40000000, 3067956,
+    405282, 10, 2209, 11938, 155, 4, 976, 14, 40000000, 40000000, 40000000,
+    590152, 12973, 108, 36,
+)
+
+
+def _flat(one_chip, lay):
+    return _spec(one_chip, (lay.entries,))
+
+
 def test_genvocab_vmem_tier_compiles_5k(one_chip):
-    """Loop ①, 5K: the whole state resident in VMEM."""
+    """Loop ①, 5K: the whole flat state resident in VMEM."""
+    lay = vocab_lib.layout((5000,) * N_SPARSE)
     hlo = _compile(
         lambda st, sp, pos: fv_kernel.fused_genvocab(
-            st, sp, pos, row_block=1024, interpret=False
+            st, sp, pos, ranges=lay.ranges, offsets=lay.offsets,
+            row_block=1024, interpret=False,
         ),
-        _spec(one_chip, (N_SPARSE, 5000)),
+        _flat(one_chip, lay),
         _spec(one_chip, (ROWS, N_SPARSE)),
         _spec(one_chip, (ROWS,)),
     )
     assert "tpu_custom_call" in hlo
 
 
-def _compile_slabs(one_chip, vocab_range, planes, slab_range=None):
-    """Compile the slab kernel for ``[26, vocab_range]`` with one or two
+def _compile_slabs(one_chip, ranges, planes, slab_range=None):
+    """Compile the slab kernel for columns of ``ranges`` with one or two
     carried planes; returns the optimized HLO text."""
-    sr = slab_range or fv_ops.default_slab_range(
-        N_SPARSE, vocab_range, track_counts=planes == 2
-    )
-    width = -(-vocab_range // sr) * sr
-    state = _spec(one_chip, (N_SPARSE, width))
+    lay = vocab_lib.layout(ranges, track_counts=planes == 2, slab_range=slab_range)
+    state = _flat(one_chip, lay)
     counts = state if planes == 2 else None
     return _compile(
         lambda st, ct, sp, pos: fv_kernel.fused_genvocab_slabs(
-            st, ct, sp, pos, slab_range=sr, vocab_range=vocab_range,
-            row_block=1024, interpret=False,
+            st, ct, sp, pos, ranges=lay.ranges, offsets=lay.offsets,
+            slab=lay.slab_entries, row_block=1024, interpret=False,
         ),
         state,
         counts,
@@ -97,34 +107,45 @@ def _compile_slabs(one_chip, vocab_range, planes, slab_range=None):
 
 @pytest.mark.parametrize("planes", [1, 2], ids=["first_pos", "with_counts"])
 def test_genvocab_slab_tier_compiles_1m(one_chip, planes):
-    """Loop ①, 1M: [26, 1M] state streamed through VMEM slab by slab
-    (with the optional count plane too: two carried planes)."""
-    assert "tpu_custom_call" in _compile_slabs(one_chip, 1_000_000, planes)
+    """Loop ①, 1M: the 26 × 1M flat state streamed through VMEM slab by
+    slab (with the optional count plane too: two carried planes)."""
+    assert "tpu_custom_call" in _compile_slabs(one_chip, (1_000_000,) * N_SPARSE, planes)
 
 
 @pytest.mark.parametrize("planes", [1, 2], ids=["first_pos", "with_counts"])
 def test_genvocab_slab_tier_compiles_10m(one_chip, planes):
-    """Loop ①, 10M: past the packed int32 key's range, so each tile's
-    values are sorted with their positions as a second operand and the
-    positions arrive as a whole entry tile in SMEM."""
-    sr = fv_ops.default_slab_range(N_SPARSE, 10_000_000, track_counts=planes == 2)
-    assert fv_kernel.key_row_bits(-(-10_000_000 // sr) * sr, 1024) is None
-    assert "tpu_custom_call" in _compile_slabs(one_chip, 10_000_000, planes)
+    """Loop ①, 10M: past the packed int32 sort key's range, so each
+    tile's values are sorted with their rows as a second operand."""
+    assert fv_kernel.key_row_bits(10_000_000, 1024) is None
+    assert "tpu_custom_call" in _compile_slabs(one_chip, (10_000_000,) * N_SPARSE, planes)
+
+
+def test_genvocab_slab_tier_compiles_mlperf_40m(one_chip):
+    """Loop ①, Criteo 1TB at MLPerf's per-column sizes: 26 ranges from 3
+    to 40M in one 817 MB flat state (195 slabs), the pre-pass in its
+    two-operand sort form."""
+    lay = vocab_lib.layout(MLPERF_40M)
+    assert fv_kernel.key_row_bits(max(MLPERF_40M), 1024) is None
+    assert lay.n_slabs == 195 and lay.entries <= 1.01 * sum(MLPERF_40M)
+    assert "tpu_custom_call" in _compile_slabs(one_chip, MLPERF_40M, planes=1)
 
 
 def test_genvocab_one_slab_compiles_5k_counts(one_chip):
     """Loop ①, 5K with occurrence counts: the vmem tier runs the slab
-    kernel with one resident slab (each column's run the whole column)."""
-    width = -(-5000 // fv_kernel.LANES) * fv_kernel.LANES
-    hlo = _compile_slabs(one_chip, 5000, planes=2, slab_range=width)
+    kernel with one resident slab (the run is the whole tile)."""
+    assert vocab_lib.layout((5000,) * N_SPARSE, track_counts=True).n_slabs == 1
+    hlo = _compile_slabs(one_chip, (5000,) * N_SPARSE, planes=2)
     assert "tpu_custom_call" in hlo
 
 
 def test_mod_dense_hbm_tier_compiles_1m(one_chip):
-    """Loop ②, 1M: modulus + dense transform fused, gather in XLA."""
+    """Loop ②, 1M: modulus (plus each column's offset) and the dense
+    transform fused, gather in XLA."""
+    lay = vocab_lib.layout((1_000_000,) * N_SPARSE)
     hlo = _compile(
         lambda sp, d: fx_kernel.fused_mod_dense(
-            sp, d, vocab_range=1_000_000, row_block=256, interpret=False
+            sp, d, ranges=lay.ranges, offsets=lay.offsets, row_block=256,
+            interpret=False,
         ),
         _spec(one_chip, (ROWS, N_SPARSE)),
         _spec(one_chip, (ROWS, N_DENSE)),
@@ -137,11 +158,13 @@ def test_vmem_transform_kernel_still_refused(one_chip):
     in-kernel take_along_axis gather. When this starts to compile, the
     route can come back (plan_compiler, ROADMAP speed item 2)."""
     with pytest.raises(Exception):
+        lay = vocab_lib.layout((5000,) * N_SPARSE)
         _compile(
             lambda t, sp, d: fx_kernel.fused_transform(
-                t, sp, d, row_block=256, interpret=False
+                t, sp, d, ranges=lay.ranges, offsets=lay.offsets,
+                row_block=256, interpret=False,
             ),
-            _spec(one_chip, (N_SPARSE, 5000)),
+            _flat(one_chip, lay),
             _spec(one_chip, (ROWS, N_SPARSE)),
             _spec(one_chip, (ROWS, N_DENSE)),
         )
@@ -152,8 +175,13 @@ def test_vmem_transform_kernel_still_refused(one_chip):
     [
         (schema_lib.CRITEO, ("fused/vmem", "unfused"), (1, 0)),
         (schema_lib.CRITEO_1M, ("fused/hbm_slab", "fused/hbm"), (1, 1)),
+        (
+            schema_lib.TableSchema(n_dense=N_DENSE, n_sparse=N_SPARSE, vocab_range=MLPERF_40M),
+            ("fused/hbm_slab", "fused/hbm"),
+            (1, 1),
+        ),
     ],
-    ids=["5k", "1m"],
+    ids=["5k", "1m", "mlperf-40m"],
 )
 def test_default_config_route_compiles(one_chip, tpu_route, schema, routes, kernels):
     """A default PipelineConfig on a TPU: its route labels, and both
@@ -171,9 +199,10 @@ def test_default_config_route_compiles(one_chip, tpu_route, schema, routes, kern
     state = jax.tree.map(
         lambda x: _spec(one_chip, x.shape, x.dtype), jax.eval_shape(pipe.init_state)
     )
+    lay = compiled.vocab_layout
+    assert lay.ranges == schema.ranges
     vocabulary = vocab_lib.Vocabulary(
-        table=_spec(one_chip, (N_SPARSE, schema.vocab_range)),
-        sizes=_spec(one_chip, (N_SPARSE,)),
+        table=_flat(one_chip, lay), sizes=_spec(one_chip, (N_SPARSE,)), layout=lay
     )
     loop1 = _compile(compiled.vocab_step, state, batch)
     loop2 = _compile(compiled.transform, vocabulary, batch)

@@ -1,6 +1,8 @@
 """Vocabulary engine: scatter-min formulation vs the dict oracle;
 kernel vs ref; shard-merge invariance."""
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -81,7 +83,7 @@ def test_shard_merge_invariance(rows, split, seed):
     s1 = vocab_lib.update(s1, jnp.asarray(vals[:split]), jnp.ones(split, bool))
     s2 = vocab_lib.VocabState.init(2, 32)
     # shard 2 must use global positions — emulate via rows_seen offset
-    s2 = vocab_lib.VocabState(first_pos=s2.first_pos, rows_seen=jnp.int32(split))
+    s2 = dataclasses.replace(s2, rows_seen=jnp.int32(split))
     s2 = vocab_lib.update(
         s2, jnp.asarray(vals[split:]), jnp.ones(rows - split, bool)
     )

@@ -4,8 +4,8 @@ position-overflow regression suite.
 Three concerns pinned here:
 
 * **Slab streaming** (kernels/fused_vocab hbm_slab tier): one Pallas
-  dispatch per chunk streams the HBM-resident ``[n_cols, slab_range]``
-  state slabs through VMEM. Every slab configuration — boundary
+  dispatch per chunk streams the HBM-resident flat state through VMEM,
+  slab by slab (runs of ``slab_range`` entries). Every slab configuration — boundary
   straddles, partial last slabs, single-slab residency, tracked counts —
   must be bit-identical to the unfused ``positive_modulus`` →
   ``vocab.update`` oracle, and the forced-slab path must equal the VMEM
@@ -77,11 +77,13 @@ def _np_counts(sparse, valid, vocab_range: int) -> np.ndarray:
 
 def _fresh(n_cols, vocab_range, offset=0, track_counts=False):
     st0 = vocab_lib.VocabState.init(n_cols, vocab_range, track_counts=track_counts)
-    return vocab_lib.VocabState(
-        first_pos=st0.first_pos,
-        rows_seen=jnp.int32(offset),
-        counts=st0.counts,
-    )
+    return dataclasses.replace(st0, rows_seen=jnp.int32(offset))
+
+
+def _rect(lay, flat) -> np.ndarray:
+    """A uniform layout's flat array as ``[n_cols, range]`` (pad lanes
+    dropped)."""
+    return np.stack([np.asarray(lay.column(flat, c)) for c in range(lay.n_columns)])
 
 
 def _assert_states_equal(got, want):
@@ -107,8 +109,8 @@ def _assert_states_equal(got, want):
     n_cols=st.integers(1, 5),
     seed=st.integers(0, 1 << 30),
     offset=st.integers(0, 1 << 20),
-    vocab_range=st.sampled_from([100, 129, 997, 1000]),
-    slab_range=st.sampled_from([128, 256, 512]),
+    vocab_range=st.sampled_from([100, 129, 997, 1000, 3000]),
+    slab_range=st.sampled_from([1024, 2048, 4096]),
     track_counts=st.booleans(),
 )
 def test_slab_matches_oracle_property(
@@ -140,8 +142,8 @@ def test_slab_boundary_straddle_values():
     """Values landing exactly on slab edges (0, sr−1, sr, last slab's
     partial tail, V−1) must scatter into the right slab — the in-kernel
     local index and the out-of-slab identity lanes meet here."""
-    vocab_range, sr = 1000, 128  # 8 slabs, last one 104 entries wide
-    edges = [0, 127, 128, 255, 895, 896, 999, 128, 0, 999]
+    vocab_range, sr = 8000, 1024  # 8 slabs, last one 832 entries wide
+    edges = [0, 1023, 1024, 2047, 7167, 7168, 7999, 1024, 0, 7999]
     sparse = jnp.asarray(np.array(edges, np.int32)[:, None])  # in-range ⇒ own modulus
     valid = jnp.ones(len(edges), bool)
     want = ops.fused_vocab_update(
@@ -155,10 +157,10 @@ def test_slab_boundary_straddle_values():
         slab_range=sr,
     )
     _assert_states_equal(got, want)
-    fp = np.asarray(got.first_pos)[0]
-    assert fp[0] == 0 and fp[127] == 1 and fp[128] == 2 and fp[999] == 6
-    cnt = np.asarray(got.counts)[0]
-    assert cnt[0] == 2 and cnt[128] == 2 and cnt[999] == 2 and cnt.sum() == 10
+    fp = np.asarray(got.column(0))
+    assert fp[0] == 0 and fp[1023] == 1 and fp[1024] == 2 and fp[7999] == 6
+    cnt = np.asarray(got.layout.column(got.counts, 0))
+    assert cnt[0] == 2 and cnt[1024] == 2 and cnt[7999] == 2 and cnt.sum() == 10
 
 
 def test_slab_equals_vmem_bit_identity():
@@ -167,13 +169,13 @@ def test_slab_equals_vmem_bit_identity():
     rng = np.random.default_rng(11)
     sparse = _hashes(rng, 300, 4)
     valid = jnp.asarray(rng.random(300) < 0.9)
-    assert fv_ops.fused_vocab_tier(4, 5000) == "vmem"
-    assert fv_ops.fused_vocab_tier(4, 5000, slab_range=1280) == "hbm_slab"
+    assert fv_ops.fused_vocab_tier((5000,) * 4) == "vmem"
+    assert fv_ops.fused_vocab_tier((5000,) * 4, slab_range=4096) == "hbm_slab"
     vmem = ops.fused_vocab_update(
         _fresh(4, 5000), sparse, valid, use_kernel=True
     )
     slab = ops.fused_vocab_update(
-        _fresh(4, 5000), sparse, valid, use_kernel=True, slab_range=1280
+        _fresh(4, 5000), sparse, valid, use_kernel=True, slab_range=4096
     )
     _assert_states_equal(slab, vmem)
 
@@ -186,7 +188,7 @@ def test_slab_all_invalid_chunk():
         jnp.zeros((40, 2), jnp.int32),
         jnp.zeros(40, bool),
         use_kernel=True,
-        slab_range=256,
+        slab_range=1024,
     )
     assert (np.asarray(upd.first_pos) == vocab_lib.NEVER).all()
     assert int(np.asarray(upd.counts).sum()) == 0
@@ -207,7 +209,7 @@ def test_slab_chunk_carry_property(seed, n_chunks):
         valid = jnp.asarray(rng.random(rows) < 0.8)
         u_state = ops.fused_vocab_update(u_state, sparse, valid, use_kernel=False)
         f_state = ops.fused_vocab_update(
-            f_state, sparse, valid, use_kernel=True, slab_range=256
+            f_state, sparse, valid, use_kernel=True, slab_range=1024
         )
     _assert_states_equal(f_state, u_state)
 
@@ -219,7 +221,7 @@ def test_counts_match_numpy_reference():
     sparse = _hashes(rng, 200, 3)
     valid = jnp.asarray(rng.random(200) < 0.85)
     expect = _np_counts(sparse, valid, 500)
-    for slab_range in (None, 128):  # None ⇒ vmem tier, counts ride one slab
+    for slab_range in (None, 1024):  # None ⇒ vmem tier, counts ride one slab
         upd = ops.fused_vocab_update(
             _fresh(3, 500, track_counts=True),
             sparse,
@@ -227,21 +229,22 @@ def test_counts_match_numpy_reference():
             use_kernel=True,
             slab_range=slab_range,
         )
-        np.testing.assert_array_equal(np.asarray(upd.counts), expect)
+        np.testing.assert_array_equal(_rect(upd.layout, upd.counts), expect)
 
 
 def test_auto_tier_above_vmem_cutoff_uses_slabs():
     """Just above VMEM_TIER_MAX the policy (no forcing) must pick slabs,
-    partition the range evenly, and still match the oracle."""
+    partition the state evenly, and still match the oracle."""
     vocab_range = vocab_lib.VMEM_TIER_MAX + 128
-    n_cols = 26  # the Criteo stack: one column's slab budget is ~1M
-    # entries, so a single column would fit one slab — the full stack
-    # is what forces a real multi-slab partition
-    assert fv_ops.fused_vocab_tier(n_cols, vocab_range) == "hbm_slab"
-    n_slabs = fv_ops.vocab_slab_count(n_cols, vocab_range)
-    assert n_slabs > 1
-    sr = fv_ops.default_slab_range(n_cols, vocab_range)
-    assert sr % fv_ops.SLAB_LANE == 0 and (n_slabs - 1) * sr < vocab_range
+    n_cols = 26  # the Criteo stack: one slab holds ~1M entries, so a
+    # single column would fit one slab — the full stack is what forces a
+    # real multi-slab partition
+    assert fv_ops.fused_vocab_tier((vocab_range,) * n_cols) == "hbm_slab"
+    lay = vocab_lib.layout((vocab_range,) * n_cols)
+    assert lay.n_slabs > 1
+    assert lay.slab % fv_ops.SLAB_GRAIN == 0
+    assert (lay.n_slabs - 1) * lay.slab < lay.used <= lay.entries
+    assert lay.entries - lay.used < lay.n_slabs * fv_ops.SLAB_GRAIN
     rng = np.random.default_rng(31)
     sparse = _hashes(rng, 64, n_cols)
     valid = jnp.ones(64, bool)
@@ -265,33 +268,35 @@ def _in_range(rng, rows, n_cols, lo, hi):
 
 
 def _case_one_slab_first(rng):
-    return _in_range(rng, 1500, 3, 0, 128), jnp.ones(1500, bool), 1000, 128
+    # each column's entries in the first slab of its segment
+    return _in_range(rng, 1500, 3, 0, 1024), jnp.ones(1500, bool), 8000, 1024
 
 
 def _case_one_slab_last(rng):
-    return _in_range(rng, 1500, 3, 896, 1000), jnp.ones(1500, bool), 1000, 128
+    return _in_range(rng, 1500, 3, 7168, 8000), jnp.ones(1500, bool), 8000, 1024
 
 
 def _case_empty_slabs(rng):
-    # values only in slabs 1 and 5 of 8: the other six runs are empty
+    # values only in two slabs of each column's eight: most runs empty
     vals = np.where(
         rng.random((1200, 3)) < 0.5,
-        rng.integers(128, 256, size=(1200, 3)),
-        rng.integers(640, 768, size=(1200, 3)),
+        rng.integers(1024, 2048, size=(1200, 3)),
+        rng.integers(5120, 6144, size=(1200, 3)),
     ).astype(np.int32)
-    return jnp.asarray(vals), jnp.ones(1200, bool), 1000, 128
+    return jnp.asarray(vals), jnp.ones(1200, bool), 8000, 1024
 
 
 def _case_padded_last_slab(rng):
-    # 1000 is no multiple of 384: three slabs, the last 232 wide + 152 pad
-    vals = _in_range(rng, 1100, 2, 600, 1000)
-    return vals, jnp.asarray(rng.random(1100) < 0.9), 1000, 384
+    # 2 × 8064 entries are no multiple of 3072: the state is padded to
+    # whole slabs for the call, the last slab part pad
+    vals = _in_range(rng, 1100, 2, 4800, 8000)
+    return vals, jnp.asarray(rng.random(1100) < 0.9), 8000, 3072
 
 
 def _case_negative_hashes(rng):
     # every hash has the top bit set: the uint32 modulus, not int32's
     vals = rng.integers(-(2**31), 0, size=(1300, 3), dtype=np.int64)
-    return jnp.asarray(vals.astype(np.int32)), jnp.ones(1300, bool), 997, 256
+    return jnp.asarray(vals.astype(np.int32)), jnp.ones(1300, bool), 997, 1024
 
 
 def _case_never_rows_partial_tile(rng):
@@ -300,21 +305,21 @@ def _case_never_rows_partial_tile(rng):
     valid = np.ones(2600, bool)
     valid[1024:2048] = False
     valid[2048:] = rng.random(552) < 0.5
-    return _hashes(rng, 2600, 2), jnp.asarray(valid), 1000, 256
+    return _hashes(rng, 2600, 2), jnp.asarray(valid), 1000, 1024
 
 
 def _case_hot_key(rng):
-    # one hot value in slab 3 on most rows of every tile, the rest spread
-    # over every slab
-    vals = np.asarray(_in_range(rng, 2100, 3, 0, 1000))
+    # one hot value on most rows of every tile, the rest spread over
+    # every slab
+    vals = np.asarray(_in_range(rng, 2100, 3, 0, 8000))
     hot = rng.random((2100, 3)) < 0.6
-    vals = np.where(hot, 400, vals).astype(np.int32)
-    return jnp.asarray(vals), jnp.asarray(rng.random(2100) < 0.8), 1000, 128
+    vals = np.where(hot, 3200, vals).astype(np.int32)
+    return jnp.asarray(vals), jnp.asarray(rng.random(2100) < 0.8), 8000, 1024
 
 
 def _case_single_slab(rng):
-    # slab_range ≥ vocab_range: one slab, walked as it comes
-    return _hashes(rng, 1500, 3), jnp.ones(1500, bool), 1000, 1024
+    # slab_range ≥ the state: one slab, walked as it comes
+    return _hashes(rng, 1500, 3), jnp.ones(1500, bool), 1000, 4096
 
 
 _BUCKET_CASES = {
@@ -362,21 +367,26 @@ def _check_bucketed(case, track_counts, seed=7):
     )
     _assert_states_equal(got, vmem)
     pos = vocab_lib.positions(jnp.int32(offset), sparse.shape[0], valid)
+    lay = got.layout
     np.testing.assert_array_equal(
         np.asarray(got.first_pos),
         np.asarray(
             fv_ref.fused_genvocab(
-                _fresh(n_cols, vocab_range).first_pos, sparse, pos
+                _fresh(n_cols, vocab_range).first_pos,
+                sparse,
+                pos,
+                ranges=lay.ranges,
+                offsets=lay.offsets,
             )
         ),
     )
     if track_counts:
         np.testing.assert_array_equal(
-            np.asarray(got.counts), _np_counts(sparse, valid, vocab_range)
+            _rect(lay, got.counts), _np_counts(sparse, valid, vocab_range)
         )
-    return fv_ops.vocab_slab_count(
-        n_cols, vocab_range, slab_range=slab_range, track_counts=track_counts
-    )
+    return vocab_lib.layout(
+        lay.ranges, slab_range=slab_range, track_counts=track_counts
+    ).n_slabs
 
 
 @pytest.mark.parametrize("track_counts", [False, True], ids=["first_pos", "counts"])
@@ -386,8 +396,8 @@ def test_bucketed_slab_cases(case, track_counts):
     that moves where the runs start — all entries in the first or the
     last slab, empty slabs, a padded last slab, hashes with the top bit
     set, all-NEVER tiles and a partial last tile, a hot key across tiles
-    and slabs, and a single slab (each column's run the whole column,
-    nothing sorted) — with and without the count plane."""
+    and slabs, and a single slab (the run the whole tile, nothing
+    sorted) — with and without the count plane."""
     n_slabs = _check_bucketed(case, track_counts)
     assert (n_slabs > 1) == (case != "single_slab")
 
@@ -403,9 +413,10 @@ def fresh_jit_caches():
 
 @pytest.mark.parametrize("case", ["hot_key", "never_rows_partial_tile", "negative_hashes"])
 def test_bucketed_slab_two_operand_sort(case, monkeypatch, fresh_jit_caches):
-    """Where a packed int32 key (value << row bits | row) would not fit,
-    the values sort with their positions as a second operand; narrowing
-    the key to 12 bits takes that path on small shapes."""
+    """Where a packed int32 sort key (value << row bits | row) would not
+    fit, the pre-pass sorts the values with their rows as a second
+    operand; narrowing the key to 12 bits takes that path on small
+    shapes."""
     from repro.kernels.fused_vocab import kernel as fv_kernel
 
     monkeypatch.setattr(fv_kernel, "KEY_BITS", 12)
@@ -417,20 +428,26 @@ def test_bucketed_slab_two_operand_sort(case, monkeypatch, fresh_jit_caches):
 def test_slab_offsets_in_declared_footprint():
     """The bucketed tiles' SMEM blocks are in ``vmem_accounting``: the
     per-tile slab offsets (padded to the SMEM grain), and the positions
-    as a whole entry tile where a packed key does not fit."""
+    of the tile's rows — every entry reaches them through its packed
+    ``local << row bits | row``, past the packed sort key's range too."""
     from repro.kernels.fused_vocab import kernel as fv_kernel
 
-    sr = fv_ops.default_slab_range(26, 1_000_000)
-    acct = fv_ops.vmem_accounting(26, 1_000_000, slab_range=sr)
-    assert acct["offsets_tile"] == fv_kernel.offsets_width(26, 25) * 4 == 1024 * 4
-    assert acct["pos_tile"] == 1024 * 4  # packed key: 20 value + 10 row bits
-    sr10 = fv_ops.default_slab_range(26, 10_000_000)
-    wide = fv_ops.vmem_accounting(26, 10_000_000, slab_range=sr10)
-    assert wide["pos_tile"] == wide["sparse_tile"]
-    assert "offsets_tile" not in fv_ops.vmem_accounting(26, 5000)
+    lay = vocab_lib.layout((1_000_000,) * 26)
+    assert lay.n_slabs == 25
+    acct = fv_ops.vmem_accounting(lay)
+    assert acct["offsets_tile"] == fv_kernel.offsets_width(25) * 4 == 1024 * 4
+    assert acct["pos_tile"] == 1024 * 4
+    assert acct["state_stack"] <= fv_ops.SLAB_VMEM_BYTES
+    wide = fv_ops.vmem_accounting(vocab_lib.layout((10_000_000,) * 26))
+    assert fv_kernel.key_row_bits(10_000_000, 1024) is None
+    assert wide["pos_tile"] == 1024 * 4
+    assert wide["state_stack"] <= fv_ops.SLAB_VMEM_BYTES
+    assert "offsets_tile" not in fv_ops.vmem_accounting(vocab_lib.layout((5000,) * 26))
     # the vmem tier with counts runs the slab kernel with one slab
-    counted = fv_ops.vmem_accounting(26, 5000, track_counts=True)
-    assert counted["offsets_tile"] == fv_kernel.offsets_width(26, 1) * 4
+    counted = fv_ops.vmem_accounting(
+        vocab_lib.layout((5000,) * 26, track_counts=True), track_counts=True
+    )
+    assert counted["offsets_tile"] == fv_kernel.offsets_width(1) * 4
 
 
 # --------------------------------------------------------------------- #
@@ -440,10 +457,12 @@ def test_slab_offsets_in_declared_footprint():
 
 def _count_state(first_pos_rows, counts_rows):
     """Build a VocabState from explicit per-column first_pos/count rows."""
+    lay = vocab_lib.layout([len(r) for r in first_pos_rows], track_counts=True)
     return vocab_lib.VocabState(
-        first_pos=jnp.asarray(np.array(first_pos_rows, np.int32)),
+        first_pos=lay.pack(first_pos_rows, vocab_lib.NEVER),
         rows_seen=jnp.int32(100),
-        counts=jnp.asarray(np.array(counts_rows, np.int32)),
+        counts=lay.pack(counts_rows, 0),
+        layout=lay,
     )
 
 
@@ -457,7 +476,7 @@ def test_finalize_topk_keeps_most_frequent_ties_by_first_pos():
     vocab = vocab_lib.finalize_topk(state, 2)
     # keep v1 (count 5) and the count-3 tie winner v2 (first_pos 3 < 7);
     # ordinals follow appearing-sequence order among the keepers.
-    table = np.asarray(vocab.table)[0]
+    table = np.asarray(vocab.column(0))
     assert int(vocab.sizes[0]) == 2
     assert table[1] == 0 and table[2] == 1  # v1 first (pos 0), then v2
     assert table[0] == 2 and table[3] == 2 and table[4] == 2  # OOV ordinal
@@ -469,15 +488,15 @@ def test_finalize_topk_edge_cases():
     state = _count_state([[4, 1, N]], [[2, 9, 0]])
     # k = 0: everything OOV, ordinal 0
     v0 = vocab_lib.finalize_topk(state, 0)
-    assert int(v0.sizes[0]) == 0 and (np.asarray(v0.table) == 0).all()
+    assert int(v0.sizes[0]) == 0 and (np.asarray(v0.column(0)) == 0).all()
     # k ≥ present: kept ordinals match plain finalize; absent → OOV
     vk = vocab_lib.finalize_topk(state, 10)
     plain = vocab_lib.finalize(state)
     assert int(vk.sizes[0]) == 2
     np.testing.assert_array_equal(
-        np.asarray(vk.table)[0][:2], np.asarray(plain.table)[0][:2]
+        np.asarray(vk.column(0))[:2], np.asarray(plain.column(0))[:2]
     )
-    assert int(np.asarray(vk.table)[0][2]) == 2  # absent → sizes, not 0
+    assert int(np.asarray(vk.column(0))[2]) == 2  # absent → sizes, not 0
     with pytest.raises(ValueError, match="k >= 0"):
         vocab_lib.finalize_topk(state, -1)
     with pytest.raises(ValueError, match="min_count >= 1"):
@@ -496,8 +515,8 @@ def test_finalize_min_count_matches_numpy():
     state = ops.fused_vocab_update(
         _fresh(2, 40, track_counts=True), vals, valid, use_kernel=False
     )
-    fp = np.asarray(state.first_pos)
-    cnt = np.asarray(state.counts)
+    fp = _rect(state.layout, state.first_pos)
+    cnt = _rect(state.layout, state.counts)
     for min_count in (1, 5, 12):
         vocab = vocab_lib.finalize_min_count(state, min_count)
         kept = (fp < vocab_lib.NEVER) & (cnt >= min_count)
@@ -505,7 +524,7 @@ def test_finalize_min_count_matches_numpy():
             kept_vals = np.nonzero(kept[c])[0]
             order = kept_vals[np.argsort(fp[c][kept_vals], kind="stable")]
             assert int(vocab.sizes[c]) == len(order)
-            table = np.asarray(vocab.table)[c]
+            table = np.asarray(vocab.column(c))
             for rank, v in enumerate(order):
                 assert table[v] == rank
             dropped = np.setdiff1d(np.arange(40), order)
@@ -577,9 +596,7 @@ def test_merge_counts_mismatch_raises():
 
 def test_merge_dtype_mismatch_raises():
     a = vocab_lib.VocabState.init(1, 8)
-    b = vocab_lib.VocabState(
-        first_pos=a.first_pos.astype(jnp.int16), rows_seen=a.rows_seen
-    )
+    b = dataclasses.replace(a, first_pos=a.first_pos.astype(jnp.int16))
     with pytest.raises(ValueError, match="dtypes"):
         vocab_lib.merge(a, b)
 
@@ -614,7 +631,7 @@ _CEILING_PATHS = {
         s, v, m, use_kernel=True
     ),
     "fused-slab": lambda s, v, m: ops.fused_vocab_update(
-        s, v, m, use_kernel=True, slab_range=128
+        s, v, m, use_kernel=True, slab_range=1024
     ),
 }
 
@@ -643,9 +660,7 @@ def test_positions_saturate_at_ceiling_jit(path, track_counts):
         st0 = vocab_lib.VocabState.init(
             n_cols, vocab_range, track_counts=track_counts
         )
-        state = vocab_lib.VocabState(
-            first_pos=st0.first_pos, rows_seen=rows_seen, counts=st0.counts
-        )
+        state = dataclasses.replace(st0, rows_seen=rows_seen)
         return _CEILING_PATHS[path](state, vals, valid)
 
     out = jax.jit(run)(jnp.int32(N - 3))
@@ -662,9 +677,8 @@ def test_positions_saturate_at_ceiling_jit(path, track_counts):
 def test_ceiling_raises_eagerly():
     """Host-driven (eager) entry points fail loudly instead of silently
     saturating: check_row_ceiling fires on concrete rows_seen."""
-    state = vocab_lib.VocabState(
-        first_pos=jnp.full((1, 64), vocab_lib.NEVER, jnp.int32),
-        rows_seen=jnp.int32(vocab_lib.NEVER - 3),
+    state = dataclasses.replace(
+        vocab_lib.VocabState.init(1, 64), rows_seen=jnp.int32(vocab_lib.NEVER - 3)
     )
     vals = jnp.zeros((8, 1), jnp.int32)
     with pytest.raises(OverflowError, match="ceiling"):
@@ -685,8 +699,8 @@ def test_bytes_in_kernel_saturates_at_ceiling():
     N = vocab_lib.NEVER
 
     def run(rows_seen, use_kernel):
-        state = vocab_lib.VocabState(
-            first_pos=jnp.full((3, 97), N, jnp.int32), rows_seen=rows_seen
+        state = dataclasses.replace(
+            vocab_lib.VocabState.init(3, 97), rows_seen=rows_seen
         )
         if use_kernel:
             return fdv_ops.fused_decode_update(
@@ -743,7 +757,8 @@ def test_vocab_route_reports_tier():
         P.PipelineConfig(use_fused_vocab=True, vocab_slab_range=1280)
     )
     assert slab.compiled.vocab_route == "fused/hbm_slab"
-    assert slab.compiled.vocab_slabs == 4  # 5000 / 1280
+    # 26 columns × 5120 entries in slabs of 2048 (1280 rounded to a tile)
+    assert slab.compiled.vocab_slabs == 65
     assert "fused/hbm_slab" in slab.compiled.describe()
     big_schema = dataclasses.replace(
         P.PipelineConfig().schema, vocab_range=vocab_lib.VMEM_TIER_MAX + 128
@@ -753,10 +768,10 @@ def test_vocab_route_reports_tier():
     )
     assert auto.compiled.vocab_route == "fused/hbm_slab"
     assert auto.compiled.vocab_slabs > 1
-    # degenerate widths: thousands of columns where not even one
-    # 128-lane slab fits the budget → the XLA oracle, reported as such
-    assert fv_ops.fused_vocab_tier(9000, 300) == "xla_fallback"
-    assert fv_ops.vocab_slab_count(9000, 300) == 1
+    # thousands of columns: slabs are runs of the flat state, so any
+    # column count streams through the slab kernel
+    assert fv_ops.fused_vocab_tier((300,) * 9000) == "hbm_slab"
+    assert vocab_lib.layout((300,) * 9000).n_slabs == 4
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
